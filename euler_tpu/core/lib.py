@@ -11,18 +11,53 @@ numpy-facing wrapper.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
+_CC = os.path.join(_HERE, "cc")
 _LIB_PATH = os.path.join(_HERE, "libeuler_core.so")
+# content hash of the sources the library was built from, written beside
+# it at build time (git-ignored like the library itself)
+_STAMP_PATH = _LIB_PATH + ".srchash"
 
 _lib = None
 
 
-def _build() -> None:
+def source_hash() -> str:
+    """sha256 over the tracked native sources (cc/*.cc, cc/*.h, the
+    Makefile), names included. Content, not mtimes: a copy or checkout
+    that rewrites every mtime must neither hide a source change nor
+    force a rebuild."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(_CC)):
+        if name.endswith((".cc", ".h")) or name == "Makefile":
+            h.update(name.encode() + b"\0")
+            with open(os.path.join(_CC, name), "rb") as f:
+                h.update(f.read())
+            h.update(b"\0")
+    return h.hexdigest()
+
+
+def build_stamp():
+    """The source hash the library on disk was built from (None when
+    there is no library or no stamp)."""
+    if not os.path.exists(_LIB_PATH):
+        return None
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() or None
+    except OSError:
+        return None
+
+
+def _build(want: str) -> None:
+    # -B: a stale library means the object files beside it cannot be
+    # trusted either (make compares mtimes, which a copy rewrites)
     proc = subprocess.run(
-        ["make", "-C", os.path.join(_HERE, "cc"), "-j", "4"],
+        ["make", "-B", "-C", _CC, "-j", "4"],
         capture_output=True,
         text=True,
     )
@@ -30,37 +65,26 @@ def _build() -> None:
         raise RuntimeError(
             "native engine build failed:\n" + proc.stdout + proc.stderr
         )
-
-
-def _stale() -> bool:
-    """True when the .so must be (re)built before loading.
-
-    A missing library always triggers a build. The mtime-vs-source check
-    is a developer convenience only, gated behind EULER_TPU_DEV_REBUILD:
-    a fresh checkout or container copy can legitimately carry sources
-    newer than a prebuilt .so, and surprise-compiling at import (or hard-
-    failing where no compiler exists) is worse than using the prebuilt.
-    """
-    if not os.path.exists(_LIB_PATH):
-        return True
-    if not os.environ.get("EULER_TPU_DEV_REBUILD"):
-        return False
-    so_mtime = os.path.getmtime(_LIB_PATH)
-    cc = os.path.join(_HERE, "cc")
-    for name in os.listdir(cc):
-        if name.endswith((".cc", ".h")) or name == "Makefile":
-            if os.path.getmtime(os.path.join(cc, name)) > so_mtime:
-                return True
-    return False
+    tmp = _STAMP_PATH + f".{os.getpid()}.tmp"
+    with open(tmp, "w") as f:
+        f.write(want + "\n")
+    os.replace(tmp, _STAMP_PATH)
 
 
 def load() -> ctypes.CDLL:
-    """Load (building if necessary) the native engine library."""
+    """Load the native engine library, (re)building it first unless it
+    was built from exactly the sources beside it. A failed build raises:
+    an engine older than its sources never loads silently."""
     global _lib
     if _lib is not None:
         return _lib
-    if _stale():
-        _build()
+    want = source_hash()
+    # one builder at a time: concurrent first imports (shard servers,
+    # subprocess drills) must not race make over the same objects
+    with open(os.path.join(_HERE, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if build_stamp() != want:
+            _build(want)
     lib = ctypes.CDLL(_LIB_PATH)
     _declare(lib)
     _lib = lib

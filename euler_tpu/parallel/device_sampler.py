@@ -798,10 +798,7 @@ def make_table_gather(mesh: Optional[jax.sharding.Mesh] = None,
         return base
     from functools import partial
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mp = dict(mesh.shape)[axis]

@@ -3,7 +3,7 @@ feature shipping.
 
 The reference streams features from the graph engine to the trainer on
 every batch (GetDenseFeature over gRPC, tf_euler/kernels/
-get_dense_feature_op.cc). On TPU the host↔device link (PCIe, or a tunnel)
+get_dense_feature_op.cc). On TPU the host↔device link (PCIe)
 is the bottleneck: a 15×10 fanout batch of 100-dim float features is
 ~66MB/step, while the same batch as int32 row ids is ~0.7MB. When the
 node feature matrix fits in HBM (ogbn-products at 100-dim f32 is ~1GB),
@@ -76,6 +76,7 @@ class DeviceFeatureStore:
         per-column scale (quantize_int8); the store exposes
         feature_scale and models dequantize after the gather."""
         self.shard_rows = bool(shard_rows)
+        self.mesh = mesh
         # table rows follow ENGINE row order so lookup() is the engine's
         # O(1) hash translation (etg_node_rows), not a binary search
         ids = graph.all_node_ids()
@@ -144,6 +145,7 @@ class DeviceFeatureStore:
             self.pad_row, dtype=np.uint64)
         self._sorted_ids = ids is not None
         self.shard_rows = bool(shard_rows)
+        self.mesh = mesh
         from euler_tpu.parallel.placement import (
             put_replicated, put_row_sharded,
         )

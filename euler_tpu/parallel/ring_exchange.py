@@ -21,11 +21,10 @@ the tests.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
 
@@ -70,21 +69,13 @@ def ring_lookup(table: Array, ids: Array, mesh: Mesh,
 
         acc0 = jnp.zeros((ids_shard.shape[0], table_shard.shape[1]),
                          table_shard.dtype)
-        # the new shard_map tracks per-axis varyingness: the carry must
-        # enter the scan already device-varying because ppermute makes it
-        # so on the way out (pcast on jax >= 0.9, pvary before)
-        if hasattr(jax.lax, "pcast"):
-            acc0 = jax.lax.pcast(acc0, axis, to="varying")
-        elif hasattr(jax.lax, "pvary"):
-            acc0 = jax.lax.pvary(acc0, axis)
+        # shard_map tracks per-axis varyingness: the carry must enter
+        # the scan already device-varying because ppermute makes it so
+        # on the way out
+        acc0 = jax.lax.pcast(acc0, axis, to="varying")
         (_, acc), _ = jax.lax.scan(step, (ids_shard, acc0), None, length=k)
         # after k hops every id shard (and its answers) is home again
         return acc
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     fn = shard_map(
         body, mesh=mesh,
@@ -120,11 +111,6 @@ def allgather_lookup(table: Array, ids: Array, mesh: Mesh,
         # one owner per id → the scatter-sum reassembles exact rows
         return jax.lax.psum_scatter(ans, axis, scatter_dimension=0,
                                     tiled=True)
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     fn = shard_map(
         body, mesh=mesh,

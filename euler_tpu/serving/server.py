@@ -97,14 +97,26 @@ class _BundleEngine:
         self._index = None
         self._index_mu = threading.Lock()
 
-        table = jnp.asarray(self.emb) if self.emb.size else None
-        self.jit_gather = jax.jit(
-            (lambda rows: table[rows]) if table is not None
-            else (lambda rows: jnp.zeros((rows.shape[0], 0), jnp.float32)))
-        self.jit_score = jax.jit(
-            (lambda a, b: jnp.sum(table[a] * table[b], axis=-1))
-            if table is not None
-            else (lambda a, b: jnp.zeros((a.shape[0],), jnp.float32)))
+        # the table rides the jitted applies as an ARGUMENT: closed
+        # over, it is baked into every ladder bucket's executable as a
+        # literal — on the v5e that made warming a 100k x 256 bundle
+        # take 77 s (8 compiles, each shipping the ~100 MB table; PR 21
+        # chip_smoke phase B)
+        self._table = jnp.asarray(self.emb) if self.emb.size \
+            else jnp.zeros((1, 0), jnp.float32)
+        self._gather = jax.jit(lambda t, rows: t[rows])
+        self._score = jax.jit(
+            lambda t, a, b: jnp.sum(t[a] * t[b], axis=-1))
+
+    def jit_gather(self, rows):
+        return self._gather(self._table, rows)
+
+    def jit_score(self, a, b):
+        return self._score(self._table, a, b)
+
+    def jit_cache_sizes(self) -> Dict[str, int]:
+        return {"gather": int(self._gather._cache_size()),
+                "score": int(self._score._cache_size())}
 
     def warm(self, ladder: Tuple[int, ...]) -> None:
         """Compile every ladder bucket of both applies BEFORE this
@@ -496,9 +508,7 @@ class InferenceServer:
         applies (steady-state no-recompile assertions): stays <=
         len(ladder) per fn — including right after a hot-swap, whose
         engine was warmed before the flip."""
-        eng = self._engine
-        return {"gather": int(eng.jit_gather._cache_size()),
-                "score": int(eng.jit_score._cache_size())}
+        return self._engine.jit_cache_sizes()
 
     # -- network -----------------------------------------------------------
     def _accept_loop(self) -> None:

@@ -157,22 +157,3 @@ def test_degree_sort_tables_is_isomorphic():
         np.testing.assert_allclose(cum2[r], cum[old])
         np.testing.assert_allclose(label2[r], label[old])
     assert (nbr2[-1] == n).all()
-
-
-def test_tracked_tpu_record_is_canonical():
-    """The tracked BENCH_TPU.json must be the canonical gate's own
-    output (advisor r4: the round-4 record was hand-promoted from an
-    A/B leg file and recorded on a dirty tree; after the round-5
-    re-record the source field must be back to 'auto' and stay there)."""
-    d = json.loads((REPO / "BENCH_TPU.json").read_text())
-    assert d["source"].startswith("auto"), d["source"]
-    # provenance keys must be PRESENT (a hand-edited or fingerprint-
-    # failed record simply lacks them — absence must fail the gate)
-    assert d.get("recorded_dirty") is False, (
-        "canonical record lacks clean-tree provenance — re-record it "
-        "from a clean tree (rm .bench_cache/stamps/canonical, then let "
-        "tools/tpu_window_payload.sh run at the next window)")
-    assert "device_path_fp" in d
-    assert d["detail"]["backend"] == "tpu"
-    # bench.py always writes this key; absence means a hand-edit
-    assert d["detail"]["cpu_fallback"] is False

@@ -148,11 +148,10 @@ def test_pallas_gather_mean_interpret():
     got16 = _pallas_gather_mean(table, rows, tile_n=16, interpret=True)
     np.testing.assert_allclose(np.asarray(got16), np.asarray(ref),
                                atol=1e-6)
-    # public entry falls back to XLA off-TPU
+    # public entry defaults to the XLA formulation
     np.testing.assert_allclose(np.asarray(gather_mean(table, rows)),
                                np.asarray(ref), atol=1e-6)
-    # single-semaphore layout (mosaic-crash workaround candidate):
-    # identical numerics by construction
+    # single-semaphore layout: identical numerics by construction
     got1s = _pallas_gather_mean(table, rows, interpret=True, one_sem=True)
     np.testing.assert_allclose(np.asarray(got1s), np.asarray(ref),
                                atol=1e-6)

@@ -2336,16 +2336,8 @@ def main(argv=None):
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices",
-                              max(int(args.partition), 2))
-        except Exception:  # older jax raises on the unknown option
-            import os
-
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count="
-                + str(max(int(args.partition), 2)))
+        jax.config.update("jax_num_cpu_devices",
+                          max(int(args.partition), 2))
         bench_table(args)
         return
     if args.mode == "fanout":

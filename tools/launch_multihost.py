@@ -4,9 +4,15 @@ processes).
 
 Two modes:
 
-  * --local N : spawn N worker processes on THIS machine (CPU backend,
-    one device each) that join one jax.distributed job — the smoke path
-    used by tests/test_multihost.py.
+  * --local N : spawn N worker processes on THIS machine that join one
+    jax.distributed job — the smoke path used by
+    tests/test_multihost.py. The local workers ALWAYS run the CPU
+    backend (the launcher exports JAX_PLATFORMS=cpu and each worker
+    forces it again before its first device query): a chip belongs to
+    one process at a time, so N local children could not share one.
+    The launcher process itself never touches jax. Real multi-host
+    runs use print mode, one worker per machine, each owning that
+    machine's chips.
   * print mode (default): emit the per-host command lines + env to run
     on each machine of a real pod/cluster.
 
