@@ -31,6 +31,19 @@ from flax.training import train_state
 from euler_tpu import obs as _obs
 from euler_tpu.utils import optimizers as opt_lib
 
+# the spans' second sink: while a jax.profiler session runs, every
+# obs.span is also an "euler.<name>" event on that session's host plane,
+# on the device plane's clock (obs itself imports no jax)
+_obs.install_profiler_annotation(jax.profiler.TraceAnnotation)
+# the device program's kernels are found in a profile by their
+# jax.named_scope names (draw/hop<h>, gather/hop<h>, cache, update ...),
+# which are op metadata. jax leaves metadata out of the compile cache's
+# key by default, so an executable cached before a scope was added or
+# renamed would carry the old names into every later profile (seen on the
+# chip, PR 24: a cache that came with the machine served the unnamed
+# program). The price: an edit that only moves lines recompiles once.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 # per-process estimator numbering: the label value distinguishing N
 # estimators' children on the shared estimator_* metrics
 _EST_IDS = itertools.count()
@@ -199,7 +212,15 @@ class BaseEstimator:
             "host→device conversion)", ("estimator",)).labels(**lab)
         self._hist_device_step = reg.histogram(
             "estimator_device_step_ms",
-            "per-step train-step dispatch", ("estimator",)).labels(**lab)
+            "host time to ENQUEUE one train dispatch (a single step, or "
+            "a scanned window of steps_per_loop): the call is "
+            "asynchronous, the device's time is not in it",
+            ("estimator",)).labels(**lab)
+        self._hist_result_wait = reg.histogram(
+            "estimator_result_wait_ms",
+            "blocking fetch of a scanned window's losses: the host "
+            "waiting for the device to finish the window",
+            ("estimator",)).labels(**lab)
         self._hist_hook = reg.histogram(
             "estimator_hook_ms",
             "per-step logging/checkpoint hooks", ("estimator",)
@@ -295,7 +316,8 @@ class BaseEstimator:
                 loss_fn, has_aux=True)(state.params)
 
             def apply_update(_):
-                s2 = state.apply_gradients(grads=grads)
+                with jax.named_scope("update"):
+                    s2 = state.apply_gradients(grads=grads)
                 if new_vars:
                     s2 = s2.replace(extra_vars=dict(new_vars))
                 return s2
@@ -312,10 +334,12 @@ class BaseEstimator:
                 # guard the GRADS too: overflow in the backward pass can
                 # yield NaN grads under a finite loss, which would poison
                 # the donated params with skipped_steps still reading 0
-                ok = jnp.isfinite(loss)
-                for g in jax.tree_util.tree_leaves(grads):
-                    ok &= jnp.all(jnp.isfinite(g))
-                state = jax.lax.cond(ok, apply_update, skip_update, None)
+                with jax.named_scope("guard"):
+                    ok = jnp.isfinite(loss)
+                    for g in jax.tree_util.tree_leaves(grads):
+                        ok &= jnp.all(jnp.isfinite(g))
+                    state = jax.lax.cond(ok, apply_update, skip_update,
+                                         None)
             else:
                 state = apply_update(None)
             return state, loss, out.metric
@@ -478,9 +502,9 @@ class BaseEstimator:
 
     def _phase(self, name: str, hist):
         """Span + histogram for one train-loop phase (input_wait /
-        device_step / hook). obs.timed_span never swallows exceptions —
-        a StopIteration from the input iterator propagates to the
-        loops' break handlers unchanged."""
+        device_step / result_wait / hook). obs.timed_span never swallows
+        exceptions — a StopIteration from the input iterator propagates
+        to the loops' break handlers unchanged."""
         return _obs.timed_span(name, hist, estimator=self._obs_name)
 
     def _emergency_checkpoint(self, err: BaseException) -> None:
@@ -735,67 +759,84 @@ class BaseEstimator:
 
         while step < max_steps:
             want = min(K, max_steps - step)
-            if len(buf) < want and not exhausted:
-                with self._phase("input_wait", self._hist_input_wait):
-                    while len(buf) < want and not exhausted:
-                        try:
-                            raw, it = self._next_input(it)
-                            buf.append(_to_device_tree(raw, self.max_id))
-                        except StopIteration:
-                            exhausted = True
-            if not buf:
-                break
-            if len(buf) == K:
-                if self._train_loop is None:
-                    self._train_loop = self._build_train_loop()
-                stacked = jax.tree_util.tree_map(stack, *buf)
-                with self._phase("device_step", self._hist_device_step):
-                    self.state, l_arr, m_arr = self._train_loop(
-                        self.state, stacked, self.static_batch)
-                # nanmean / last-finite: guard-skipped steps inside the
-                # scanned window report NaN and must not poison the
-                # window aggregate or the reported final loss
-                loop_losses.append((jnp.nanmean(l_arr), K))
-                loop_metrics.append((jnp.nanmean(m_arr), K))
-                fin = np.asarray(l_arr)
-                fin = fin[np.isfinite(fin)]
-                if fin.size:
-                    last_loss = float(fin[-1])
-                done = K
-            else:
-                # tail shorter than K: single-step dispatches (the jit
-                # was built in train() before this path was entered)
-                for b in buf:
+            # one parent per window; input_wait, stack, device_step,
+            # result_wait and hook follow each other under it with
+            # nothing between them, so a device-idle gap inside a window
+            # always lies under one named phase
+            with _obs.span("train_dispatch", estimator=self._obs_name,
+                           step=step, K=want):
+                if len(buf) < want and not exhausted:
+                    with self._phase("input_wait", self._hist_input_wait):
+                        while len(buf) < want and not exhausted:
+                            try:
+                                raw, it = self._next_input(it)
+                                buf.append(
+                                    _to_device_tree(raw, self.max_id))
+                            except StopIteration:
+                                exhausted = True
+                if not buf:
+                    break
+                if len(buf) == K:
+                    if self._train_loop is None:
+                        self._train_loop = self._build_train_loop()
+                    with _obs.span("stack", estimator=self._obs_name):
+                        stacked = jax.tree_util.tree_map(stack, *buf)
                     with self._phase("device_step",
                                      self._hist_device_step):
-                        self.state, l, m = self._train_step(
-                            self.state, _merged(b, self.static_batch))
-                    loop_losses.append((l, 1))
-                    loop_metrics.append((m, 1))
-                    if np.isfinite(float(l)):
-                        last_loss = float(l)
-                done = len(buf)
-            prev = step
-            step += done
-            buf = []
-            do_log = step - logged_at >= self.log_steps
-            do_ckpt = self.ckpt_steps and \
-                step // self.ckpt_steps > prev // self.ckpt_steps
-            if do_log or do_ckpt:
-                with self._phase("hook", self._hist_hook):
-                    if do_log:
-                        now = time.monotonic()
-                        rate = (step - logged_at) / max(now - last_log,
-                                                        1e-9)
-                        self._g_steps_per_sec.set(rate)
-                        self._g_skipped_steps.set(self._skipped_steps())
-                        print(f"step {step}: "
-                              f"loss={float(loop_losses[-1][0]):.4f} "
-                              f"metric={float(loop_metrics[-1][0]):.4f} "
-                              f"({rate:.1f} steps/s)", flush=True)
-                        last_log, logged_at = now, step
-                    if do_ckpt:
-                        self.save_checkpoint(step)
+                        self.state, l_arr, m_arr = self._train_loop(
+                            self.state, stacked, self.static_batch)
+                    with self._phase("result_wait",
+                                     self._hist_result_wait):
+                        # nanmean / last-finite: guard-skipped steps
+                        # inside the scanned window report NaN and must
+                        # not poison the window aggregate or the
+                        # reported final loss
+                        loop_losses.append((jnp.nanmean(l_arr), K))
+                        loop_metrics.append((jnp.nanmean(m_arr), K))
+                        # the one place the host waits for the chip
+                        fin = np.asarray(l_arr)
+                    fin = fin[np.isfinite(fin)]
+                    if fin.size:
+                        last_loss = float(fin[-1])
+                    done = K
+                else:
+                    # tail shorter than K: single-step dispatches (the
+                    # jit was built in train() before this path was
+                    # entered)
+                    for b in buf:
+                        with self._phase("device_step",
+                                         self._hist_device_step):
+                            self.state, l, m = self._train_step(
+                                self.state,
+                                _merged(b, self.static_batch))
+                        loop_losses.append((l, 1))
+                        loop_metrics.append((m, 1))
+                        if np.isfinite(float(l)):
+                            last_loss = float(l)
+                    done = len(buf)
+                prev = step
+                step += done
+                buf = []
+                do_log = step - logged_at >= self.log_steps
+                do_ckpt = self.ckpt_steps and \
+                    step // self.ckpt_steps > prev // self.ckpt_steps
+                if do_log or do_ckpt:
+                    with self._phase("hook", self._hist_hook):
+                        if do_log:
+                            now = time.monotonic()
+                            rate = (step - logged_at) / max(
+                                now - last_log, 1e-9)
+                            self._g_steps_per_sec.set(rate)
+                            self._g_skipped_steps.set(
+                                self._skipped_steps())
+                            print(f"step {step}: "
+                                  f"loss={float(loop_losses[-1][0]):.4f} "
+                                  f"metric="
+                                  f"{float(loop_metrics[-1][0]):.4f} "
+                                  f"({rate:.1f} steps/s)", flush=True)
+                            last_log, logged_at = now, step
+                        if do_ckpt:
+                            self.save_checkpoint(step)
             if exhausted:
                 break
         if self.ckpt_steps:

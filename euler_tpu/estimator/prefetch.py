@@ -25,7 +25,44 @@ import queue
 import threading
 from typing import Callable, Iterator, Optional, Union
 
+from euler_tpu import obs as _obs
+
 _FEEDER_IDS = itertools.count()
+
+
+class _FeederObs:
+    """What both feeders report through euler_tpu.obs, children labeled
+    feeder=<name>: feeder_queue_depth (ready batches waiting for the
+    consumer), feeder_batches_total (batches handed to it) and
+    feeder_produce_ms (one batch: pulled from the source and
+    transformed), the last also as the span `feeder_produce` with its
+    child `feeder_transform` on the producing thread."""
+
+    def __init__(self, name: Optional[str] = None):
+        self.name = name or f"feeder{next(_FEEDER_IDS)}"
+        reg = _obs.default_registry()
+        lab = {"feeder": self.name}
+        self.depth = reg.gauge(
+            "feeder_queue_depth",
+            "ready batches waiting for the consumer",
+            ("feeder",)).labels(**lab)
+        self.batches = reg.counter(
+            "feeder_batches_total", "batches produced by feeder workers",
+            ("feeder",)).labels(**lab)
+        self.produce_ms = reg.histogram(
+            "feeder_produce_ms",
+            "one batch pulled from the source and transformed, on the "
+            "producing thread", ("feeder",)).labels(**lab)
+
+    def produce(self):
+        return _obs.timed_span("feeder_produce", self.produce_ms,
+                               feeder=self.name)
+
+    def transform(self, fn, item):
+        if fn is None:
+            return item
+        with _obs.span("feeder_transform", feeder=self.name):
+            return fn(item)
 
 
 class Prefetcher:
@@ -37,25 +74,37 @@ class Prefetcher:
         """transform (optional) runs on each batch IN the prefetch thread —
         pass jax.device_put to overlap host→device transfer with device
         compute, not just graph sampling."""
-        self._it = it
+        self._it = iter(it)
         self._transform = transform
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._err = None
         self._closed = threading.Event()
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._obs = _FeederObs()
+        self._thread = threading.Thread(
+            target=self._run, daemon=True,
+            name=f"euler-{self._obs.name}")
         self._thread.start()
+
+    def _produce(self):
+        """The next transformed batch (StopIteration at the source's
+        end), under the feeder_produce span."""
+        with self._obs.produce():
+            return self._obs.transform(self._transform, next(self._it))
 
     def _run(self):
         try:
-            for item in self._it:
-                if self._transform is not None:
-                    item = self._transform(item)
+            while True:
+                try:
+                    item = self._produce()
+                except StopIteration:
+                    break
                 # bounded put that can be interrupted: close() sets the
                 # flag and drains, so a producer parked on a full queue
                 # always wakes up and exits instead of leaking
                 while not self._closed.is_set():
                     try:
                         self._q.put(item, timeout=0.1)
+                        self._obs.depth.set(self._q.qsize())
                         break
                     except queue.Full:
                         continue
@@ -78,10 +127,12 @@ class Prefetcher:
         if self._closed.is_set():
             raise StopIteration
         item = self._q.get()
+        self._obs.depth.set(self._q.qsize())
         if item is self._STOP:
             if self._err is not None:
                 raise self._err
             raise StopIteration
+        self._obs.batches.inc()
         return item
 
     def close(self) -> None:
@@ -95,6 +146,7 @@ class Prefetcher:
             except queue.Empty:
                 pass
             self._thread.join(0.05)
+        self._obs.depth.set(0)
 
     def __enter__(self) -> "Prefetcher":
         return self
@@ -123,8 +175,7 @@ class ParallelPrefetcher:
     retry without tearing the feeder down. StopIteration from an
     iterator source ends the stream.
 
-    Reports feeder_queue_depth{feeder=...} (ready batches waiting) and
-    feeder_batches_total through euler_tpu.obs.
+    Reports through euler_tpu.obs what Prefetcher reports (_FeederObs).
     """
 
     # a raised batch does NOT kill the stream — the estimator's input
@@ -134,8 +185,6 @@ class ParallelPrefetcher:
     def __init__(self, source: Union[Callable, Iterator],
                  workers: int = 4, depth: Optional[int] = None,
                  transform=None, name: Optional[str] = None):
-        from euler_tpu import obs as _obs
-
         self._transform = transform
         if callable(source):
             self._pull = source
@@ -158,19 +207,10 @@ class ParallelPrefetcher:
         self._ready = {}           # seq -> (kind, payload)
         self._closed = False
         self._ended = False        # iterator source exhausted
-        self._name = name or f"feeder{next(_FEEDER_IDS)}"
-        reg = _obs.default_registry()
-        lab = {"feeder": self._name}
-        self._g_depth = reg.gauge(
-            "feeder_queue_depth",
-            "ready batches parked in the reorder buffer",
-            ("feeder",)).labels(**lab)
-        self._ctr_batches = reg.counter(
-            "feeder_batches_total", "batches produced by feeder workers",
-            ("feeder",)).labels(**lab)
+        self._obs = _FeederObs(name)
         self._threads = [
             threading.Thread(target=self._work, daemon=True,
-                             name=f"euler-{self._name}-{i}")
+                             name=f"euler-{self._obs.name}-{i}")
             for i in range(self.workers)]
         for t in self._threads:
             t.start()
@@ -190,47 +230,54 @@ class ParallelPrefetcher:
             return seq
 
     def _claim_and_pull(self):
-        """(seq, result) — factory mode claims then pulls concurrently;
-        iterator mode does both under the pull lock so ticket order ==
-        source order (and "end" is provably the LAST ticket)."""
+        """(seq, result, span) — factory mode claims then pulls
+        concurrently; iterator mode does both under the pull lock so
+        ticket order == source order (and "end" is provably the LAST
+        ticket). `span` is the batch's feeder_produce span, entered once
+        the ticket is claimed (the wait for a ticket is back-pressure,
+        not production); the caller leaves it after the transform."""
         if self._pull_mu is None:
             seq = self._claim()
             if seq is None:
-                return None, None
+                return None, None, None
         else:
             self._pull_mu.acquire()
         try:
             if self._pull_mu is not None:
                 seq = self._claim()
                 if seq is None:
-                    return None, None
+                    return None, None, None
+            span = self._obs.produce()
+            span.__enter__()
             try:
-                return seq, ("ok", self._pull())
+                return seq, ("ok", self._pull()), span
             except StopIteration:
-                return seq, ("end", None)
+                return seq, ("end", None), span
             except BaseException as e:   # delivered in-order, once
-                return seq, ("err", e)
+                return seq, ("err", e), span
         finally:
             if self._pull_mu is not None:
                 self._pull_mu.release()
 
     def _work(self):
         while True:
-            seq, res = self._claim_and_pull()
+            seq, res, span = self._claim_and_pull()
             if seq is None:
                 return
             # transform stays OUTSIDE the pull lock: in iterator mode
             # it is the part that actually parallelizes
-            if res[0] == "ok" and self._transform is not None:
+            if res[0] == "ok":
                 try:
-                    res = ("ok", self._transform(res[1]))
+                    res = ("ok", self._obs.transform(
+                        self._transform, res[1]))
                 except BaseException as e:
                     res = ("err", e)
+            span.__exit__(None, None, None)
             with self._cond:
                 if self._closed:
                     return
                 self._ready[seq] = res
-                self._g_depth.set(len(self._ready))
+                self._obs.depth.set(len(self._ready))
                 self._cond.notify_all()
                 if res[0] == "end":
                     self._ended = True
@@ -251,11 +298,11 @@ class ParallelPrefetcher:
                     self._cond.wait(0.1)
                     continue
                 self._next_out += 1
-                self._g_depth.set(len(self._ready))
+                self._obs.depth.set(len(self._ready))
                 self._cond.notify_all()
                 kind, payload = res
                 if kind == "ok":
-                    self._ctr_batches.inc()
+                    self._obs.batches.inc()
                     return payload
                 if kind == "end":
                     # workers past the end parked "end" too; everything
@@ -269,7 +316,7 @@ class ParallelPrefetcher:
         with self._cond:
             self._closed = True
             self._ready.clear()
-            self._g_depth.set(0)
+            self._obs.depth.set(0)
             self._cond.notify_all()
         for t in self._threads:
             t.join(5.0)

@@ -42,9 +42,14 @@ def gather_feature_rows(batch: Dict[str, Any], rows, gather=None):
 
         take = hub_routed_take(take, hub)
     scale = batch.get("feature_scale")
-    if scale is None:
-        return [take(table, r) for r in rows]
-    return [dequantize_rows(take(table, r), scale) for r in rows]
+    out = []
+    for hop, r in enumerate(rows):
+        # one name per element of `rows` (hop 0: the roots); the
+        # dequantise multiply stays inside it
+        with jax.named_scope(f"gather/hop{hop}"):
+            x = take(table, r)
+            out.append(x if scale is None else dequantize_rows(x, scale))
+    return out
 
 
 def _fanout_layers(batch: Dict[str, Any]):
@@ -227,7 +232,7 @@ class DeviceSampledScalableSage(SuperviseModel):
         import jax.numpy as jnp
 
         from euler_tpu.parallel.device_sampler import (
-            is_model_sharded, make_table_gather, sample_hop,
+            draw_scope, is_model_sharded, make_table_gather, sample_hop,
             sample_hop_fused,
         )
 
@@ -236,16 +241,17 @@ class DeviceSampledScalableSage(SuperviseModel):
         key = jax.random.fold_in(jax.random.key(17), batch["sample_seed"])
         gather = make_table_gather(self.table_mesh)
         tg = gather if is_model_sharded(self.table_mesh) else None
-        if batch.get("nbrcum_table") is not None:
-            nbr = sample_hop_fused(batch["nbrcum_table"], roots,
-                                   int(self.fanout), key, tg)
-        else:
-            atab = batch.get("alias_table") if tg is None else None
-            nbr = sample_hop(batch["nbr_table"], batch["cum_table"],
-                             roots, int(self.fanout), key, tg,
-                             uniform=self.uniform_sampling
-                             and tg is None and atab is None,
-                             alias_table=atab)
+        with draw_scope(1):
+            if batch.get("nbrcum_table") is not None:
+                nbr = sample_hop_fused(batch["nbrcum_table"], roots,
+                                       int(self.fanout), key, tg)
+            else:
+                atab = batch.get("alias_table") if tg is None else None
+                nbr = sample_hop(batch["nbr_table"], batch["cum_table"],
+                                 roots, int(self.fanout), key, tg,
+                                 uniform=self.uniform_sampling
+                                 and tg is None and atab is None,
+                                 alias_table=atab)
         x, nbr_x = gather_feature_rows(batch, [roots, nbr], gather=gather)
         if self.encoder == "gcn":
             from euler_tpu.utils.encoders import ScalableGCNEncoder
@@ -459,14 +465,15 @@ class DeviceSampledUnsupervisedSage(nn.Module):
         layers = gather_feature_rows(batch, rows, gather=gather)
         emb = SageEncoder(self.dim, tuple(self.fanouts), self.aggregator,
                           concat=False, name="encoder")(layers)   # [B, D]
-        if fused_tab is not None:
-            pos_r = sample_hop_fused(fused_tab, roots, 1, kp, tg)  # [B]
-        else:
-            pos_r = sample_hop(batch["nbr_table"], batch["cum_table"],
-                               roots, 1, kp, gather=tg,
-                               uniform=self.uniform_sampling
-                               and tg is None and atab is None,
-                               alias_table=atab)                  # [B]
+        with jax.named_scope("draw/pos"):
+            if fused_tab is not None:
+                pos_r = sample_hop_fused(fused_tab, roots, 1, kp, tg)
+            else:
+                pos_r = sample_hop(batch["nbr_table"], batch["cum_table"],
+                                   roots, 1, kp, gather=tg,
+                                   uniform=self.uniform_sampling
+                                   and tg is None and atab is None,
+                                   alias_table=atab)              # [B]
         negs_r = sample_global_rows(batch["neg_rows"], batch["neg_cum"],
                                     kn, (roots.shape[0], self.num_negs))
         ctx = Embedding(self.num_rows + 1, self.dim, name="ctx_emb")

@@ -64,8 +64,9 @@ class SuperviseModel(nn.Module):
         if labels is None:
             # device-resident label table (DeviceFeatureStore): gather the
             # root rows in-jit instead of shipping labels from the host
-            labels = self.table_gather()(batch["label_table"],
-                                         batch["rows"][0])
+            with jax.named_scope("labels"):
+                labels = self.table_gather()(batch["label_table"],
+                                             batch["rows"][0])
         logits = nn.Dense(self.num_classes, name="out")(emb)
         # optional [B] 0/1 metric_mask: padded rows (deterministic eval
         # sweeps pad the final chunk to the static batch shape) drop out
@@ -75,24 +76,27 @@ class SuperviseModel(nn.Module):
         def wmean(per_row):
             return M.masked_mean(per_row, mask)
 
-        if self.multilabel:
-            loss = wmean(optax.sigmoid_binary_cross_entropy(
-                logits, labels.astype(jnp.float32)).sum(-1))
-            metric = M.micro_f1(jax.nn.sigmoid(logits), labels, mask=mask)
-            name = "f1"
-        else:
-            # labels arrive either as integer classes [B] or one-hot [B, C]
-            # (dense label features are stored one-hot)
-            if labels.ndim == logits.ndim:
-                loss = wmean(optax.softmax_cross_entropy(
-                    logits, labels.astype(jnp.float32)))
-                int_labels = jnp.argmax(labels, axis=-1)
+        with jax.named_scope("loss"):
+            if self.multilabel:
+                loss = wmean(optax.sigmoid_binary_cross_entropy(
+                    logits, labels.astype(jnp.float32)).sum(-1))
+                metric = M.micro_f1(jax.nn.sigmoid(logits), labels,
+                                    mask=mask)
+                name = "f1"
             else:
-                int_labels = labels.astype(jnp.int32)
-                loss = wmean(optax.softmax_cross_entropy_with_integer_labels(
-                    logits, int_labels))
-            metric = M.micro_f1(logits, int_labels, mask=mask)
-            name = "f1"
+                # labels arrive either as integer classes [B] or one-hot
+                # [B, C] (dense label features are stored one-hot)
+                if labels.ndim == logits.ndim:
+                    loss = wmean(optax.softmax_cross_entropy(
+                        logits, labels.astype(jnp.float32)))
+                    int_labels = jnp.argmax(labels, axis=-1)
+                else:
+                    int_labels = labels.astype(jnp.int32)
+                    loss = wmean(
+                        optax.softmax_cross_entropy_with_integer_labels(
+                            logits, int_labels))
+                metric = M.micro_f1(logits, int_labels, mask=mask)
+                name = "f1"
         return ModelOutput(emb, loss, name, metric)
 
 

@@ -27,15 +27,31 @@ tracer — what the wired layers use)::
 
 Wired out of the box: `graph/remote.py` (per-call spans; retry /
 failover / degrade counters — `health()` is a view over these),
-`estimator/base_estimator.py` (per-step `input_wait` / `device_step` /
-`hook` phase spans + histograms), `parallel/train.py`, `gql.py`
-(engine-side Query.stats() + UDF-cache gauges via collectors),
-`graph/chaos.py` (`chaos_injected_total{kind=...}`), and `bench.py`
-(`detail.obs` snapshot on every artifact; `--trace out.json`).
+`estimator/base_estimator.py` (single-step path: `train_step` →
+`device_step` / `hook` / `input_wait`; scanned path, one parent
+`train_dispatch` a window of steps_per_loop → `input_wait` / `stack` /
+`device_step` (the enqueue) / `result_wait` (the host waiting for the
+chip) / `hook`, back to back; histograms estimator_input_wait_ms,
+_device_step_ms, _result_wait_ms, _hook_ms), `estimator/prefetch.py`
+(both feeders, on the producing thread: `feeder_produce` →
+`feeder_transform`; feeder_produce_ms, feeder_queue_depth,
+feeder_batches_total), `parallel/train.py`, `gql.py` (engine-side
+Query.stats() + UDF-cache gauges via collectors), `graph/chaos.py`
+(`chaos_injected_total{kind=...}`), and `bench.py` (`detail.obs`
+snapshot on every artifact; `--trace out.json`).
 
-`obs.disable()` turns the span path into a shared no-op (~0.1µs/call);
-counters stay live — they are the health() bookkeeping. See PERF.md
-"observability overhead" for measured costs.
+Two sinks, one API. Every span lands in the ring (`dump_trace`). Once
+the jax side has called `install_profiler_annotation` (importing
+euler_tpu.estimator does), a span opened while a jax.profiler session
+runs is also the event `euler.<name>` on that session's host plane, on
+its own thread and on the device plane's clock, so a device-idle gap
+can be put down to the phase the host was in (benchmark/scope_readers.py
+reads them). This package imports no jax.
+
+`obs.disable()` turns the span path into a shared no-op; counters stay
+live — they are the health() bookkeeping. Cost of one span on this
+sandbox's CPU (PR 24): 0.26 µs disabled, 2.4 µs ring only, 3.5 µs with
+the profiler sink installed and no session, 4.7 µs inside a session.
 """
 
 from __future__ import annotations
@@ -66,7 +82,8 @@ __all__ = [
     "ObsServer", "default_registry", "default_tracer", "counter", "gauge",
     "histogram", "span", "timed_span", "serve", "snapshot",
     "snapshot_delta", "render_prometheus", "dump_trace", "clear_trace",
-    "enable", "disable", "enabled", "register_health",
+    "enable", "disable", "enabled", "install_profiler_annotation",
+    "register_health",
     "unregister_health", "health_snapshot", "log2_buckets",
     "DEFAULT_MS_BUCKETS", "reset_for_tests",
 ]
@@ -167,6 +184,18 @@ def dump_trace(path: str) -> str:
 def clear_trace() -> None:
     """Drop all finished spans (start of a measured region)."""
     default_tracer().clear()
+
+
+# -- the profiler's clock --------------------------------------------------
+def install_profiler_annotation(factory) -> None:
+    """Give every span of the default tracer a second sink:
+    `factory("euler.<name>", **attrs)` must return a context manager,
+    entered and left with the span on the span's thread. The jax-side
+    code (estimator/base_estimator.py) hands jax.profiler.TraceAnnotation
+    in, so a span opened during a jax.profiler session is an event of
+    that session's host plane; obs itself never imports jax. None takes
+    the sink away (as does reset_for_tests(): a fresh tracer has none)."""
+    default_tracer().annotate = factory
 
 
 # -- global switch ---------------------------------------------------------

@@ -14,10 +14,20 @@ host). `chrome_trace()` / `export()` render the ring as the Trace Event
 Format JSON that chrome://tracing and https://ui.perfetto.dev load
 directly — complete "X" (duration) events with microsecond `ts`/`dur`.
 
+A second sink, on the profiler's clock: `Tracer.annotate` holds a
+factory of context managers (jax.profiler.TraceAnnotation, installed by
+the jax-side code through obs.install_profiler_annotation — this
+module never imports jax). While it is set every span also enters
+`factory("euler.<name>", **attrs)`, so a span opened during a
+jax.profiler session shows on the host plane of that session's
+.xplane.pb, on the thread that opened it and on the same clock as the
+device plane; outside a session the annotation is the profiler's own
+no-op.
+
 Disabled-path cost: when the tracer (or the whole subsystem, see
 euler_tpu.obs.disable()) is off, `span()` returns a shared no-op
-singleton — one attribute check, no allocation (measured ~0.1µs/call;
-PERF.md "observability overhead").
+singleton — one attribute check, no allocation (~0.1µs/call; PERF.md
+section 6, PR 24, has the costs of the enabled paths).
 """
 
 from __future__ import annotations
@@ -32,7 +42,10 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["Tracer", "Span", "NULL_SPAN"]
+__all__ = ["Tracer", "Span", "NULL_SPAN", "ANNOTATION_PREFIX"]
+
+# what a span is called on the profiler's host plane: "euler." + name
+ANNOTATION_PREFIX = "euler."
 
 
 class _NullSpan:
@@ -67,7 +80,7 @@ class Span:
     timing breakdown stitches under this span in a merged trace."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
-                 "trace_id", "_t0", "ts_us", "dur_us", "tid")
+                 "trace_id", "_t0", "ts_us", "dur_us", "tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict):
         self._tracer = tracer
@@ -80,6 +93,7 @@ class Span:
         self.ts_us = 0.0
         self.dur_us = 0.0
         self.tid = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -97,12 +111,21 @@ class Span:
             self.trace_id = tr._trace_base + next(tr._trace_ids)
         stack.append(self)
         self.tid = threading.get_ident()
+        if tr.annotate is not None:
+            # entered last and left first: the profiler's event lies
+            # inside the ring's interval
+            self._ann = tr.annotate(ANNOTATION_PREFIX + self.name,
+                                    **self.attrs)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         self.ts_us = (self._t0 - tr._epoch) * 1e6
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         tr = self._tracer
         stack = tr._stack()
         # pop self even if an inner span leaked (defensive: a span that
@@ -128,6 +151,9 @@ class Tracer:
         self._epoch = time.perf_counter()
         self._epoch_unix = time.time()
         self.enabled = True
+        # the profiler-side sink (see the module docstring); None: ring
+        # only
+        self.annotate = None
 
     # -- recording ---------------------------------------------------------
     def _stack(self) -> list:

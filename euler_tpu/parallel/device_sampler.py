@@ -722,6 +722,14 @@ def fuse_tables(nbr_tab, cum_tab):
     return jnp.concatenate([nbr.astype(jnp.int32), cum_bits], axis=1)
 
 
+def draw_scope(hop: int):
+    """The name the device program's ops of hop `hop`'s neighbour draw
+    carry (`draw/hop<h>`, h from 1), whichever draw it takes: uniform,
+    inverse-CDF, alias or fused. The trace's per-kernel metrics find the
+    draw by it (benchmark/scope_readers.py)."""
+    return jax.named_scope(f"draw/hop{hop}")
+
+
 def sample_hop_fused(fused_table: jax.Array, rows: jax.Array,
                      count: int, key, gather=None) -> jax.Array:
     """sample_hop over a fuse_tables() layout: one row gather yields
@@ -752,9 +760,10 @@ def sample_fanout_rows_fused(fused_table: jax.Array, roots: jax.Array,
     """sample_fanout_rows over a fuse_tables() layout."""
     layers = [roots]
     cur = roots
-    for k in fanouts:
+    for hop, k in enumerate(fanouts, 1):
         key, sub = jax.random.split(key)
-        cur = sample_hop_fused(fused_table, cur, int(k), sub, gather)
+        with draw_scope(hop):
+            cur = sample_hop_fused(fused_table, cur, int(k), sub, gather)
         layers.append(cur)
     return layers
 
@@ -1010,9 +1019,11 @@ def sample_fanout_rows(nbr_table: jax.Array, cum_table: jax.Array,
     alias_table → the O(1) alias draw per hop (see sample_hop)."""
     layers = [roots]
     cur = roots
-    for k in fanouts:
+    for hop, k in enumerate(fanouts, 1):
         key, sub = jax.random.split(key)
-        cur = sample_hop(nbr_table, cum_table, cur, int(k), sub, gather,
-                         uniform=uniform, alias_table=alias_table)
+        with draw_scope(hop):
+            cur = sample_hop(nbr_table, cum_table, cur, int(k), sub,
+                             gather, uniform=uniform,
+                             alias_table=alias_table)
         layers.append(cur)
     return layers

@@ -210,9 +210,10 @@ class ScalableGCNEncoder(nn.Module):
                 h_self = nn.relu(h_self)
                 # store this batch's layer-(l+1) input activations
                 store = caches[layer + 1]
-                old = store(ids)
-                new = _ema_update(old, h_self, self.store_decay)
-                store(ids, write_ids=ids, write_vals=new)
+                with jax.named_scope("cache"):
+                    old = store(ids)
+                    new = _ema_update(old, h_self, self.store_decay)
+                    store(ids, write_ids=ids, write_vals=new)
         return h_self
 
 
@@ -245,9 +246,10 @@ class ScalableSageEncoder(nn.Module):
             if layer < self.num_layers - 1:
                 h_new = nn.relu(h_new)
                 store = caches[layer + 1]
-                old = store(ids)
-                upd = _ema_update(old, h_new, self.store_decay)
-                store(ids, write_ids=ids, write_vals=upd)
+                with jax.named_scope("cache"):
+                    old = store(ids)
+                    upd = _ema_update(old, h_new, self.store_decay)
+                    store(ids, write_ids=ids, write_vals=upd)
             h_self = h_new
         return h_self
 

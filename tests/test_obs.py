@@ -654,3 +654,169 @@ def test_disabled_path_cost_is_tiny():
         assert per_call_us < 5.0, per_call_us
     finally:
         obs.enable()
+
+
+# ---------------------------------------------------------------------------
+# the second sink: spans on the profiler's clock (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """[(line index, name, start_ns, end_ns, stats)] of the host planes of
+    the one .xplane.pb under trace_dir."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.append((i, ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_span_in_a_profiler_session_is_an_event_of_its_host_plane(
+        tmp_path):
+    import jax
+
+    import euler_tpu.estimator  # noqa: F401 - installs the bridge
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    obs.clear_trace()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("caller"):
+            with obs.span("probe", shard=3):
+                time.sleep(0.002)
+            obs.disable()
+            try:
+                with obs.span("hidden"):
+                    time.sleep(0.001)
+            finally:
+                obs.enable()
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (caller,) = [e for e in events if e[1] == "caller"]
+    (probe,) = [e for e in events if e[1] == "euler.probe"]
+    # same thread, nested in time inside the caller's own annotation
+    assert probe[0] == caller[0]
+    assert caller[2] <= probe[2] and probe[3] <= caller[3]
+    assert probe[3] - probe[2] >= 2e6
+    assert probe[4]["shard"] == 3
+    assert not [e for e in events if e[1] == "euler.hidden"]
+    # the ring keeps what it kept before
+    assert [s.name for s in obs.default_tracer().spans()] == ["probe"]
+    # outside a session the sink is the profiler's own no-op
+    with obs.span("after"):
+        pass
+    assert obs.default_tracer().spans()[-1].name == "after"
+
+
+def test_obs_imports_and_records_without_jax_or_the_bridge():
+    code = (
+        "import sys\n"
+        "from euler_tpu import obs\n"
+        "assert obs.default_tracer().annotate is None\n"
+        "with obs.span('outer', k=1):\n"
+        "    with obs.span('inner'):\n"
+        "        pass\n"
+        "inner, outer = obs.default_tracer().spans()\n"
+        "assert inner.parent_id == outer.span_id\n"
+        "assert 'jax' not in sys.modules, 'obs pulled jax in'\n")
+    res = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr
+
+
+def test_the_sink_is_entered_and_left_with_each_span():
+    seen = []
+
+    class Sink:
+        def __init__(self, name, **attrs):
+            self.what = (name, attrs)
+
+        def __enter__(self):
+            seen.append(("in",) + self.what)
+
+        def __exit__(self, *exc):
+            seen.append(("out",) + self.what)
+
+    import jax
+
+    try:
+        obs.install_profiler_annotation(Sink)
+        with obs.span("x", a=1):
+            pass
+        with obs.span("y"):
+            pass
+    finally:
+        obs.install_profiler_annotation(jax.profiler.TraceAnnotation)
+    assert seen == [("in", "euler.x", {"a": 1}), ("out", "euler.x", {"a": 1}),
+                    ("in", "euler.y", {}), ("out", "euler.y", {})]
+
+
+def test_scanned_dispatch_is_one_parent_fully_covered():
+    """One scanned est.train = exactly one train_dispatch; input_wait,
+    stack, device_step and result_wait are its children and leave under
+    5 % of it open (the input is slowed so the span's own cost is small
+    against it)."""
+    est, input_fn = _tiny_estimator(_tiny_citation().engine, sleep_s=0.01,
+                                    steps_per_loop=4)
+    est.train(input_fn, max_steps=4)        # init + compile, not timed
+    rw0 = est._hist_result_wait.value["count"]
+    obs.clear_trace()
+    res = est.train(input_fn, max_steps=8)
+    assert res["global_step"] == 8
+    spans = obs.default_tracer().spans()
+    (parent,) = [s for s in spans if s.name == "train_dispatch"]
+    assert parent.attrs["K"] == 4 and parent.attrs["step"] == 4
+    kids = [s for s in spans if s.parent_id == parent.span_id]
+    assert [s.name for s in sorted(kids, key=lambda s: s.ts_us)] == [
+        "input_wait", "stack", "device_step", "result_wait"]
+    covered = sum(s.dur_us for s in kids)
+    assert covered >= 0.95 * parent.dur_us, (covered, parent.dur_us)
+    assert covered <= parent.dur_us
+    # the first batch's wait is a span of the same thread, before it
+    first = [s for s in spans if s.name == "input_wait"
+             and s.parent_id == 0]
+    assert len(first) == 1 and first[0].tid == parent.tid
+    assert first[0].ts_us + first[0].dur_us <= parent.ts_us
+    assert est._hist_result_wait.value["count"] - rw0 == 1
+    assert "ENQUEUE" in obs.snapshot()["estimator_device_step_ms"]["help"]
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+def test_feeders_report_batches_and_produce_time(workers):
+    from euler_tpu.estimator.prefetch import make_feeder
+
+    def slow_double(x):
+        time.sleep(0.002)
+        return 2 * x
+
+    obs.clear_trace()
+    name = None
+    with make_feeder(iter(range(6)), workers=workers, depth=2,
+                     transform=slow_double) as feed:
+        name = feed._obs.name
+        assert list(feed) == [0, 2, 4, 6, 8, 10]
+    snap = obs.snapshot()
+    lbl = f"feeder={name}"
+    assert snap["feeder_batches_total"]["values"][lbl] == 6
+    assert snap["feeder_queue_depth"]["values"][lbl] == 0
+    hist = snap["feeder_produce_ms"]["values"][lbl]
+    assert hist["count"] >= 6 and hist["sum"] >= 6 * 2.0
+    spans = obs.default_tracer().spans()
+    produce = {s.span_id: s for s in spans if s.name == "feeder_produce"}
+    transform = [s for s in spans if s.name == "feeder_transform"]
+    assert len(transform) == 6
+    for s in transform:
+        assert s.parent_id in produce
+        assert s.tid == produce[s.parent_id].tid != threading.get_ident()
+        assert s.attrs["feeder"] == name
