@@ -21,6 +21,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.custom_derivatives import SymbolicZero
 
 from euler_tpu.utils.aggregators import get_aggregator
 from euler_tpu.utils.layers import AttLayer, Embedding, LSTMLayer, SparseEmbedding, bucketize_ids
@@ -141,9 +142,107 @@ def _ema_update(old: Array, fresh: Array, decay: float) -> Array:
     return jnp.where(seen, decay * old + (1 - decay) * fresh, fresh)
 
 
+def _write_read(cache: Array, rows: Array, vals: Array, read_rows: Array):
+    """new = cache with vals at rows; out = new[read_rows] (read_rows of
+    any shape); pos = the int32[N] table of which write owns each row
+    (len(rows) where none lands). rows may repeat: the write the table
+    names wins its row and the others write nowhere (row N is out of
+    bounds: dropped), so the stored value and the write the backward
+    pass pays are the same one."""
+    n, b = cache.shape[0], rows.shape[0]
+    with jax.named_scope("write"):
+        order = jnp.arange(b, dtype=jnp.int32)
+        pos = jnp.full((n,), b, jnp.int32).at[rows].set(order)
+        won = jnp.take(pos, rows) == order
+        new = cache.at[jnp.where(won, rows, n)].set(vals, mode="drop")
+    with jax.named_scope("read"):
+        out = jnp.take(new, read_rows, axis=0)
+    return new, out, pos
+
+
+@jax.custom_vjp
+def _cache_write_then_read(cache: Array, rows: Array, vals: Array,
+                           read_rows: Array) -> Tuple[Array, Array]:
+    """(cache.at[rows].set(vals), that[read_rows]) as ONE differentiable
+    operation: d vals[i] is the sum of the cotangents of the reads that
+    saw write i, found through a rows-long position table, so neither
+    pass builds anything of the cache's shape but the write itself.
+    cache and vals share a dtype. The cache is state, not a function of
+    the parameters: it takes and gives no cotangent."""
+    return _write_read(cache, rows, vals, read_rows)[:2]
+
+
+def _cache_write_then_read_fwd(cache, rows, vals, read_rows):
+    new, out, pos = _write_read(cache.value, rows.value, vals.value,
+                                read_rows.value)
+    with jax.named_scope("read"):
+        src = jnp.take(pos, read_rows.value)  # the write each read saw
+    return (new, out), (src, rows.value)
+
+
+# reads summed a loop turn in the backward pass (_sum_by_write)
+_GRAD_CHUNK = 8192
+
+
+def _sum_by_write(g: Array, src: Array, n_writes: int) -> Array:
+    """[n_writes, dim]: the rows g[..., :] summed by the write each read
+    saw (src, of g's leading shape; n_writes = saw none). Most reads see
+    none, and a scatter-add over all of them costs the chip a sort, a
+    gather and a scatter of EVERY row: so the reads are sorted by src
+    once, which puts the hits first, and only the chunks that hold a hit
+    are gathered and summed. How many that is is the batch's to say (a
+    while loop), so the sum is exact for any batch, all reads hitting
+    included."""
+    n = src.size
+    chunk = min(n, _GRAD_CHUNK)
+    pad = -n % chunk + chunk   # a slice that starts inside never clamps
+    hits = jnp.sum(src < n_writes)
+    seg, read = jax.lax.sort_key_val(src.ravel(),
+                                     jnp.arange(n, dtype=jnp.int32))
+    seg = jnp.concatenate([seg, jnp.full((pad,), n_writes, seg.dtype)])
+    read = jnp.concatenate([read, jnp.zeros((pad,), read.dtype)])
+
+    def add_chunk(c, acc):
+        at = jnp.unravel_index(
+            jax.lax.dynamic_slice(read, (c * chunk,), (chunk,)), src.shape)
+        # segment n_writes is out of range: dropped
+        return acc + jax.ops.segment_sum(
+            g[at], jax.lax.dynamic_slice(seg, (c * chunk,), (chunk,)),
+            num_segments=n_writes, indices_are_sorted=True)
+
+    return jax.lax.fori_loop(0, (hits + chunk - 1) // chunk, add_chunk,
+                             jnp.zeros((n_writes, g.shape[-1]), g.dtype))
+
+
+def _cache_write_then_read_bwd(res, cts):
+    src, rows = res
+    g_new, g_out = cts
+    if not isinstance(g_new, SymbolicZero):
+        raise NotImplementedError(
+            "the activation cache is state: differentiate the rows read "
+            "from it, not the table")
+    with jax.named_scope("grad"):
+        d_vals = _sum_by_write(g_out, src, rows.shape[0])
+    return None, None, d_vals, None
+
+
+_cache_write_then_read.defvjp(_cache_write_then_read_fwd,
+                              _cache_write_then_read_bwd,
+                              symbolic_zeros=True)
+
+
 class _ScalableCache(nn.Module):
     """Per-node activation cache: [max_id+1, dim] rows in the 'cache'
-    collection, read for neighbor ids, written for the batch's own ids.
+    collection. One call stores the batch's fresh activations at its own
+    ids (moving average with the old rows) and returns the rows of its
+    neighbours from the cache AS WRITTEN, for the next layer.
+
+    Write and read are one operation (_cache_write_then_read) because
+    the gradient runs through the pair: a neighbour that is also a root
+    of the step reads what this step wrote. Left to jax, the rule for a
+    scatter that may repeat an index masks the whole table and the read's
+    cotangent is scattered into a table of zeros: five [N+1, dim] passes
+    a step for a term that touches a few thousand rows (PERF.md, PR 25).
 
     dtype picks the stored row precision: bfloat16 halves the HBM
     footprint AND the per-step read bytes at products scale (the whole
@@ -153,24 +252,52 @@ class _ScalableCache(nn.Module):
     max_id: int
     dim: int
     dtype: Any = jnp.float32
+    decay: float = 0.9
 
     @nn.compact
-    def __call__(self, read_ids: Array, write_ids: Optional[Array] = None,
-                 write_vals: Optional[Array] = None) -> Array:
+    def __call__(self, ids: Array, fresh: Array, nbr_ids: Array) -> Array:
+        """ids [B], fresh [B, dim], nbr_ids [B, K] -> [B, K, dim]."""
         cache = self.variable(
             "cache", "h",
             lambda: jnp.zeros((self.max_id + 1, self.dim), self.dtype))
-        out = jnp.take(cache.value, bucketize_ids(read_ids, self.max_id + 1),
-                       axis=0).astype(jnp.float32)
-        if (write_ids is not None and write_vals is not None
-                and self.is_mutable_collection("cache")):
+        # rows are read neighbour-major, [K, B, dim]: the chip lays that
+        # out as the gather wrote it, [B, K, dim] costs it a relayout
+        nbr_rows = bucketize_ids(nbr_ids, self.max_id + 1).T
+        if self.is_mutable_collection("cache"):
+            rows = bucketize_ids(ids, self.max_id + 1)
+            with jax.named_scope("read"):
+                old = jnp.take(cache.value, rows,
+                               axis=0).astype(jnp.float32)
+            with jax.named_scope("write"):
+                upd = _ema_update(old, fresh, self.decay).astype(self.dtype)
+            cache.value, nbr_h = _cache_write_then_read(cache.value, rows,
+                                                        upd, nbr_rows)
+        else:
             # eval/infer apply the module with the cache frozen; historical
             # activations are read-only there (reference ScalableGCNEncoder
             # only updates stores inside the training op).
-            rows = bucketize_ids(write_ids, self.max_id + 1)
-            cache.value = cache.value.at[rows].set(
-                write_vals.astype(self.dtype))
-        return out
+            with jax.named_scope("read"):
+                nbr_h = jnp.take(cache.value, nbr_rows, axis=0)
+        return jnp.swapaxes(nbr_h, 0, 1).astype(jnp.float32)
+
+
+def _store_then_neighbors(enc: nn.Module, layer: int, ids: Array,
+                          fresh: Array, nbr_ids: Array) -> Array:
+    """The batch's fresh layer-`layer` input activations go into
+    cache_<layer>; its neighbours' [B, K, dim] come back out of it."""
+    store = _ScalableCache(enc.max_id, enc.dim, dtype=enc.cache_dtype,
+                           decay=enc.store_decay, name=f"cache_{layer}")
+    if enc.is_mutable_collection("cache"):
+        # trace time only: nothing is fetched from the device for it
+        from euler_tpu import obs
+
+        obs.counter(
+            "act_cache_fused_traces_total",
+            "fused activation-cache write-then-read operations traced "
+            "into a program (or run eagerly), one a cache layer",
+            ("encoder",)).labels(encoder=type(enc).__name__).inc()
+    with jax.named_scope("cache"):
+        return store(ids, fresh, nbr_ids)
 
 
 class ScalableGCNEncoder(nn.Module):
@@ -191,29 +318,15 @@ class ScalableGCNEncoder(nn.Module):
     @nn.compact
     def __call__(self, ids: Array, x: Array, nbr_ids: Array,
                  nbr_x: Array) -> Array:
-        b, k = nbr_ids.shape
-        # one cache module per non-input layer, created once
-        caches = {layer: _ScalableCache(self.max_id, self.dim,
-                                        dtype=self.cache_dtype,
-                                        name=f"cache_{layer}")
-                  for layer in range(1, self.num_layers)}
-        h_self = x
+        h_self, nbr_h = x, nbr_x
         for layer in range(self.num_layers):
             w = nn.Dense(self.dim, use_bias=False, name=f"w_{layer}")
-            if layer == 0:
-                nbr_h = nbr_x
-            else:
-                nbr_h = caches[layer](nbr_ids.ravel()).reshape(b, k, self.dim)
             both = jnp.concatenate([h_self[:, None, :], nbr_h], axis=1)
             h_self = w(both.mean(axis=1))
             if layer < self.num_layers - 1:
                 h_self = nn.relu(h_self)
-                # store this batch's layer-(l+1) input activations
-                store = caches[layer + 1]
-                with jax.named_scope("cache"):
-                    old = store(ids)
-                    new = _ema_update(old, h_self, self.store_decay)
-                    store(ids, write_ids=ids, write_vals=new)
+                nbr_h = _store_then_neighbors(self, layer + 1, ids, h_self,
+                                              nbr_ids)
         return h_self
 
 
@@ -230,27 +343,14 @@ class ScalableSageEncoder(nn.Module):
     @nn.compact
     def __call__(self, ids: Array, x: Array, nbr_ids: Array,
                  nbr_x: Array) -> Array:
-        b, k = nbr_ids.shape
-        caches = {layer: _ScalableCache(self.max_id, self.dim,
-                                        dtype=self.cache_dtype,
-                                        name=f"cache_{layer}")
-                  for layer in range(1, self.num_layers)}
-        h_self = x
+        h_self, nbr_h = x, nbr_x
         for layer in range(self.num_layers):
-            if layer == 0:
-                nbr_h = nbr_x
-            else:
-                nbr_h = caches[layer](nbr_ids.ravel()).reshape(b, k, self.dim)
             h_cat = jnp.concatenate([h_self, nbr_h.mean(axis=1)], axis=-1)
-            h_new = nn.Dense(self.dim, name=f"w_{layer}")(h_cat)
+            h_self = nn.Dense(self.dim, name=f"w_{layer}")(h_cat)
             if layer < self.num_layers - 1:
-                h_new = nn.relu(h_new)
-                store = caches[layer + 1]
-                with jax.named_scope("cache"):
-                    old = store(ids)
-                    upd = _ema_update(old, h_new, self.store_decay)
-                    store(ids, write_ids=ids, write_vals=upd)
-            h_self = h_new
+                h_self = nn.relu(h_self)
+                nbr_h = _store_then_neighbors(self, layer + 1, ids, h_self,
+                                              nbr_ids)
         return h_self
 
 
