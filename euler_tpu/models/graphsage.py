@@ -105,15 +105,28 @@ class _GatherEncode(nn.Module):
     aggregator: str
     encoder: str
     gather: Any = None  # make_table_gather closure for sharded tables
+    heads: int = 1      # encoder 'gat' only, as is out_dim
+    out_dim: int = 0
 
     @nn.compact
     def __call__(self, table, scale, rows):
-        from euler_tpu.utils.encoders import GCNEncoder, GenieEncoder
+        from euler_tpu.utils.encoders import (
+            GATEncoder, GCNEncoder, GenieEncoder, neighbor_major_rows,
+        )
 
         batch = {"feature_table": table}
         if scale is not None:
             batch["feature_scale"] = scale
+        if self.encoder == "gat":
+            rows = neighbor_major_rows(rows, self.fanouts)
         layers = gather_feature_rows(batch, rows, gather=self.gather)
+        if self.encoder == "gat":
+            # the softmax needs to know the pad slots (the mean only
+            # needed the pad row's zeros); the pad row is the table's last
+            pad = table.shape[0] - 1
+            return GATEncoder(self.dim, self.fanouts, self.heads,
+                              self.out_dim, name="enc")(
+                layers, [r != pad for r in rows])
         if self.encoder == "gcn":
             return GCNEncoder(self.dim, self.fanouts, name="enc")(layers)
         if self.encoder == "genie":
@@ -129,13 +142,17 @@ class DeviceSampledGraphSage(SuperviseModel):
     feature gather, and label lookup all read HBM-resident tables inside
     the jitted step. The TPU-first configuration bench.py measures —
     the host feeder drops out of the critical path entirely. encoder
-    picks any fanout-layer encoder ('sage', 'gcn' or 'genie' — all
-    consume the per-hop feature list the on-device sampler produces)."""
+    picks any fanout-layer encoder ('sage', 'gcn', 'genie' or 'gat' —
+    all consume the per-hop feature list the on-device sampler
+    produces). 'gat' is multi-head attention (utils/encoders.GATEncoder:
+    `heads` heads of width `dim` a hidden layer) whose last layer emits
+    the class logits itself, so the model has no `out` layer then."""
 
     dim: int = 32
     fanouts: Sequence[int] = (10, 10)
     aggregator: str = "mean"
     encoder: str = "sage"
+    heads: int = 4  # attention heads a layer (encoder='gat')
     # remat: recompute gather+encode in the backward pass
     # (_RematGatherEncode) — unlocks batches whose per-hop feature
     # layers don't fit HBM twice. Replicated tables only.
@@ -181,21 +198,33 @@ class DeviceSampledGraphSage(SuperviseModel):
                 uniform=(self.uniform_sampling and not sharded
                          and atab is None),
                 alias_table=atab)
-        if self.encoder not in ("sage", "gcn", "genie"):
+        if self.encoder not in ("sage", "gcn", "genie", "gat"):
             raise ValueError(
-                f"DeviceSampledGraphSage.encoder must be 'sage', 'gcn' "
-                f"or 'genie', got {self.encoder!r}")
+                f"DeviceSampledGraphSage.encoder must be 'sage', 'gcn', "
+                f"'genie' or 'gat', got {self.encoder!r}")
         if self.remat and sharded:
             raise ValueError(
                 "DeviceSampledGraphSage(remat=True) supports "
                 "replicated tables only (the re-gather would nest "
                 "shard_map inside jax.checkpoint)")
+        if self.encoder == "gat" and sharded:
+            raise ValueError(
+                "DeviceSampledGraphSage(encoder='gat') supports "
+                "replicated tables only: its softmax masks pad slots by "
+                "the pad row's id, taken from the table's shape, which "
+                "row-sharding pads to the model-axis multiple")
         mod_cls = nn.remat(_GatherEncode) if self.remat else _GatherEncode
         mod = mod_cls(self.dim, tuple(self.fanouts), self.aggregator,
                       self.encoder, gather=gather if sharded else None,
+                      heads=int(self.heads), out_dim=int(self.num_classes),
                       name="encoder")
         return mod(batch["feature_table"], batch.get("feature_scale"),
                    rows)
+
+    def logits(self, emb: Array) -> Array:
+        if self.encoder == "gat":
+            return emb  # the last attention layer maps to the classes
+        return super().logits(emb)
 
 
 class DeviceSampledScalableSage(SuperviseModel):

@@ -54,6 +54,12 @@ class SuperviseModel(nn.Module):
 
         return make_table_gather(self.table_mesh)
 
+    def logits(self, emb: Array) -> Array:
+        """[B, D] embedding -> [B, num_classes] logits: a dense `out`
+        layer. A subclass whose embedding already is the class logits
+        (an encoder whose last layer maps to the classes) returns it."""
+        return nn.Dense(self.num_classes, name="out")(emb)
+
     @nn.compact
     def __call__(self, batch: Dict[str, Any]) -> ModelOutput:
         emb = self.embed(batch)
@@ -67,7 +73,7 @@ class SuperviseModel(nn.Module):
             with jax.named_scope("labels"):
                 labels = self.table_gather()(batch["label_table"],
                                              batch["rows"][0])
-        logits = nn.Dense(self.num_classes, name="out")(emb)
+        logits = self.logits(emb)
         # optional [B] 0/1 metric_mask: padded rows (deterministic eval
         # sweeps pad the final chunk to the static batch shape) drop out
         # of both the loss mean and the metric counts

@@ -130,6 +130,175 @@ class GCNEncoder(nn.Module):
         return hidden[0]
 
 
+def neighbor_major_rows(rows: Sequence[Array],
+                        fanouts: Sequence[int]) -> list:
+    """The fanout draw's per-hop rows (hop h+1 holds the k children of
+    hop h's row m at m*k .. m*k+k-1) re-ordered NEIGHBOUR-MAJOR, every
+    hop consistently: hop h+1's row j*n_h + m is slot j of hop h's row
+    m. Its features then reshape to [k, n_h, D] for free and a sum over
+    the slots adds k slabs, where the target-major [n_h, k, D] view
+    costs the chip a relayout whenever k is no multiple of 8 (PERF.md:
+    5 ms a step in the mean model). Hop 0 keeps its order; int32 ids
+    only are moved."""
+    b = rows[0].shape[0]
+    out = [rows[0]]
+    for hop in range(1, len(rows)):
+        shape = (b, *[int(k) for k in fanouts[:hop]])
+        out.append(rows[hop].reshape(shape).transpose(
+            tuple(reversed(range(hop + 1)))).reshape(-1))
+    return out
+
+
+class _HeadVectors(nn.Module):
+    """One attention vector a head, [width, heads], as a leaf named
+    `kernel` whose first axis is the head's width: initialisers that go
+    by leaf name and fan-in (flax's own; the benchmark's seeded weights)
+    then treat it as the weight it is, and not as a bias."""
+
+    width: int
+    heads: int
+
+    @nn.compact
+    def __call__(self) -> Array:
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          (self.width, self.heads))
+
+
+def _block_diagonal(vec: Array) -> Array:
+    """[C, H] head vectors -> [H*C, H]: column h holds head h's vector in
+    rows h*C .. h*C+C-1, so `z @ that` is every head's dot product with
+    its own slice of z in one matrix product."""
+    c, h = vec.shape
+    return (vec.T[:, :, None] * jnp.eye(h, dtype=vec.dtype)[:, None, :]
+            ).reshape(h * c, h)
+
+
+def _attend(z_t: Array, z_s: Array, e_self: Array, e_nbr: Array,
+            mask: Optional[Array], concat: bool) -> Array:
+    """Softmax attention of every target over its k sampled slots and
+    itself. z_t [M, H*C], z_s [k, M, H*C] projected rows; e_self [H, M],
+    e_nbr [H, k, M] the logits (M minor: dense on the chip's lanes);
+    mask bool [k, M], False where the slot is a pad. A target whose
+    slots are all pads attends to itself only. -> [M, H*C] (heads
+    concatenated) or [M, C] (their mean)."""
+    heads = e_self.shape[0]
+    if mask is not None:
+        e_nbr = jnp.where(mask[None], e_nbr, -jnp.inf)
+    top = jnp.maximum(e_self, e_nbr.max(axis=1))        # finite: e_self is
+    p_self = jnp.exp(e_self - top)
+    p_nbr = jnp.exp(e_nbr - top[:, None])
+    total = p_self + p_nbr.sum(axis=1)
+    a_self, a_nbr = p_self / total, p_nbr / total[:, None]
+    c = z_t.shape[-1] // heads
+    outs = []
+    for h in range(heads):
+        lanes = slice(h * c, (h + 1) * c)
+        outs.append(a_self[h][:, None] * z_t[:, lanes]
+                    + (a_nbr[h][:, :, None] * z_s[:, :, lanes]).sum(axis=0))
+    if concat:
+        return jnp.concatenate(outs, axis=-1)
+    return sum(outs) / heads
+
+
+class GATLayer(nn.Module):
+    """One graph-attention layer (Velickovic et al. 2018; PyG's GATConv
+    with a linear skip, as examples/ogbn_products_gat.py stacks them)
+    applied with shared weights to every (hop h, hop h+1) pair of a
+    NEIGHBOUR-MAJOR fanout (`neighbor_major_rows`):
+
+        z = x W;  e_ij = LeakyReLU_0.2(a_src . z_j + a_dst . z_i) a head,
+        j over i's k sampled slots and i itself; alpha = softmax_j e;
+        y_i = concat_h or mean_h (sum_j alpha_ij z_j) + b;
+        x_i' = y_i + x_i S + s, through ELU where heads are concatenated.
+
+    A slot drawn twice counts twice; pad slots (masks False) take no
+    weight. Every hop is projected once a layer. Scopes `proj`, `attn`,
+    `skip` under the layer's name; trace-time counter
+    `gat_attention_traces_total{layer}`."""
+
+    width: int          # C, one head's
+    heads: int
+    concat: bool        # False: heads averaged, no ELU (the last layer)
+
+    @nn.compact
+    def __call__(self, hidden: Sequence[Array],
+                 masks: Sequence[Optional[Array]]) -> list:
+        from euler_tpu import obs
+
+        h, c = self.heads, self.width
+        out_dim = h * c if self.concat else c
+        proj = nn.Dense(h * c, use_bias=False, name="proj")
+        zs = [proj(x) for x in hidden]
+        att = jnp.concatenate(
+            [_block_diagonal(_HeadVectors(c, h, name=name)())
+             for name in ("att_src", "att_dst")], axis=1)     # [H*C, 2H]
+        bias = self.param("bias", nn.initializers.zeros, (out_dim,))
+        skip = nn.Dense(out_dim, name="skip")
+        # trace time only: nothing is fetched from the device for it
+        obs.counter(
+            "gat_attention_traces_total",
+            "graph-attention layers traced into a program (or run "
+            "eagerly), one a layer whatever its hops",
+            ("layer",)).labels(layer=self.name or "").inc()
+        with jax.named_scope("attn"):
+            # [2H, n] a hop, n minor: a_src . z then a_dst . z, every head
+            scores = [jnp.einsum("fg,nf->gn", att, z) for z in zs]
+        out = []
+        for hop in range(len(hidden) - 1):
+            x, z_t, z_s = hidden[hop], zs[hop], zs[hop + 1]
+            m = x.shape[0]
+            assert z_s.shape[0] % m == 0, (
+                f"layer of {z_s.shape[0]} rows is not a whole fanout of "
+                f"the {m}-row parent layer")
+            k = z_s.shape[0] // m
+            with jax.named_scope("attn"):
+                src, dst = scores[hop][:h], scores[hop][h:]
+                e_self = nn.leaky_relu(src + dst, 0.2)
+                e_nbr = nn.leaky_relu(
+                    scores[hop + 1][:h].reshape(h, k, m) + dst[:, None],
+                    0.2)
+                mask = masks[hop + 1]
+                y = _attend(z_t, z_s.reshape(k, m, h * c), e_self, e_nbr,
+                            None if mask is None else mask.reshape(k, m),
+                            self.concat) + bias
+            with jax.named_scope("skip"):
+                y = y + skip(x)
+                out.append(nn.elu(y) if self.concat else y)
+        return out
+
+
+class GATEncoder(nn.Module):
+    """Multi-head attention encoder over a sampled fanout: len(fanouts)
+    GATLayers, deepest pairs first as SageEncoder applies its
+    aggregators; hidden layers concatenate `heads` heads of width `dim`
+    through ELU, the last averages heads of width `out_dim` (the class
+    logits where the model has no output layer of its own).
+
+    layers[h]: hop h's features NEIGHBOUR-MAJOR (`neighbor_major_rows`);
+    masks[h]: bool per row of hop h, False for a pad slot (None: no pads).
+    """
+
+    dim: int
+    fanouts: Sequence[int]
+    heads: int
+    out_dim: int
+
+    @nn.compact
+    def __call__(self, layers: Sequence[Array],
+                 masks: Optional[Sequence[Optional[Array]]] = None) -> Array:
+        n_hops = len(self.fanouts)
+        assert len(layers) == n_hops + 1, (
+            f"need {n_hops + 1} feature layers for {n_hops} fanouts")
+        hidden = list(layers)
+        masks = list(masks) if masks is not None else [None] * len(hidden)
+        for depth in range(n_hops):
+            last = depth == n_hops - 1
+            layer = GATLayer(self.out_dim if last else self.dim, self.heads,
+                             concat=not last, name=f"layer{depth}")
+            hidden = layer(hidden, masks)
+        return hidden[0]
+
+
 def _ema_update(old: Array, fresh: Array, decay: float) -> Array:
     """Bias-corrected cache write: rows never written before (all-zero —
     the init value) take the fresh activation at FULL scale; visited
