@@ -30,6 +30,7 @@ from flax.training import train_state
 
 from euler_tpu import obs as _obs
 from euler_tpu.utils import optimizers as opt_lib
+from euler_tpu.utils.layers import undo_collection
 
 # the spans' second sink: while a jax.profiler session runs, every
 # obs.span is also an "euler.<name>" event on that session's host plane,
@@ -75,6 +76,16 @@ def _to_device_tree(batch: Dict, max_id: int = 0) -> Dict:
 
 def _merged(batch: Dict, static_batch: Dict) -> Dict:
     return {**batch, **static_batch} if static_batch else batch
+
+
+def _put_back(table, rows, old, ok):
+    """`table` as given where the step is sound (`ok`), with `old` back
+    at `rows` where it is skipped. A loop of no turn or one: its carry is
+    updated in place and a sound step pays nothing, where the identity
+    branch of a lax.cond copies the table and a scatter whose updates
+    are all dropped still walks every row (PERF.md, PR 27)."""
+    return jax.lax.fori_loop(0, jnp.where(ok, 0, 1),
+                             lambda _, t: t.at[rows].set(old), table)
 
 
 def _last_finite(vals) -> float:
@@ -294,6 +305,7 @@ class BaseEstimator:
         """The single SGD step shared by the per-step jit and the scanned
         loop — one definition so the two dispatch paths cannot drift."""
         mutable_keys = [k for k in (self.state.extra_vars or {})]
+        undo_keys = [undo_collection(k) for k in mutable_keys]
         dropout_key = jax.random.key(
             int(self.params_cfg.get("seed", 0)) + 1)
 
@@ -306,20 +318,46 @@ class BaseEstimator:
                 variables = {"params": p, **(state.extra_vars or {})}
                 if mutable_keys:
                     out, new_vars = state.apply_fn(
-                        variables, batch, mutable=mutable_keys, rngs=rngs)
+                        variables, batch, mutable=mutable_keys + undo_keys,
+                        rngs=rngs)
+                    new_vars = dict(new_vars)
+                    undo = {k: new_vars.pop(u)
+                            for k, u in zip(mutable_keys, undo_keys)
+                            if u in new_vars}
                 else:
                     out = state.apply_fn(variables, batch, rngs=rngs)
-                    new_vars = {}
-                return out.loss, (out, new_vars)
+                    new_vars, undo = {}, {}
+                return out.loss, (out, new_vars, undo)
 
-            (loss, (out, new_vars)), grads = jax.value_and_grad(
+            (loss, (out, new_vars, undo)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
+            guarded = self.nonfinite_guard and state.skipped_steps is not None
+            # a collection the apply wrote by rows, with a record of its
+            # variables' old rows (layers.undo_collection), is taken as
+            # written and rolled back by rows: through the lax.cond both
+            # the old and the new table would have to be alive, and the
+            # compiler copies the whole table twice a step (PERF.md, PR 27)
+            by_rows = undo if guarded else {}
+            for k in by_rows:
+                # trace time only, as act_cache_fused_traces_total
+                _obs.counter(
+                    "guard_row_rollback_traces_total",
+                    "variables of a mutable collection that the non-finite "
+                    "guard rolls back by rows, counted each time a train "
+                    "step is traced", ("collection",)
+                ).labels(collection=k).inc(
+                    len(jax.tree_util.tree_leaves(new_vars[k])))
+            if by_rows:
+                state = state.replace(extra_vars={
+                    k: v for k, v in state.extra_vars.items()
+                    if k not in by_rows})
+            in_cond = {k: v for k, v in new_vars.items() if k not in by_rows}
 
             def apply_update(_):
                 with jax.named_scope("update"):
                     s2 = state.apply_gradients(grads=grads)
                 if new_vars:
-                    s2 = s2.replace(extra_vars=dict(new_vars))
+                    s2 = s2.replace(extra_vars=in_cond)
                 return s2
 
             def skip_update(_):
@@ -330,7 +368,7 @@ class BaseEstimator:
                     step=state.step + 1,
                     skipped_steps=state.skipped_steps + 1)
 
-            if self.nonfinite_guard and state.skipped_steps is not None:
+            if guarded:
                 # guard the GRADS too: overflow in the backward pass can
                 # yield NaN grads under a finite loss, which would poison
                 # the donated params with skipped_steps still reading 0
@@ -340,6 +378,13 @@ class BaseEstimator:
                         ok &= jnp.all(jnp.isfinite(g))
                     state = jax.lax.cond(ok, apply_update, skip_update,
                                          None)
+                    if by_rows:
+                        state = state.replace(extra_vars={
+                            **state.extra_vars,
+                            **{k: jax.tree_util.tree_map(
+                                lambda t, rec: _put_back(t, *rec, ok),
+                                new_vars[k], records)
+                               for k, records in by_rows.items()}})
             else:
                 state = apply_update(None)
             return state, loss, out.metric

@@ -24,7 +24,14 @@ import jax.numpy as jnp
 from jax.custom_derivatives import SymbolicZero
 
 from euler_tpu.utils.aggregators import get_aggregator
-from euler_tpu.utils.layers import AttLayer, Embedding, LSTMLayer, SparseEmbedding, bucketize_ids
+from euler_tpu.utils.layers import (
+    AttLayer,
+    Embedding,
+    LSTMLayer,
+    SparseEmbedding,
+    bucketize_ids,
+    undo_collection,
+)
 
 Array = jax.Array
 
@@ -435,10 +442,16 @@ class _ScalableCache(nn.Module):
         if self.is_mutable_collection("cache"):
             rows = bucketize_ids(ids, self.max_id + 1)
             with jax.named_scope("read"):
-                old = jnp.take(cache.value, rows,
-                               axis=0).astype(jnp.float32)
+                old = jnp.take(cache.value, rows, axis=0)
+            if not self.is_initializing():
+                # what a skipped step puts back (layers.undo_collection):
+                # rows may repeat, every duplicate carries the same old row
+                self.sow(undo_collection("cache"), "h", (rows, old),
+                         reduce_fn=lambda _, record: record,
+                         init_fn=lambda: None)
             with jax.named_scope("write"):
-                upd = _ema_update(old, fresh, self.decay).astype(self.dtype)
+                upd = _ema_update(old.astype(jnp.float32), fresh,
+                                  self.decay).astype(self.dtype)
             cache.value, nbr_h = _cache_write_then_read(cache.value, rows,
                                                         upd, nbr_rows)
         else:

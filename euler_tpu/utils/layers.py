@@ -22,7 +22,7 @@ import jax.numpy as jnp
 Array = jax.Array
 
 __all__ = ["Dense", "Embedding", "SparseEmbedding", "AttLayer", "LSTMLayer",
-           "bucketize_ids"]
+           "bucketize_ids", "undo_collection"]
 
 Dense = nn.Dense  # re-export: flax Dense is the reference's Dense
 
@@ -37,6 +37,19 @@ def bucketize_ids(ids: Array, num_buckets: int) -> Array:
     if ids.dtype != jnp.int32:
         ids = ids.astype(jnp.int32)
     return ids % jnp.int32(num_buckets)
+
+
+def undo_collection(collection: str) -> str:
+    """Where a module that overwrites ROWS of a variable in the mutable
+    `collection` may record what it overwrote: sow (rows, the old rows
+    in the stored dtype) under the variable's own name into this
+    collection, before the write. The record is an output of that apply
+    alone, never state (not sown while initializing). The estimator's
+    non-finite guard reads it: a collection whose every variable has a
+    record is taken as written and a skipped step puts the old rows
+    back, so the table never passes through the guard's lax.cond
+    (base_estimator._make_one_step)."""
+    return f"{collection}_undo"
 
 
 class Embedding(nn.Module):

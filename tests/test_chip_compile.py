@@ -12,6 +12,7 @@ process may load the TPU library, and it keeps it until exit.
 """
 
 import os
+import re
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -173,8 +174,20 @@ def test_act_cache_scanned_step_compiles_and_fits(one_chip):
         None, None, label_fid="label", label_dim=c["num_classes"],
         feature_store=store, device_sampler=sampler)
     compiled = _compile_scanned_step(est, one_chip)
-    assert "cache" in est.state.extra_vars
+    assert list(est.state.extra_vars) == ["cache"]
     assert _device_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+    # the guard rolls the cache back by rows (PR 27): with the table in
+    # its lax.cond the compiler copied it before the write's scatter and
+    # copied the written one back, and kept 1,309,784,576 bytes of
+    # temporaries (my compile of the parent, PR 27; 550,852,608 now)
+    table = f"bf16[{c['n_nodes'] + 1},{c['dim']}]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.match(rf"\s*(ROOT )?\S+ = {re.escape(table)}\S* copy\(",
+                          line)]
+    assert not copies, copies
+    table_bytes = (c["n_nodes"] + 1) * c["dim"] * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1_309_784_576 - table_bytes // 2, compiled.memory_analysis()
 
 
 def test_row_sharded_scanned_step_on_2x2_mesh(mesh):

@@ -312,3 +312,276 @@ def test_act_cache_backward_sums_every_chunk(monkeypatch, hit_share):
     got = jax.jit(E._sum_by_write, static_argnums=2)(g, src, n_writes)
     want = jax.ops.segment_sum(g, src, num_segments=n_writes)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+# --- the non-finite guard rolls a cache back by rows (PR 27) ---------------
+
+def _guard_net(kind, dtype, num_layers):
+    import flax.linen as nn
+
+    from euler_tpu.mp_utils.base import ModelOutput
+
+    class CacheNet(nn.Module):
+        @nn.compact
+        def __call__(self, batch):
+            emb = ENCODERS[kind](
+                dim=CD, num_layers=num_layers, max_id=N_ROWS - 1,
+                cache_dtype=dtype, name="encoder")(
+                    batch["ids"], batch["x"], batch["nbr_ids"],
+                    batch["nbr_x"])
+            # sqrt(0 * s) is 0 with an infinite slope: root_scale 0 gives
+            # a finite loss under a NaN gradient, 1 a plain smooth term
+            loss = ((emb - batch["labels"]) ** 2).mean() + 1e-3 * jnp.sqrt(
+                batch["root_scale"] * (emb ** 2).sum())
+            return ModelOutput(emb, loss, "mse", loss)
+
+    return CacheNet()
+
+
+def _guard_batch(seed=7, poison=None):
+    """_cache_batch's roots and neighbours (five neighbours ARE roots),
+    made harder: root 3 twice, root 6 once more as id + N_ROWS (the
+    modulo wrap). poison: 'labels' (NaN loss), 'x' (NaN rows written into
+    the cache), 'grad' (finite loss, NaN gradient)."""
+    ids, x, nbr_ids, nbr_x = _cache_batch()
+    rng = np.random.default_rng(seed)
+    ids = ids.at[4].set(ids[3]).at[1].set(ids[6] + N_ROWS)
+    batch = {"ids": np.asarray(ids), "nbr_ids": np.asarray(nbr_ids),
+             "x": rng.normal(size=x.shape).astype(np.float32),
+             "nbr_x": rng.normal(size=nbr_x.shape).astype(np.float32),
+             "labels": rng.normal(size=(CB, CD)).astype(np.float32),
+             "root_scale": np.float32(1.0)}
+    if poison == "grad":
+        batch["root_scale"] = np.float32(0.0)
+    elif poison is not None:
+        batch[poison] = np.full_like(batch[poison], np.nan)
+    return batch
+
+
+def _guard_estimator(kind, dtype, num_layers, model_dir=None, warm=True,
+                     **params):
+    """A BaseEstimator over the cache encoder, its caches holding
+    non-zero old rows; warm: one sound step behind it (so Adam's moments
+    are not zeros either)."""
+    from euler_tpu.estimator import BaseEstimator
+
+    est = BaseEstimator(
+        _guard_net(kind, dtype, num_layers),
+        {"learning_rate": 0.01, "log_steps": 1 << 30,
+         "checkpoint_steps": 0, **params}, model_dir=model_dir)
+    est._init_state(jax.tree_util.tree_map(jnp.asarray, _guard_batch()))
+    est.state = est.state.replace(
+        extra_vars={"cache": {"encoder": _old_caches(num_layers, dtype)}})
+    est._train_step = est._build_train_step()
+    if warm:
+        est.state, loss, _ = est._train_step(
+            est.state, jax.tree_util.tree_map(jnp.asarray, _guard_batch(1)))
+        assert np.isfinite(float(loss))
+    return est
+
+
+def _parent_one_step(est):
+    """_make_one_step as it was before PR 27 (every mutable collection
+    through the guard's lax.cond): the plain reference."""
+    mutable_keys = list(est.state.extra_vars or {})
+    dropout_key = jax.random.key(int(est.params_cfg.get("seed", 0)) + 1)
+
+    def one_step(state, batch):
+        rngs = {"dropout": jax.random.fold_in(dropout_key, state.step)}
+
+        def loss_fn(p):
+            variables = {"params": p, **(state.extra_vars or {})}
+            if mutable_keys:
+                out, new_vars = state.apply_fn(
+                    variables, batch, mutable=mutable_keys, rngs=rngs)
+            else:
+                out = state.apply_fn(variables, batch, rngs=rngs)
+                new_vars = {}
+            return out.loss, (out, new_vars)
+
+        (loss, (out, new_vars)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params)
+
+        def apply_update(_):
+            with jax.named_scope("update"):
+                s2 = state.apply_gradients(grads=grads)
+            if new_vars:
+                s2 = s2.replace(extra_vars=dict(new_vars))
+            return s2
+
+        def skip_update(_):
+            return state.replace(step=state.step + 1,
+                                 skipped_steps=state.skipped_steps + 1)
+
+        with jax.named_scope("guard"):
+            ok = jnp.isfinite(loss)
+            for g in jax.tree_util.tree_leaves(grads):
+                ok &= jnp.all(jnp.isfinite(g))
+            state = jax.lax.cond(ok, apply_update, skip_update, None)
+        return state, loss, out.metric
+
+    return one_step
+
+
+def _bytes_of(tree):
+    return [np.asarray(leaf).tobytes()
+            for leaf in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("num_layers", [2, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", ["sage", "gcn"])
+def test_guard_skipped_step_leaves_every_byte(kind, dtype, num_layers):
+    """A poisoned batch (duplicate and modulo-wrapped roots, a root that
+    is also a neighbour, old rows non-zero) leaves every cache table,
+    the parameters and the optimizer state byte for byte; step and
+    skipped_steps advance by one; the next sound step trains."""
+    est = _guard_estimator(kind, dtype, num_layers)
+    written = np.asarray(_guard_batch()["ids"]) % N_ROWS
+    for table in jax.tree_util.tree_leaves(est.state.extra_vars):
+        assert np.asarray(table, np.float32)[written].any()
+    for n, poison in enumerate(["labels", "x", "grad"]):
+        before = est.state
+        kept = _bytes_of((before.params, before.opt_state,
+                          before.extra_vars))
+        step, skipped = int(before.step), int(before.skipped_steps)
+        est.state, loss, _ = est._train_step(
+            est.state, jax.tree_util.tree_map(jnp.asarray,
+                                              _guard_batch(2 + n, poison)))
+        assert np.isfinite(float(loss)) == (poison == "grad")
+        assert list(est.state.extra_vars) == ["cache"]
+        assert _bytes_of((est.state.params, est.state.opt_state,
+                          est.state.extra_vars)) == kept, poison
+        assert int(est.state.step) == step + 1
+        assert int(est.state.skipped_steps) == skipped + 1
+        est.state, loss, _ = est._train_step(
+            est.state, jax.tree_util.tree_map(jnp.asarray,
+                                              _guard_batch(5 + n)))
+        assert np.isfinite(float(loss))
+        assert int(est.state.skipped_steps) == skipped + 1
+        assert _bytes_of(est.state.extra_vars) != kept[-(num_layers - 1):]
+
+
+@pytest.mark.parametrize("num_layers", [2, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", ["sage", "gcn"])
+def test_guard_rollback_inside_scanned_dispatch(kind, dtype, num_layers):
+    """steps_per_loop 4, the bad batches (NaN rows written into the
+    cache; a NaN gradient) in the middle: the dispatch ends in the state
+    that the lax.cond form of the guard reaches from the same start."""
+    est = _guard_estimator(kind, dtype, num_layers, warm=False,
+                           steps_per_loop=4)
+    start = jax.tree_util.tree_map(jnp.copy, est.state)
+    batches = [_guard_batch(10), _guard_batch(11, "x"),
+               _guard_batch(12, "grad"), _guard_batch(13)]
+    res = est.train(iter(batches), max_steps=int(start.step) + 4)
+    assert res["skipped_steps"] == 2
+    plain = jax.jit(_parent_one_step(est))
+    want = start
+    for b in batches:
+        want, _, _ = plain(want, jax.tree_util.tree_map(jnp.asarray, b))
+    assert int(want.skipped_steps) == 2 and int(est.state.step) == int(
+        want.step)
+    for got, ref in zip(
+            jax.tree_util.tree_leaves((est.state.params, est.state.opt_state,
+                                       est.state.extra_vars)),
+            jax.tree_util.tree_leaves((want.params, want.opt_state,
+                                       want.extra_vars))):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        assert np.isfinite(got).all()
+        # the same arithmetic in two programs: a last bit of the stored
+        # dtype at most
+        tol = 1e-6 if dtype == jnp.float32 or got.shape != (N_ROWS, CD) \
+            else 2 ** -8
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+
+
+def _conds(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _conds(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("kind", ["sage", "gcn"])
+def test_guard_cond_never_holds_the_cache(kind, tmp_path):
+    """What keeps the two whole-table copies from coming back where there
+    is no chip: no conditional of the scanned step takes or returns a
+    value of the cache's shape (both tables alive at a lax.cond cost a
+    copy before the scatter and one after), the undo record is no state,
+    and the trace is counted once a cache layer."""
+    from euler_tpu import obs
+
+    num_layers = 3
+    est = _guard_estimator(kind, jnp.bfloat16, num_layers,
+                           model_dir=str(tmp_path), steps_per_loop=2)
+    counter = obs.counter("guard_row_rollback_traces_total",
+                          labelnames=("collection",)).labels(
+                              collection="cache")
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), _guard_batch(1), _guard_batch(2))
+    before = counter.value
+    jaxpr = jax.make_jaxpr(est._build_train_loop())(est.state, stacked, {})
+    assert counter.value == before + num_layers - 1
+    conds = _conds(jaxpr.jaxpr, [])
+    n_state = len(jax.tree_util.tree_leaves(
+        (est.state.params, est.state.opt_state)))
+    assert any(len(c.outvars) >= n_state for c in conds)   # the guard's
+    for c in conds:
+        for v in list(c.invars) + list(c.outvars):
+            assert getattr(v.aval, "shape", None) != (N_ROWS, CD), c
+    # ... while the plain reference's guard does hold it
+    held = [v for c in _conds(jax.make_jaxpr(_parent_one_step(est))(
+        est.state, jax.tree_util.tree_map(jnp.asarray, _guard_batch(1))
+    ).jaxpr, []) for v in c.outvars if v.aval.shape == (N_ROWS, CD)]
+    assert len(held) == num_layers - 1
+    # the record is an output of the apply alone: not in the state after
+    # a dispatch, a save or a restore
+    est.train(iter([_guard_batch(4), _guard_batch(5, "labels")]),
+              max_steps=int(est.state.step) + 2)
+    assert list(est.state.extra_vars) == ["cache"]
+    est.save_checkpoint(int(est.state.step))
+    est.finalize_checkpoints()
+    kept = _bytes_of(est.state.extra_vars)
+    est.state = est.state.replace(extra_vars=jax.tree_util.tree_map(
+        jnp.zeros_like, est.state.extra_vars))
+    assert est.restore_checkpoint() == int(est.state.step)
+    assert list(est.state.extra_vars) == ["cache"]
+    assert _bytes_of(est.state.extra_vars) == kept
+
+
+def test_guard_without_a_mutable_collection_traces_as_before():
+    """A model with no mutable collection (every benchmark cell but the
+    cache model's): one_step is the program it was, equation for
+    equation."""
+    import flax.linen as nn
+
+    from euler_tpu import obs
+    from euler_tpu.estimator import BaseEstimator
+    from euler_tpu.mp_utils.base import ModelOutput
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, batch):
+            emb = nn.Dense(CD)(nn.Dropout(0.1, deterministic=False)(
+                batch["x"]))
+            loss = ((emb - batch["labels"]) ** 2).mean()
+            return ModelOutput(emb, loss, "mse", loss)
+
+    est = BaseEstimator(Net(), {"learning_rate": 0.01})
+    batch = {k: jnp.asarray(v) for k, v in _guard_batch().items()
+             if k in ("x", "labels")}
+    est._init_state(batch)
+    assert est.state.extra_vars == {}
+    counter = obs.counter("guard_row_rollback_traces_total",
+                          labelnames=("collection",)).labels(
+                              collection="cache")
+    before = counter.value
+    now = jax.make_jaxpr(est._make_one_step())(est.state, batch)
+    was = jax.make_jaxpr(_parent_one_step(est))(est.state, batch)
+    assert str(now) == str(was)
+    assert counter.value == before
