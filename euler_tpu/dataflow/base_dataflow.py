@@ -191,6 +191,15 @@ class FullBatchDataFlow(DataFlow):
         return batch
 
 
+_NO_NODE = np.iinfo(np.uint64).max
+
+
+def _bucket(n: int) -> int:
+    """n rounded up to the next 2^k or 1.5 * 2^k."""
+    p = 1 << max(n - 1, 1).bit_length()
+    return p * 3 // 4 if n <= p * 3 // 4 else p
+
+
 class LayerwiseDataFlow(DataFlow):
     """LADIES-style layerwise batches (reference layerwise_dataflow.py:26):
     per-layer importance-sampled pools + dense inter-pool adjacency."""
@@ -269,7 +278,15 @@ class LayerwiseDataFlow(DataFlow):
             for _ in self.layer_sizes:
                 _, nbr, _, _ = self.graph.get_full_neighbor(
                     levels[-1], edge_types=self.edge_types)
-                levels.append(np.unique(np.concatenate([levels[-1], nbr])))
+                closure = np.unique(np.concatenate([levels[-1], nbr]))
+                # a closure's size differs from batch to batch, and every
+                # new shape is a compile of the eval step: pad it up to
+                # the next 2^k or 1.5 * 2^k with an id no node has (no
+                # neighbours, zero features, so its adjacency columns
+                # are zeros and its rows feed nothing real)
+                levels.append(np.concatenate([closure, np.full(
+                    _bucket(len(closure)) - len(closure), _NO_NODE,
+                    np.uint64)]))
         adjs = [self._dense_adj(levels[i], levels[i + 1])
                 for i in range(len(levels) - 1)]
         batch = {"ids": levels, "adjs": adjs}

@@ -148,13 +148,6 @@ class BaseEstimator:
         self.log_steps = int(self.params_cfg.get("log_steps", 20))
         self.ckpt_steps = int(self.params_cfg.get("checkpoint_steps", 1000))
         self.profiling = bool(self.params_cfg.get("profiling", False))
-        # nonfinite guard: a batch whose loss is NaN/Inf must not poison
-        # the donated params/opt_state — the step skips the update and
-        # counts it (see _make_one_step). Default ON; set
-        # nonfinite_guard=False to trade the (tiny) lax.cond for raw
-        # speed on trusted data.
-        self.nonfinite_guard = bool(
-            self.params_cfg.get("nonfinite_guard", True))
         # resilient input path: transient input-pipeline failures (a
         # flaky graph service) are retried with backoff; past the
         # retries, up to skip_batch_budget batches may be abandoned
@@ -331,7 +324,7 @@ class BaseEstimator:
 
             (loss, (out, new_vars, undo)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
-            guarded = self.nonfinite_guard and state.skipped_steps is not None
+            guarded = state.skipped_steps is not None
             # a collection the apply wrote by rows, with a record of its
             # variables' old rows (layers.undo_collection), is taken as
             # written and rolled back by rows: through the lax.cond both

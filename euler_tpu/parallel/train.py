@@ -31,7 +31,10 @@ def spmd_init(model: nn.Module, tx: optax.GradientTransformation,
     mirrors the param placement."""
     rng = jax.random.key(seed)
     batch = shard_batch(sample_batch, mesh)
-    variables = model.init(rng, batch)
+    # one compiled program, as BaseEstimator._init_state dispatches it:
+    # op by op, every primitive of the forward pass is a compile of its
+    # own (some 200 for a device-sampled model over row-sharded tables)
+    variables = jax.jit(model.init)(rng, batch)
     variables = apply_param_shardings(variables, mesh)
     params = variables.pop("params")
     opt_state = tx.init(params)
@@ -43,14 +46,13 @@ def spmd_init(model: nn.Module, tx: optax.GradientTransformation,
 def make_spmd_train_step(model: nn.Module,
                          tx: optax.GradientTransformation,
                          mutable_keys: Tuple[str, ...] = (),
-                         nonfinite_guard: bool = True,
                          table_store=None,
                          table_rows_key: str = "rows") -> Callable:
     """Jitted (state, batch) → (state, loss, metric). State buffers are
     donated so HBM is reused across steps — which is exactly why the
-    nonfinite guard defaults on: one NaN loss applied to donated buffers
-    destroys the only copy of the params. A guarded bad step keeps the
-    old params/opt_state and bumps state['skipped_steps'].
+    step is guarded: one NaN loss applied to donated buffers destroys
+    the only copy of the params. A bad step keeps the old
+    params/opt_state and bumps state['skipped_steps'].
 
     table_store (a PartitionedFeatureStore) turns on per-step gather
     accounting in the HOST wrapper: each dispatch's table rows
@@ -98,7 +100,7 @@ def make_spmd_train_step(model: nn.Module,
                 new["skipped_steps"] = state["skipped_steps"] + 1
             return new
 
-        if nonfinite_guard and has_ctr:
+        if has_ctr:
             # loss AND grads: backward-pass overflow can produce NaN
             # grads under a finite loss
             ok = jnp.isfinite(loss)

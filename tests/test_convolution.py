@@ -38,7 +38,11 @@ SIMPLE_LAYERS = [
 ]
 
 
-@pytest.mark.parametrize("layer", SIMPLE_LAYERS, ids=lambda l: type(l).__name__ + str(id(l) % 97))
+# ids that are the same in every run: the type's name and the layer's
+# place in SIMPLE_LAYERS
+@pytest.mark.parametrize(
+    "layer", SIMPLE_LAYERS,
+    ids=[f"{type(l).__name__}-{i}" for i, l in enumerate(SIMPLE_LAYERS)])
 def test_layer_shapes(graph_data, layer):
     x, edge_index = graph_data
     params = layer.init(jax.random.key(0), x, edge_index)

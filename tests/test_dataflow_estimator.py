@@ -69,6 +69,43 @@ def test_layerwise_dataflow(tiny_data):
     np.testing.assert_allclose(fb["adjs"][0].sum(axis=1), 1.0, rtol=1e-4)
 
 
+def test_layerwise_exact_closure_is_padded_to_few_shapes(tiny_data):
+    """sample=False pads each closure with an id no node has: the real
+    part is the exact closure and its propagation matrix, pad columns
+    carry no weight and pad rows no features, and batches of many
+    closure sizes come in a few shapes (each one a compile of the eval
+    step)."""
+    from euler_tpu.dataflow.base_dataflow import _NO_NODE
+
+    g = tiny_data.engine
+    full = LayerwiseDataFlow(g, [6, 8], sample=False,
+                             feature_ids=["feature"])
+    exact_sizes, shapes = set(), set()
+    for _ in range(12):
+        roots = np.unique(g.sample_node(8, -1))[:4]
+        fb = full(roots)
+        level = roots
+        for l in range(2):
+            _, nbr, _, _ = g.get_full_neighbor(level)
+            closure = np.unique(np.concatenate([level, nbr]))
+            got = fb["ids"][l + 1]
+            n = len(closure)
+            np.testing.assert_array_equal(got[:n], closure)
+            assert (got[n:] == _NO_NODE).all()
+            assert n <= len(got) <= 3 * (n + 1) // 2
+            adj = fb["adjs"][l]
+            rows = len(level)
+            np.testing.assert_array_equal(
+                adj[:rows, :n], full._dense_adj(level, closure))
+            assert not adj[:rows, n:].any()
+            assert not fb["layers"][l + 1][n:].any()
+            level = closure
+        exact_sizes.add(tuple(len(np.unique(i[i != _NO_NODE]))
+                              for i in fb["ids"]))
+        shapes.add(tuple(len(i) for i in fb["ids"]))
+    assert len(shapes) < len(exact_sizes)
+
+
 def test_relation_dataflow(tiny_data):
     g = tiny_data.engine
     flow = RelationDataFlow(g, fanout=3, num_relations=1,

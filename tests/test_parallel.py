@@ -1,5 +1,7 @@
 """Sharding tests on the 8-device virtual CPU mesh (conftest forces it)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,9 @@ import pytest
 
 from euler_tpu.parallel import (
     ShardedEmbedding,
+    device_layerwise,
+    device_sampler,
+    device_walk,
     make_mesh,
     make_spmd_train_step,
     param_shardings,
@@ -15,26 +20,96 @@ from euler_tpu.parallel import (
     spmd_init,
 )
 
+# The library's draws as compiled programs, which is how every model runs
+# them (inside its jitted step). Called op by op, each primitive of a draw
+# is a compile of its own, and the same draw on the same shapes is compiled
+# anew by every test: one jitted wrapper a function, shared by the module,
+# compiles a draw once for each shape.
+def _compiled(fn, *static):
+    # jit infers the positions of the static arguments from their names
+    return jax.jit(fn, static_argnames=static)
 
-def test_mesh_shapes():
-    mesh = make_mesh(model_parallel=2)
+
+sample_hop = _compiled(device_sampler.sample_hop,
+                       "count", "gather", "uniform")
+sample_fanout_rows = _compiled(device_sampler.sample_fanout_rows,
+                               "fanouts", "gather", "uniform")
+sample_hop_fused = _compiled(device_sampler.sample_hop_fused,
+                             "count", "gather")
+sample_fanout_rows_fused = _compiled(device_sampler.sample_fanout_rows_fused,
+                                     "fanouts", "gather")
+fuse_tables = _compiled(device_sampler.fuse_tables)
+walk_rows = _compiled(device_walk.walk_rows,
+                      "walk_len", "p", "q", "gather", "uniform")
+sample_global_rows = _compiled(device_walk.sample_global_rows, "shape")
+gen_pair_rows = _compiled(device_walk.gen_pair_rows,
+                          "left_win", "right_win")
+sample_layerwise_rows = _compiled(device_layerwise.sample_layerwise_rows,
+                                  "layer_sizes")
+
+
+def _init(model, key, batch):
+    """model.init as ONE compiled program, as the estimators dispatch it
+    (BaseEstimator._init_state); eagerly, every primitive of the forward
+    pass, shard_map bodies included, is compiled and run on its own."""
+    return jax.jit(model.init)(key, batch)
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    """{data: 4, model: 2} over conftest's 8 virtual devices."""
+    return make_mesh(model_parallel=2)
+
+
+def _citation(n, d, seed, **split):
+    from euler_tpu.dataset.base_dataset import synthetic_citation
+
+    return synthetic_citation("t", n=n, d=d, num_classes=3, seed=seed,
+                              **split)
+
+
+@pytest.fixture(scope="module")
+def citation300():
+    """The 300-node citation set the *_trains tests learn on, with its
+    replicated float feature/label store and its cap-16 sampling tables:
+    read by every one of them, written by none."""
+    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
+
+    data = _citation(300, 16, 2, train_per_class=30, val=40, test=60)
+    store = DeviceFeatureStore(data.engine, ["feature"], label_fid="label",
+                               label_dim=data.num_classes)
+    return data, store, DeviceNeighborTable(data.engine, cap=16)
+
+
+@pytest.fixture(scope="module")
+def citation200():
+    """The 200-node set of the two SPMD train-step tests (each places its
+    own tables on the mesh: replicated in one, row-sharded in the other)."""
+    return _citation(200, 8, 6, train_per_class=20, val=20, test=30)
+
+
+@pytest.fixture(scope="module")
+def citation120():
+    """The 120-node set of the single-step model tests."""
+    return _citation(120, 8, 9, train_per_class=10, val=15, test=20)
+
+
+def test_mesh_shapes(mesh):
     assert dict(mesh.shape) == {"data": 4, "model": 2}
     mesh_dp = make_mesh()
     assert dict(mesh_dp.shape) == {"data": 8, "model": 1}
 
 
-def test_sharded_embedding_partition_metadata():
+def test_sharded_embedding_partition_metadata(mesh):
     model = ShardedEmbedding(num_embeddings=16, dim=4)
     variables = model.init(jax.random.key(0), jnp.arange(4, dtype=jnp.int32))
-    mesh = make_mesh(model_parallel=2)
     shardings = param_shardings(variables, mesh)
     leaf = jax.tree_util.tree_leaves(
         shardings, is_leaf=lambda x: hasattr(x, "spec"))[0]
     assert leaf.spec[0] == "model"
 
 
-def test_shard_batch_layouts():
-    mesh = make_mesh(model_parallel=2)  # data axis = 4
+def test_shard_batch_layouts(mesh):
     batch = {"a": np.ones((8, 3), np.float32), "b": np.ones((5,), np.float32)}
     out = shard_batch(batch, mesh)
     # a: divisible by 4 → sharded; b: not → replicated
@@ -42,11 +117,10 @@ def test_shard_batch_layouts():
     assert out["b"].sharding.spec == ()
 
 
-def test_spmd_graphsage_step_runs():
+def test_spmd_graphsage_step_runs(mesh):
     from euler_tpu.models import ShardedSupervisedGraphSage
     from __graft_entry__ import _tiny_fanout_batch
 
-    mesh = make_mesh(model_parallel=2)
     model = ShardedSupervisedGraphSage(
         num_classes=3, multilabel=False, dim=8, fanouts=(2, 2),
         max_id=31, id_dim=4)
@@ -143,7 +217,9 @@ def test_ring_lookup_matches_take():
 # Device-resident neighbor sampling (parallel/device_sampler.py): the
 # TPU-first input path — fanout sampled in-jit from HBM tables.
 # ---------------------------------------------------------------------------
+@functools.cache
 def _weighted_ring(n=10):
+    """Built once a size for the module: the tests only read it."""
     from euler_tpu.graph import GraphBuilder
 
     b = GraphBuilder()
@@ -160,7 +236,7 @@ def test_device_sampler_draws_true_neighbors():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import DeviceNeighborTable, sample_fanout_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4)
@@ -182,7 +258,7 @@ def test_device_sampler_weight_proportions():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import DeviceNeighborTable, sample_fanout_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4)
@@ -202,7 +278,7 @@ def test_device_sampler_zero_degree_pads():
     import jax.numpy as jnp
 
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     b = GraphBuilder()
     b.add_nodes(np.arange(3, dtype=np.uint64))
@@ -215,6 +291,7 @@ def test_device_sampler_zero_degree_pads():
     assert set(np.asarray(out).tolist()) == {t.pad_row}
 
 
+@functools.cache
 def _unweighted_ring(n=10):
     from euler_tpu.graph import GraphBuilder
 
@@ -247,7 +324,7 @@ def test_uniform_sample_hop_matches_weighted_distribution():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _unweighted_ring()
     t = DeviceNeighborTable(g, cap=4)
@@ -269,7 +346,7 @@ def test_uniform_sample_hop_zero_degree_pads():
     import jax.numpy as jnp
 
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     b = GraphBuilder()
     b.add_nodes(np.arange(3, dtype=np.uint64))
@@ -296,7 +373,7 @@ def test_uniform_hub_draws_from_capped_subset():
     import jax.numpy as jnp
 
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     b = GraphBuilder()
     ids = np.arange(12, dtype=np.uint64)
@@ -336,22 +413,16 @@ def test_from_arrays_uniform_rows_stat_and_recompute():
     assert DeviceNeighborTable.from_arrays(nw, cw).uniform_rows is False
 
 
-def test_device_sampled_graphsage_uniform_trains():
+def test_device_sampled_graphsage_uniform_trains(citation300):
     """Model-level wiring: uniform_sampling=True (the one-gather path on
     an unweighted citation set) trains to the same quality bar as the
     weighted-path estimator test above it."""
     from euler_tpu.dataflow import FanoutDataFlow
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.estimator import NodeEstimator
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    data = synthetic_citation("t", n=300, d=16, num_classes=3,
-                              train_per_class=30, val=40, test=60, seed=2)
+    data, store, sampler = citation300
     g = data.engine
-    store = DeviceFeatureStore(g, ["feature"], label_fid="label",
-                               label_dim=data.num_classes)
-    sampler = DeviceNeighborTable(g, cap=16)
     assert sampler.uniform_rows
     est = NodeEstimator(
         DeviceSampledGraphSage(num_classes=data.num_classes,
@@ -369,22 +440,16 @@ def test_device_sampled_graphsage_uniform_trains():
     assert ev["metric"] > 0.55, ev
 
 
-def test_device_sampled_graphsage_trains():
+def test_device_sampled_graphsage_trains(citation300):
     """Root-rows-only batches through NodeEstimator(device_sampler=...)
     + DeviceSampledGraphSage learn on a small citation set, including
     under steps_per_loop scanning."""
     from euler_tpu.dataflow import FanoutDataFlow
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.estimator import NodeEstimator
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    data = synthetic_citation("t", n=300, d=16, num_classes=3,
-                              train_per_class=30, val=40, test=60, seed=2)
+    data, store, sampler = citation300
     g = data.engine
-    store = DeviceFeatureStore(g, ["feature"], label_fid="label",
-                               label_dim=data.num_classes)
-    sampler = DeviceNeighborTable(g, cap=16)
     est = NodeEstimator(
         DeviceSampledGraphSage(num_classes=data.num_classes,
                                multilabel=False, dim=16, fanouts=(4, 4)),
@@ -400,24 +465,19 @@ def test_device_sampled_graphsage_trains():
     assert ev["metric"] > 0.55, ev
 
 
-def test_device_sampled_spmd_train_step():
+def test_device_sampled_spmd_train_step(mesh, citation200):
     """Full SPMD training step with the device sampler under an 8-device
     mesh: tables replicated (shard_batch's REPLICATED_TABLE_KEYS), roots
     sharded over 'data' — sampling + gather + grad all-reduce in one jit."""
-    import jax
     import optax
 
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.models import DeviceSampledGraphSage
     from euler_tpu.parallel import (
-        DeviceFeatureStore, DeviceNeighborTable, make_mesh,
-        make_spmd_train_step, shard_batch, spmd_init,
+        DeviceFeatureStore, DeviceNeighborTable, make_spmd_train_step,
+        shard_batch, spmd_init,
     )
 
-    mesh = make_mesh(model_parallel=2, devices=jax.devices()[:8])
-    data = synthetic_citation("t", n=200, d=8, num_classes=3,
-                              train_per_class=20, val=20, test=30, seed=6)
-    g = data.engine
+    g = citation200.engine
     store = DeviceFeatureStore(g, ["feature"], label_fid="label",
                                label_dim=3, mesh=mesh)
     sampler = DeviceNeighborTable(g, cap=8, mesh=mesh)
@@ -446,24 +506,20 @@ def test_device_sampled_spmd_train_step():
     assert losses[-1] < losses[0]
 
 
-def test_spmd_train_step_with_row_sharded_tables():
+def test_spmd_train_step_with_row_sharded_tables(mesh, citation200):
     """The full SPMD training-step flow (spmd_init + shard_batch +
     make_spmd_train_step) over ROW-SHARDED fused tables: shard_batch
     must keep the caller's 'model'-axis placement (not re-replicate),
     and training must converge identically to the replicated setup."""
     import optax
 
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.models import DeviceSampledGraphSage
     from euler_tpu.parallel import (
-        DeviceFeatureStore, DeviceNeighborTable, make_mesh,
-        make_spmd_train_step, shard_batch, spmd_init,
+        DeviceFeatureStore, DeviceNeighborTable, make_spmd_train_step,
+        shard_batch, spmd_init,
     )
 
-    mesh = make_mesh(model_parallel=2, devices=jax.devices()[:8])
-    data = synthetic_citation("t", n=200, d=8, num_classes=3,
-                              train_per_class=20, val=20, test=30, seed=6)
-    g = data.engine
+    g = citation200.engine
     store = DeviceFeatureStore(g, ["feature"], label_fid="label",
                                label_dim=3, mesh=mesh, shard_rows=True)
     sampler = DeviceNeighborTable(g, cap=8, mesh=mesh, shard_rows=True,
@@ -491,18 +547,15 @@ def test_spmd_train_step_with_row_sharded_tables():
     assert losses[-1] < losses[0]
 
 
-def test_device_sampled_gcn_encoder():
+def test_device_sampled_gcn_encoder(citation120):
     """The on-device sampling path composes with the GCN fanout encoder
     too (encoder='gcn') — sampling is encoder-agnostic."""
     import jax
 
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.models import DeviceSampledGraphSage
     from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    data = synthetic_citation("t", n=120, d=8, num_classes=3,
-                              train_per_class=10, val=15, test=20, seed=9)
-    g = data.engine
+    g = citation120.engine
     store = DeviceFeatureStore(g, ["feature"], label_fid="label",
                                label_dim=3)
     sampler = DeviceNeighborTable(g, cap=8)
@@ -512,7 +565,7 @@ def test_device_sampled_gcn_encoder():
     batch = {"rows": [roots], "sample_seed": np.uint32(1),
              "feature_table": store.features, "label_table": store.labels,
              **sampler.tables}
-    params = model.init(jax.random.key(0), batch)
+    params = _init(model, jax.random.key(0), batch)
     loss, emb = jax.jit(
         lambda p, b: (model.apply(p, b).loss, model.apply(p, b).embedding)
     )(params, batch)
@@ -567,7 +620,7 @@ def test_hub_zero_total_weight_pads():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g = _star_graph(10, np.zeros(10, np.float32))
     t = DeviceNeighborTable(g, cap=4)
@@ -582,7 +635,7 @@ def test_hub_few_positive_weights_keeps_them_all():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     w = np.zeros(20, np.float32)
     w[[3, 7]] = 1.0
@@ -636,12 +689,9 @@ def test_device_tables_from_arrays_roundtrip(ring_graph):
 # Row-sharded HBM tables over the 'model' axis (VERDICT r2 missing #4):
 # per-chip memory 1/mp, gathers = masked local take + psum over 'model'.
 # ---------------------------------------------------------------------------
-def test_sharded_gather_matches_local_take():
-    from euler_tpu.parallel import (
-        make_mesh, make_table_gather, put_row_sharded,
-    )
+def test_sharded_gather_matches_local_take(mesh):
+    from euler_tpu.parallel import make_table_gather, put_row_sharded
 
-    mesh = make_mesh(model_parallel=2)          # {data: 4, model: 2}
     rng = np.random.default_rng(0)
     tab = rng.normal(0, 1, (21, 5)).astype(np.float32)  # odd rows → pad
     tab_s = put_row_sharded(tab, mesh)
@@ -666,15 +716,12 @@ def test_sharded_gather_matches_local_take():
     np.testing.assert_array_equal(np.asarray(goti), itab[rows])
 
 
-def test_sharded_device_sampler_matches_replicated():
+def test_sharded_device_sampler_matches_replicated(mesh):
     """sample_hop over row-sharded tables draws the SAME neighbors as
     the replicated fast path under the same key."""
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, make_mesh, make_table_gather, sample_hop,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable, make_table_gather
 
     g, ids = _weighted_ring(16)
-    mesh = make_mesh(model_parallel=2)
     t_rep = DeviceNeighborTable(g, cap=4)
     t_sh = DeviceNeighborTable(g, cap=4, mesh=mesh, shard_rows=True)
     assert t_sh.neighbors.addressable_shards[0].data.shape[0] == \
@@ -690,22 +737,16 @@ def test_sharded_device_sampler_matches_replicated():
     np.testing.assert_array_equal(np.asarray(out_rep), np.asarray(out_sh))
 
 
-def test_device_sampled_model_with_sharded_tables():
+def test_device_sampled_model_with_sharded_tables(mesh, citation120):
     """End-to-end: DeviceSampledGraphSage(table_mesh=...) trains one jit
     step with ALL tables (nbr/cum/feature/label) row-sharded over
     'model' and roots sharded over 'data'."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel import (
-        DeviceFeatureStore, DeviceNeighborTable, make_mesh,
-    )
+    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    mesh = make_mesh(model_parallel=2)
-    data = synthetic_citation("t", n=120, d=8, num_classes=3,
-                              train_per_class=10, val=15, test=20, seed=9)
-    g = data.engine
+    g = citation120.engine
     store = DeviceFeatureStore(g, ["feature"], label_fid="label",
                                label_dim=3, mesh=mesh, shard_rows=True)
     sampler = DeviceNeighborTable(g, cap=8, mesh=mesh, shard_rows=True)
@@ -720,7 +761,7 @@ def test_device_sampled_model_with_sharded_tables():
         batch = {"rows": [roots_dev], "sample_seed": np.uint32(1),
                  "feature_table": store.features,
                  "label_table": store.labels, **sampler.tables}
-        params = model.init(jax.random.key(0), batch)
+        params = _init(model, jax.random.key(0), batch)
         loss, emb = jax.jit(
             lambda p, b: (model.apply(p, b).loss,
                           model.apply(p, b).embedding))(params, batch)
@@ -732,7 +773,7 @@ def test_device_sampled_model_with_sharded_tables():
 # Device-resident walks / pairs / negatives (VERDICT r2 missing #3)
 # ---------------------------------------------------------------------------
 def test_walk_rows_stays_on_graph():
-    from euler_tpu.parallel import DeviceNeighborTable, walk_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _weighted_ring(12)
     t = DeviceNeighborTable(g, cap=4)
@@ -752,7 +793,7 @@ def test_walk_rows_stays_on_graph():
 
 
 def test_walk_rows_dead_end_sticks_at_pad():
-    from euler_tpu.parallel import DeviceNeighborTable, walk_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g = _star_graph(3, np.ones(3, np.float32))  # satellites are sinks
     t = DeviceNeighborTable(g, cap=2)
@@ -767,7 +808,7 @@ def test_walk_rows_dead_end_sticks_at_pad():
 def test_node2vec_bias_prefers_return_when_p_small():
     """p → 0 makes the 1/p return weight dominate: on a bidirected ring
     with several choices, most step-2 draws return to the root."""
-    from euler_tpu.parallel import DeviceNeighborTable, walk_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     from euler_tpu.graph import GraphBuilder
 
@@ -797,7 +838,6 @@ def test_node2vec_bias_prefers_return_when_p_small():
 
 def test_gen_pair_rows_matches_host_gen_pair():
     from euler_tpu.ops.walk_ops import gen_pair
-    from euler_tpu.parallel import gen_pair_rows
 
     walks = np.arange(24, dtype=np.int32).reshape(4, 6)
     dev = np.asarray(gen_pair_rows(jnp.asarray(walks), 2, 2))
@@ -808,7 +848,7 @@ def test_gen_pair_rows_matches_host_gen_pair():
 
 def test_device_node_sampler_weighted():
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import DeviceNodeSampler, sample_global_rows
+    from euler_tpu.parallel import DeviceNodeSampler
 
     b = GraphBuilder()
     ids = np.arange(4, dtype=np.uint64)
@@ -902,10 +942,7 @@ def test_fused_sampling_matches_split_tables():
     import jax
     import jax.numpy as jnp
 
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, fuse_tables, sample_fanout_rows,
-        sample_fanout_rows_fused, sample_hop, sample_hop_fused,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4)
@@ -940,20 +977,15 @@ def test_fused_sampling_matches_split_tables():
     np.testing.assert_array_equal(np.asarray(b), np.asarray(c))
 
 
-def test_fused_sharded_matches_split_sharded():
+def test_fused_sharded_matches_split_sharded(mesh):
     """fused=True composed with shard_rows=True (VERDICT r3 weak #4):
     the [N+1, 2C] fused table row-sharded over 'model' must draw
     bit-identically to (a) the split row-sharded tables and (b) the
     replicated fused table, under the same key — so the HBM-capacity
     lever and the gather-count lever stack with no semantic cost."""
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, make_mesh, make_table_gather,
-        sample_fanout_rows, sample_fanout_rows_fused, sample_hop,
-        sample_hop_fused,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable, make_table_gather
 
     g, ids = _weighted_ring(16)
-    mesh = make_mesh(model_parallel=2)
     t_rep = DeviceNeighborTable(g, cap=4, fused=True)
     t_split = DeviceNeighborTable(g, cap=4, mesh=mesh, shard_rows=True)
     t_fs = DeviceNeighborTable(g, cap=4, mesh=mesh, shard_rows=True,
@@ -989,16 +1021,13 @@ def test_fused_sharded_matches_split_sharded():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_shard_batch_preserves_row_sharded_tables():
+def test_shard_batch_preserves_row_sharded_tables(mesh):
     """shard_batch must keep caller placement for already-placed tables:
     force-replicating a row-sharded table would all-gather it onto every
     chip, defeating the HBM-capacity lever (code-review r4)."""
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, make_mesh, shard_batch,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable, shard_batch
 
     g, ids = _weighted_ring(16)
-    mesh = make_mesh(model_parallel=2)
     t = DeviceNeighborTable(g, cap=4, mesh=mesh, shard_rows=True,
                             fused=True)
     batch = {"rows": [np.arange(8, dtype=np.int32)],
@@ -1018,30 +1047,28 @@ def test_shard_batch_preserves_row_sharded_tables():
     assert out3["feature_table"].sharding.spec == ()
 
 
-def test_table_gather_rejects_unpadded_table():
+def test_table_gather_rejects_unpadded_table(mesh):
     """A replicated (unpadded) table reaching the sharded gather must
     fail with an actionable error at trace time, not an obscure
     shard_map divisibility failure (code-review r4)."""
-    from euler_tpu.parallel import make_mesh, make_table_gather
+    from euler_tpu.parallel import make_table_gather
 
-    mesh = make_mesh(model_parallel=2)
     gather = make_table_gather(mesh)
     tab = jnp.zeros((17, 4), jnp.float32)   # 17 % 2 != 0
     with pytest.raises(ValueError, match="put_row_sharded"):
         gather(tab, jnp.zeros(4, jnp.int32))
 
 
-def test_unsupervised_device_sampled_sharded_matches_replicated():
+def test_unsupervised_device_sampled_sharded_matches_replicated(mesh):
     """DeviceSampledUnsupervisedSage(table_mesh=...) over row-sharded
     (fused) tables must produce the same loss as the replicated run
     under the same key (code-review r4: the model used plain jnp.take
     on whatever table it was handed)."""
     from euler_tpu.models import DeviceSampledUnsupervisedSage
-    from euler_tpu.parallel import DeviceNeighborTable, make_mesh
+    from euler_tpu.parallel import DeviceNeighborTable
     from euler_tpu.parallel.device_walk import DeviceNodeSampler
 
     g, ids = _weighted_ring(16)
-    mesh = make_mesh(model_parallel=2)
     negs = DeviceNodeSampler(g, mesh=mesh)
     roots = jnp.arange(8, dtype=jnp.int32)
 
@@ -1064,23 +1091,22 @@ def test_unsupervised_device_sampled_sharded_matches_replicated():
             batch["feature_table"] = put_row_sharded(
                 np.asarray(batch["feature_table"]), mesh)
         with mesh:
-            params = model.init(jax.random.key(0), batch)
+            params = _init(model, jax.random.key(0), batch)
             losses[name] = float(jax.jit(
                 lambda p, b: model.apply(p, b).loss)(params, batch))
     assert np.isfinite(losses["rep"])
     np.testing.assert_allclose(losses["fs"], losses["rep"], rtol=1e-5)
 
 
-def test_walk_model_sharded_matches_replicated():
+def test_walk_model_sharded_matches_replicated(mesh):
     """DeviceSampledSkipGram(table_mesh=...) over row-sharded walk
     tables must produce the same loss as the replicated run under the
     same key (walk_rows threads the masked-take+psum gather)."""
     from euler_tpu.models import DeviceSampledSkipGram
-    from euler_tpu.parallel import DeviceNeighborTable, make_mesh
+    from euler_tpu.parallel import DeviceNeighborTable
     from euler_tpu.parallel.device_walk import DeviceNodeSampler
 
     g, ids = _weighted_ring(16)
-    mesh = make_mesh(model_parallel=2)
     negs = DeviceNodeSampler(g, mesh=mesh)
     roots = jnp.arange(8, dtype=jnp.int32)
     losses = {}
@@ -1095,7 +1121,7 @@ def test_walk_model_sharded_matches_replicated():
                  "nbr_table": t.neighbors, "cum_table": t.cum_weights,
                  **negs.tables}
         with mesh:
-            params = model.init(jax.random.key(0), batch)
+            params = _init(model, jax.random.key(0), batch)
             losses[name] = float(jax.jit(
                 lambda p, b: model.apply(p, b).loss)(params, batch))
     assert np.isfinite(losses["rep"])
@@ -1104,7 +1130,6 @@ def test_walk_model_sharded_matches_replicated():
     # the node2vec-biased path (p/q != 1) reads tables through the same
     # gather hook: sharded walks must equal replicated draw-for-draw
     from euler_tpu.parallel import make_table_gather
-    from euler_tpu.parallel.device_walk import walk_rows
 
     t_rep = DeviceNeighborTable(g, cap=4)
     t_sh = DeviceNeighborTable(g, cap=4, mesh=mesh, shard_rows=True)
@@ -1148,22 +1173,16 @@ def test_walk_model_sharded_matches_replicated():
     assert np.asarray(w2_sh).max() <= t2_rep.pad_row
 
 
-def test_device_sampled_model_with_fused_sharded_tables():
+def test_device_sampled_model_with_fused_sharded_tables(mesh, citation120):
     """End-to-end: DeviceSampledGraphSage trains a jit step with the
     FUSED sampling table row-sharded over 'model' (composition of the
     two throughput levers) alongside sharded feature/label tables."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel import (
-        DeviceFeatureStore, DeviceNeighborTable, make_mesh,
-    )
+    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    mesh = make_mesh(model_parallel=2)
-    data = synthetic_citation("t", n=120, d=8, num_classes=3,
-                              train_per_class=10, val=15, test=20, seed=9)
-    g = data.engine
+    g = citation120.engine
     store = DeviceFeatureStore(g, ["feature"], label_fid="label",
                                label_dim=3, mesh=mesh, shard_rows=True)
     sampler = DeviceNeighborTable(g, cap=8, mesh=mesh, shard_rows=True,
@@ -1178,7 +1197,7 @@ def test_device_sampled_model_with_fused_sharded_tables():
         batch = {"rows": [roots_dev], "sample_seed": np.uint32(1),
                  "feature_table": store.features,
                  "label_table": store.labels, **sampler.tables}
-        params = model.init(jax.random.key(0), batch)
+        params = _init(model, jax.random.key(0), batch)
         loss, emb = jax.jit(
             lambda p, b: (model.apply(p, b).loss,
                           model.apply(p, b).embedding))(params, batch)
@@ -1192,9 +1211,7 @@ def test_fused_sampling_pad_row_resolves_to_pad():
     import jax.numpy as jnp
 
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, fuse_tables, sample_hop_fused,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable
 
     b = GraphBuilder()
     b.add_nodes(np.array([1, 2], dtype=np.uint64))
@@ -1210,10 +1227,13 @@ def test_fused_sampling_pad_row_resolves_to_pad():
 
 
 def test_dryrun_backend_switch_error_paths():
-    """dryrun_multichip's platform-switch fallbacks (VERDICT r2 weak #8):
+    """dryrun_multichip's platform switch (__graft_entry__.force_cpu_devices,
+    the first thing the dry run calls; VERDICT r2 weak #8):
     (a) backend already initialized with too few devices → the
-    clear_backends route recovers; (b) when every route fails, the
-    RuntimeError reports each route's error rather than a bare count."""
+    clear_backends route recovers, and a step runs on the 4 devices;
+    (b) when every route fails, the RuntimeError reports each route's
+    error rather than a bare count. (The whole dry run stays covered by
+    the slow test_graft_entry_selftest_subprocess.)"""
     import subprocess
     import sys
     from pathlib import Path
@@ -1228,11 +1248,22 @@ def test_dryrun_backend_switch_error_paths():
             "import jax\n"
             "jax.config.update('jax_platforms', 'cpu')\n"
             "assert len(jax.devices()) == 1\n"   # backend now live
-            "from __graft_entry__ import dryrun_multichip\n"
-            "dryrun_multichip(4)\n" % str(repo))],
+            "from __graft_entry__ import force_cpu_devices\n"
+            "devices = force_cpu_devices(4)\n"
+            "assert len(devices) == 4 and len(set(devices)) == 4\n"
+            "import numpy as np\n"
+            "from jax.sharding import Mesh, NamedSharding, PartitionSpec\n"
+            "mesh = Mesh(np.asarray(devices), ('data',))\n"
+            "x = jax.device_put(np.arange(8.0), NamedSharding(\n"
+            "    mesh, PartitionSpec('data')))\n"
+            "y = jax.jit(lambda v: (v * v).sum())(x)\n"
+            "assert len(x.sharding.device_set) == 4\n"
+            "assert float(y) == 140.0\n"
+            "print('RECOVERED_ROUTE_STEP_OK', len(devices))\n"
+            % str(repo))],
         capture_output=True, text=True, timeout=480, cwd=str(repo), env=env)
     assert ok.returncode == 0, ok.stdout[-2000:] + ok.stderr[-2000:]
-    assert "device-sampled step" in ok.stdout
+    assert "RECOVERED_ROUTE_STEP_OK 4" in ok.stdout
 
     # (b) break both routes: clear_backends raising must surface its
     # error in the final RuntimeError message
@@ -1247,7 +1278,7 @@ def test_dryrun_backend_switch_error_paths():
             "jex.clear_backends = boom\n"
             "import __graft_entry__ as ge\n"
             "try:\n"
-            "    ge.dryrun_multichip(4)\n"
+            "    ge.force_cpu_devices(4)\n"
             "except RuntimeError as e:\n"
             "    assert 'simulated plugin wedge' in str(e), str(e)\n"
             "    assert 'only 1 devices visible' in str(e), str(e)\n"
@@ -1299,7 +1330,7 @@ def test_unsupervised_fused_matches_split(ring_graph):
         batch = {"rows": [roots], "sample_seed": np.uint32(7),
                  "feature_table": store.features, **tab.tables,
                  **neg.tables}
-        params = model.init(jax.random.key(0), batch)
+        params = _init(model, jax.random.key(0), batch)
         losses[mode] = float(model.apply(params, batch).loss)
     assert losses["split"] == losses["fused"], losses
 
@@ -1372,7 +1403,6 @@ def test_device_layerwise_adjacency_matches_host():
     from euler_tpu.dataflow import LayerwiseDataFlow
     from euler_tpu.graph import GraphBuilder
     from euler_tpu.parallel import DeviceNeighborTable
-    from euler_tpu.parallel.device_layerwise import sample_layerwise_rows
 
     rng = np.random.default_rng(0)
     n = 40
@@ -1493,20 +1523,19 @@ def test_device_layerwise_eval_via_host_flow():
             label_fid="label", label_dim=3, eval_via_flow=True)
 
 
-def test_sharded_int8_feature_gather_dequantizes():
+def test_sharded_int8_feature_gather_dequantizes(mesh):
     """Row-sharded int8 feature table + masked-take/psum gather +
     post-gather dequant: the full multi-chip int8 path a
     DeviceSampledGraphSage(table_mesh=...) step uses. Int8 psum cannot
     overflow (exactly one chip contributes non-zero per row) and the
     dequantized rows must match the replicated-table reference."""
     from euler_tpu.models.graphsage import gather_feature_rows
-    from euler_tpu.parallel import make_mesh, make_table_gather
+    from euler_tpu.parallel import make_table_gather
     from euler_tpu.parallel.feature_store import (
         dequantize_rows, quantize_int8,
     )
     from euler_tpu.parallel.placement import put_row_sharded
 
-    mesh = make_mesh(model_parallel=2)
     rng = np.random.default_rng(5)
     feats = rng.normal(0, 3, (30, 6)).astype(np.float32)
     q, scale = quantize_int8(feats)
@@ -1654,7 +1683,7 @@ def test_ema_update_first_write_full_scale():
 # slow (~25s): sharded-act-cache estimator loop; the act-cache path
 # keeps a tier-1 smoke via the examples keep-set (--act_cache variant)
 @pytest.mark.slow
-def test_act_cache_row_sharded():
+def test_act_cache_row_sharded(mesh):
     """The activation cache composes with model-axis sharding: re-placed
     row-sharded (shard_act_cache), the estimator's jitted train step
     keeps it sharded (per-chip bytes 1/mp) and writes still land."""
@@ -1665,11 +1694,8 @@ def test_act_cache_row_sharded():
     from euler_tpu.estimator import NodeEstimator
     from euler_tpu.models import DeviceSampledScalableSage
     from euler_tpu.models.graphsage import shard_act_cache
-    from euler_tpu.parallel import (
-        DeviceFeatureStore, DeviceNeighborTable, make_mesh,
-    )
+    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
 
-    mesh = make_mesh(model_parallel=2)
     data = synthetic_citation("tshc", n=200, d=16, num_classes=3,
                               train_per_class=10, val=20, test=40, seed=11)
     g = data.engine
@@ -1795,7 +1821,6 @@ def test_device_sampled_remat_trains():
 
     import pytest
 
-    from euler_tpu.parallel import make_mesh
     with pytest.raises(ValueError, match="replicated tables only"):
         m = DeviceSampledGraphSage(num_classes=3, multilabel=False,
                                    dim=8, fanouts=(2,), remat=True,
@@ -1816,7 +1841,6 @@ def test_sample_hop_count_aware_pick_bit_parity():
     the flat pick is element-count-bound and loses 77.9ms vs 21.7ms at
     products scale). Both paths must be draw-for-draw identical: same
     inverse-CDF cols, same neighbor values."""
-    from euler_tpu.parallel.device_sampler import sample_hop
 
     rng = np.random.default_rng(3)
     N, C = 200, 8
@@ -1825,15 +1849,19 @@ def test_sample_hop_count_aware_pick_bit_parity():
         rng.random((N + 1, C)).astype(np.float32), axis=1))
     rows = jnp.asarray(rng.integers(0, N, 300), jnp.int32)
     key = jax.random.key(5)
-    for count in (1, 2, 4, 10):   # spans both sides of the threshold
-        out = sample_hop(nbr, cum, rows, count, key)
+    @functools.partial(jax.jit, static_argnums=0)
+    def flat_pick(count, nbr, cum, rows, key):
         c = jnp.take(cum, rows, axis=0)
         u = jax.random.uniform(key, (rows.shape[0], count)) \
             * c[:, -1][:, None]
         col = jnp.clip((c[:, None, :] <= u[:, :, None]).sum(-1),
                        0, C - 1).astype(jnp.int32)
-        ref = jnp.take(nbr.reshape(-1),
-                       (rows[:, None] * C + col).reshape(-1))
+        return jnp.take(nbr.reshape(-1),
+                        (rows[:, None] * C + col).reshape(-1))
+
+    for count in (1, 2, 4, 10):   # spans both sides of the threshold
+        out = sample_hop(nbr, cum, rows, count, key)
+        ref = flat_pick(count, nbr, cum, rows, key)
         assert (out == ref).all()
         assert out.shape == (300 * count,)
 
@@ -1873,7 +1901,7 @@ def test_alias_matches_inverse_cdf_marginals():
     """Chi-squared: the alias draw reproduces the inverse-CDF draw's
     marginal distribution on weighted tables, on BOTH sides of the
     count-aware pick split (count=1 flat pick, count>=4 row pick)."""
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     # 2-neighbor rows, weights 1 vs 3 → expected [0.25, 0.75]
     g, ids = _weighted_ring()
@@ -1914,7 +1942,7 @@ def test_alias_zero_degree_and_dead_rows_pad():
     a zero-TOTAL-weight row that still carries neighbor ids (the corner
     the all-sentinel convention pins down)."""
     from euler_tpu.graph import GraphBuilder
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     b = GraphBuilder()
     b.add_nodes(np.arange(5, dtype=np.uint64))
@@ -1938,7 +1966,7 @@ def test_alias_zero_degree_and_dead_rows_pad():
 def test_alias_hub_draws_from_capped_subset():
     """degree > cap: alias draws stay inside the kept C-subset, like
     every other draw path."""
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g = _star_graph(64, np.ones(64, np.float32))
     t = DeviceNeighborTable(g, cap=8, alias=True)
@@ -1951,17 +1979,14 @@ def test_alias_hub_draws_from_capped_subset():
     assert set(np.asarray(out).tolist()) <= kept
 
 
-def test_alias_layout_rejections():
+def test_alias_layout_rejections(mesh):
     """alias needs the replicated split layout; uniform and alias are
     exclusive at the sample_hop level."""
-    from euler_tpu.parallel import (
-        DeviceNeighborTable, make_mesh, make_table_gather, sample_hop,
-    )
+    from euler_tpu.parallel import DeviceNeighborTable, make_table_gather
 
     g, _ = _weighted_ring()
     with pytest.raises(ValueError, match="split"):
         DeviceNeighborTable(g, cap=4, alias=True, fused=True)
-    mesh = make_mesh(model_parallel=2)
     with pytest.raises(ValueError, match="replicated"):
         DeviceNeighborTable(g, cap=4, alias=True, mesh=mesh,
                             shard_rows=True)
@@ -2008,7 +2033,7 @@ def test_from_arrays_alias_and_chunked_recompute(monkeypatch):
     rows (the bench-cache path), and the chunked uniform recompute is
     chunk-size invariant (advisor r5: products scale must not hold
     full-table transients)."""
-    from euler_tpu.parallel import DeviceNeighborTable, sample_hop
+    from euler_tpu.parallel import DeviceNeighborTable
     from euler_tpu.parallel import device_sampler
 
     g, ids = _weighted_ring()
@@ -2037,7 +2062,7 @@ def test_walk_rows_alias_stays_on_graph_and_dead_ends():
     """walk_rows(alias_table=...): every step lands on a true
     out-neighbor; dead ends stick at pad — the chained count=1 flat
     pick composes with the alias draw."""
-    from euler_tpu.parallel import DeviceNeighborTable, walk_rows
+    from euler_tpu.parallel import DeviceNeighborTable
 
     g, ids = _weighted_ring(12)
     t = DeviceNeighborTable(g, cap=4, alias=True)
@@ -2069,7 +2094,6 @@ def test_layerwise_alias_matches_flat_pool_distribution():
     alias) reproduces the flat slot-weight draw's distribution:
     P(slot) = w/ΣW either way."""
     from euler_tpu.parallel import DeviceNeighborTable
-    from euler_tpu.parallel.device_layerwise import sample_layerwise_rows
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4, alias=True)
@@ -2086,22 +2110,18 @@ def test_layerwise_alias_matches_flat_pool_distribution():
     assert adjs[0].shape == (1, 601)
 
 
-def test_device_sampled_graphsage_alias_trains():
+def test_device_sampled_graphsage_alias_trains(citation300):
     """Model-level wiring: a DeviceNeighborTable(alias=True) sampler
     routes DeviceSampledGraphSage through the alias draw (batch carries
     alias_table via sampler.tables) and trains to the same quality bar
     as the weighted/uniform estimator tests."""
     from euler_tpu.dataflow import FanoutDataFlow
-    from euler_tpu.dataset.base_dataset import synthetic_citation
     from euler_tpu.estimator import NodeEstimator
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel import DeviceFeatureStore, DeviceNeighborTable
+    from euler_tpu.parallel import DeviceNeighborTable
 
-    data = synthetic_citation("t", n=300, d=16, num_classes=3,
-                              train_per_class=30, val=40, test=60, seed=2)
+    data, store, _ = citation300
     g = data.engine
-    store = DeviceFeatureStore(g, ["feature"], label_fid="label",
-                               label_dim=data.num_classes)
     sampler = DeviceNeighborTable(g, cap=16, alias=True)
     assert "alias_table" in sampler.tables
     est = NodeEstimator(

@@ -331,12 +331,16 @@ def test_deadline_propagation_sheds_queued_work(tmp_path):
     srv = start_service(d, shard_idx=0, shard_num=1, port=0)
     try:
         configure_rpc(mux=True, connections=1)
+        # the warm-up read goes out under the engine's default, generous
+        # policy (on a busy box a 5 ms budget sheds it before the part
+        # under test starts); only the reads issued while the pool is
+        # pinned carry the 5 ms budget
         eng = RemoteGraphEngine(
             f"hosts:127.0.0.1:{srv.port}", seed=11,
-            deadline_propagation=True,
-            retry_policy=RetryPolicy(deadline_s=0.005, max_attempts=1))
+            deadline_propagation=True)
         warm = eng.get_dense_feature(ids[:8], [0], [16])
         assert warm[0].shape == (8, 16)
+        eng.retry = RetryPolicy(deadline_s=0.005, max_attempts=1)
         # pin every pool worker: concurrent delta applies serialize on
         # the apply mutex INSIDE their pool tasks, each an O(graph)
         # rebuild of the 20k-node snapshot — far longer than the 5ms
