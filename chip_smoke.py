@@ -423,7 +423,9 @@ def phase_c(*, seed, device, cpu_device, n_nodes=1000, feat_dim=32, cap=8,
     import jax
 
     from euler_tpu.models import DeviceSampledGraphSage
-    from euler_tpu.parallel.device_sampler import sample_fanout_rows
+    from euler_tpu.parallel.device_sampler import (
+        sample_fanout_rows, store_rows,
+    )
 
     out = {"n_nodes": n_nodes, "feat_dim": feat_dim, "cap": cap, "dim": dim,
            "fanouts": list(fanouts), "batch": batch,
@@ -438,7 +440,8 @@ def phase_c(*, seed, device, cpu_device, n_nodes=1000, feat_dim=32, cap=8,
         uniform = not weighted
         host = {"rows": [roots], "sample_seed": np.uint32(1),
                 "feature_table": t["feat"], "label_table": t["label"],
-                "nbr_table": t["nbr"], "cum_table": t["cum"]}
+                "nbr_table": store_rows(t["nbr"], "nbr"),
+                "cum_table": store_rows(t["cum"], "cum")}
         model = DeviceSampledGraphSage(
             num_classes=num_classes, multilabel=False, dim=dim,
             fanouts=tuple(fanouts), uniform_sampling=uniform)

@@ -13,6 +13,7 @@
 #include <unistd.h>
 
 #include <atomic>
+#include <chrono>
 #include <thread>
 #include <csignal>
 #include <cmath>
@@ -905,11 +906,26 @@ void TestServerTraceBreakdown() {
   ch.set_mux(true);
   std::vector<char> reply;
   uint64_t t0 = ctr.trace_propagated.load();
+  // the server books a call (phase histograms, serialize last, then the
+  // ring) AFTER it has written the reply, so the caller can be back
+  // first: wait until `calls` more of them are booked
+  uint64_t n = 0, sum = 0;
+  uint64_t counts[ServerTraceStats::kTraceBuckets + 1];
+  GlobalServerTraceStats().HistSnapshot(0, 3, &n, &sum, counts);
+  const uint64_t booked0 = n;
+  auto wait_booked = [&](uint64_t calls) {
+    for (int i = 0; i < 5000; ++i) {
+      GlobalServerTraceStats().HistSnapshot(0, 3, &n, &sum, counts);
+      if (n >= booked0 + calls) return;
+      std::this_thread::sleep_for(std::chrono::milliseconds(1));
+    }
+  };
 
   // untraced call: nothing stamped, nothing ringed (wire identity is
   // pinned at the byte level by the Python interop tests)
   CHECK_OK(ch.Call(0 /*kExecute*/, w.buffer(), &reply, /*max_retries=*/2));
   CHECK_TRUE(ctr.trace_propagated.load() == t0);
+  wait_booked(1);
   GlobalServerTraceStats().Drain(&recs);
   CHECK_TRUE(recs.empty());
 
@@ -918,7 +934,12 @@ void TestServerTraceBreakdown() {
   CHECK_OK(ch.Call(0, w.buffer(), &reply, 2, /*deadline=*/0,
                    /*map_epoch=*/0, WireTrace{77, 5}));
   CHECK_TRUE(ctr.trace_propagated.load() == t0 + 1);
-  GlobalServerTraceStats().Drain(&recs);
+  wait_booked(2);
+  for (int i = 0; i < 5000 && recs.empty(); ++i) {
+    GlobalServerTraceStats().Drain(&recs);
+    if (recs.empty())
+      std::this_thread::sleep_for(std::chrono::milliseconds(1));
+  }
   CHECK_TRUE(recs.size() == 1);
   CHECK_TRUE(recs[0].trace_id == 77 && recs[0].parent_span == 5);
   CHECK_TRUE(recs[0].span_id != 0);
@@ -926,8 +947,6 @@ void TestServerTraceBreakdown() {
   CHECK_TRUE(recs[0].start_unix_us > 0);
 
   // the always-on phase histograms saw both calls (queue + execute)
-  uint64_t n = 0, sum = 0;
-  uint64_t counts[ServerTraceStats::kTraceBuckets + 1];
   CHECK_TRUE(GlobalServerTraceStats().HistSnapshot(0, 0, &n, &sum, counts));
   CHECK_TRUE(n >= 2);
   CHECK_TRUE(GlobalServerTraceStats().HistSnapshot(0, 2, &n, &sum, counts));

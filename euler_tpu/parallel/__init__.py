@@ -14,11 +14,15 @@ from euler_tpu.parallel.device_sampler import (  # noqa: F401
     DeviceNeighborTable,
     build_alias_tables,
     fuse_tables,
+    logical_rows,
     make_table_gather,
     sample_fanout_rows,
     sample_fanout_rows_fused,
     sample_hop,
     sample_hop_fused,
+    store_rows,
+    stored_info,
+    take_rows,
 )
 from euler_tpu.parallel.placement import (  # noqa: F401
     put_replicated,

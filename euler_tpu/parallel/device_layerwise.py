@@ -49,7 +49,7 @@ import jax.numpy as jnp
 
 
 from euler_tpu.parallel.device_sampler import (  # noqa: E402
-    _alias_pick, slot_weights,
+    _alias_pick, slot_weights, stored_info, take_rows,
 )
 
 
@@ -76,8 +76,8 @@ def sample_layerwise_rows(nbr_table: jax.Array, cum_table: jax.Array,
     n_frontier = roots.shape[0]  # frontier = last pool (roots at l=0)
     for m in layer_sizes:
         key, kg = jax.random.split(key)
-        nbr = jnp.take(nbr_table, cur, axis=0)          # [n, C] rows
-        w = slot_weights(jnp.take(cum_table, cur, axis=0))
+        nbr = take_rows(nbr_table, cur, "nbr")          # [n, C] rows
+        w = slot_weights(take_rows(cum_table, cur, "cum"))
         # pool draw expands the FRONTIER (a suffix of cur) only — the
         # host engine's layer-by-layer semantics; the full cur rows are
         # still needed below for the inter-level adjacency.
@@ -93,8 +93,8 @@ def sample_layerwise_rows(nbr_table: jax.Array, cum_table: jax.Array,
             idx = jnp.searchsorted(tot_cum, u, side="right")
             idx = jnp.minimum(idx,
                               tot_cum.shape[0] - 1).astype(jnp.int32)
-            arow = jnp.take(alias_table, jnp.take(cur_f, idx),
-                            axis=0)                         # [m, C]
+            arow = take_rows(alias_table, jnp.take(cur_f, idx),
+                             "alias")                       # [m, C]
             key, ka = jax.random.split(key)
             ua = jax.random.uniform(ka, (2, int(m), 1))
             col, deg = _alias_pick(arow, ua[0], ua[1])      # [m, 1]
@@ -102,7 +102,8 @@ def sample_layerwise_rows(nbr_table: jax.Array, cum_table: jax.Array,
                                        col, axis=1)[:, 0]   # [m]
             # zero-total frontier rows carry no draw mass; if the WHOLE
             # frontier is dead every draw resolves to pad explicitly
-            pool = jnp.where(deg > 0, pool, nbr_table.shape[0] - 1)
+            pool = jnp.where(deg > 0, pool,
+                             stored_info(nbr_table).pad_row)
         else:
             flat_cum = jnp.cumsum(w[-n_frontier:].reshape(-1))
             total = flat_cum[-1]

@@ -13,7 +13,13 @@ itself onto the device:
     cumulative weights [N, C] (float32) live in HBM — the
     CompactWeightedCollection layout (reference
     euler/common/compact_weighted_collection.h:55) transposed into two
-    dense tables an XLA gather can hit;
+    dense tables an XLA gather can hit. They are STORED as their bytes,
+    int8 [N, 4C] in byte planes (store_rows; the alias words too): the
+    runtime lays a C-wide 32-bit table out with the nodes on the lanes,
+    a row in C/8 tiles (36 ns a gathered row on v5e), and a 4C-byte
+    int8 row along the lanes of one tile (9 ns). Same bytes in HBM;
+    every row read goes through take_rows, which gives the words back
+    bit for bit, and the tables' logical facts come from stored_info;
   - per hop, sampling is: uniform draw → per-row inverse-CDF over C
     cumulative weights (C compares on the VPU) → gather neighbor rows.
     Pure XLA inside the jitted train step; composes with lax.scan
@@ -34,7 +40,7 @@ for exact parity.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +54,11 @@ class DeviceNeighborTable:
     convention) so the same int32 rows index features, labels, and
     adjacency. Row N (= pad_row) is an all-pad row: sampling from it
     yields pad_row again, mirroring the host sampler's default_id pads.
+
+    `neighbors`, `cum_weights` and `alias_table` (and `tables`) hold the
+    STORED form, int8 [N+1, 4C] (store_rows): read rows of them with
+    take_rows, the whole logical table with logical_rows. The fused
+    table keeps its [N+1, 2C] int32 form; `host_tables` stay logical.
 
     alias=True additionally builds the per-row Vose alias table
     (build_alias_tables): one packed int32 word per slot, enabling the
@@ -189,13 +200,12 @@ class DeviceNeighborTable:
                 self.fused_table = put_replicated(fused_tab, mesh)
             self.neighbors = None
             self.cum_weights = None
-        elif self.shard_rows:
-            self.neighbors = put_row_sharded(nbr_tab, mesh)
-            self.cum_weights = put_row_sharded(cum, mesh)
         else:
-            self.neighbors = put_replicated(nbr_tab, mesh)
-            self.cum_weights = put_replicated(cum, mesh)
-        self.alias_table = put_replicated(alias_tab, mesh) \
+            put = put_row_sharded if self.shard_rows else put_replicated
+            self.neighbors = put(store_rows(nbr_tab, "nbr"), mesh)
+            self.cum_weights = put(store_rows(cum, "cum"), mesh)
+        self.alias_table = \
+            put_replicated(store_rows(alias_tab, "alias"), mesh) \
             if alias_tab is not None else None
 
     @property
@@ -262,9 +272,9 @@ class DeviceNeighborTable:
                 nbr = np.array(self.host_tables[0], copy=True)
                 cum = np.array(self.host_tables[1], copy=True)
             else:
-                nbr = np.asarray(self.neighbors).copy()
-                cum = np.asarray(self.cum_weights).copy()
-            alias_tab = (np.asarray(self.alias_table).copy()
+                nbr = logical_rows(self.neighbors, "nbr")
+                cum = logical_rows(self.cum_weights, "cum")
+            alias_tab = (logical_rows(self.alias_table, "alias")
                          if has_alias else None)
         if grown:
             g_nbr = np.full((n_new + 1, C), n_new, dtype=np.int32)
@@ -319,11 +329,13 @@ class DeviceNeighborTable:
                 if self.host_tables is not None:
                     self.host_tables[0][rows] = blk_nbr
                     self.host_tables[1][rows] = blk_cum
-                self.neighbors = self.neighbors.at[rows].set(blk_nbr)
-                self.cum_weights = self.cum_weights.at[rows].set(blk_cum)
+                self.neighbors = self.neighbors.at[rows].set(
+                    store_rows(blk_nbr, "nbr"))
+                self.cum_weights = self.cum_weights.at[rows].set(
+                    store_rows(blk_cum, "cum"))
                 if has_alias:
-                    self.alias_table = \
-                        self.alias_table.at[rows].set(blk_alias)
+                    self.alias_table = self.alias_table.at[rows].set(
+                        store_rows(blk_alias, "alias"))
             else:
                 nbr[rows] = blk_nbr
                 cum[rows] = blk_cum
@@ -695,6 +707,90 @@ def _pick_cols(row: jax.Array, col: jax.Array, exact_f32: bool):
         .astype(row.dtype)
 
 
+# The stored form of the [N+1, C] row tables, defined ONCE here: int8
+# [N+1, 4C], the rows' own bytes in BYTE PLANES — stored[:, b*C + j] is
+# byte b (little-endian) of word j. Same bytes in HBM as the words; a
+# row lies along the lanes of one tile, and putting the words back
+# together is four contiguous lane slices, shifts and ors (an
+# interleaved view would need a strided pick a word, which the chip's
+# compiler turned into whole-array relayouts: PERF.md, PR 29). The
+# logical dtype of each table is part of the contract, not of the
+# stored array.
+_ROW_DTYPES = {"nbr": np.dtype("<i4"), "cum": np.dtype("<f4"),
+               "alias": np.dtype("<i4")}
+
+
+class StoredInfo(NamedTuple):
+    """What a stored row table says of the logical one. pad_row is the
+    id of the trailing all-pad row of a REPLICATED table (row-sharding
+    pads the row count up to the model-axis multiple: those consumers
+    take the pad from the rows' content); ids_exact_f32: every row id
+    rides an f32 lane exactly (_pick_cols' masked lane-sum)."""
+    cap: int
+    pad_row: int
+    ids_exact_f32: bool
+
+
+def stored_info(stored) -> StoredInfo:
+    return StoredInfo(cap=stored.shape[1] // 4,
+                      pad_row=stored.shape[0] - 1,
+                      ids_exact_f32=stored.shape[0] <= (1 << 24))
+
+
+def store_rows(tab: np.ndarray, table: str) -> np.ndarray:
+    """Host [n, C] rows of table `table` ("nbr" | "cum" | "alias") →
+    their stored form, int8 [n, 4C] (one pass over the bytes)."""
+    tab = np.ascontiguousarray(tab, dtype=_ROW_DTYPES[table])
+    n, cap = tab.shape
+    planes = tab.view(np.int8).reshape(n, cap, 4).transpose(0, 2, 1)
+    return np.ascontiguousarray(planes).reshape(n, 4 * cap)
+
+
+def logical_rows(stored, table: str) -> np.ndarray:
+    """A stored table (device or host) → its logical [n, C] rows on
+    the host, bit for bit (store_rows' inverse), in memory of their own."""
+    stored = np.asarray(stored)
+    n, cap = stored.shape[0], stored.shape[1] // 4
+    words = stored.reshape(n, 4, cap).transpose(0, 2, 1)
+    return np.array(words, order="C").view(_ROW_DTYPES[table]) \
+        .reshape(n, cap)
+
+
+def take_rows(stored: jax.Array, rows: jax.Array, table: str,
+              gather=None) -> jax.Array:
+    """stored [N+1, 4C] int8, rows [n] in [0, N] → the logical
+    table[rows], [n, C] in the table's dtype, bit for bit: ONE gather of
+    4C-byte rows (the feature gather's kernel), then the byte planes
+    put back together as words. Row ids are clipped to the table, not
+    tested and filled (a second pass over the gathered bytes). gather
+    (make_table_gather) routes the read of a row-sharded table: its
+    masked take + psum is exact on bytes (the owner's plus zeros).
+    Counted at trace time: table_rows_stored_traces_total{table}."""
+    from euler_tpu import obs
+
+    if stored.dtype != jnp.int8:
+        raise TypeError(
+            f"take_rows reads a STORED table (store_rows: int8 "
+            f"[N+1, 4C]), got {stored.dtype}{list(stored.shape)}: a "
+            "logical [N+1, C] table would be read as garbage")
+    obs.counter(
+        "table_rows_stored_traces_total",
+        "row reads of a stored (byte-plane) neighbour / cumulative-"
+        "weight / alias table traced into a program (or run eagerly)",
+        ("table",)).labels(table=table).inc()
+    if gather is None:
+        got = jnp.take(stored, rows, axis=0, mode="clip")  # [n, 4C] i8
+    else:
+        got = gather(stored, rows)
+    cap = got.shape[1] // 4
+    b = got.astype(jnp.int32)                  # sign-extended bytes
+    words = ((b[:, :cap] & 0xff)
+             | ((b[:, cap:2 * cap] & 0xff) << 8)
+             | ((b[:, 2 * cap:3 * cap] & 0xff) << 16)
+             | (b[:, 3 * cap:] << 24))
+    return jax.lax.bitcast_convert_type(words, _ROW_DTYPES[table])
+
+
 def fuse_tables_host(nbr_tab: np.ndarray, cum_tab: np.ndarray) -> np.ndarray:
     """Host-side fuse_tables (numpy view bitcast, no device transfer) —
     the layout contract is defined ONCE here; fuse_tables mirrors it on
@@ -910,17 +1006,14 @@ def sample_hop(nbr_table: jax.Array, cum_table: jax.Array,
     log-search). Zero-degree rows (total weight 0) resolve to the pad
     slot, whose neighbor entry is pad_row.
 
-    The neighbor pick is count-aware (round-5 on-chip probes,
-    PERF.md): TPU gather cost here is element-count-bound, not
-    byte-bound — at products scale a flat pick of n·count single int32
-    elements ran 77.9ms where a row gather of the same n nodes ran
-    21.7ms — so for count >= 4 the whole [n, C] neighbor row is
-    gathered once per node and the count columns are picked locally
-    (draw-for-draw identical output; _pick_cols uses a masked lane-sum
-    instead of take_along_axis when ids fit f32, which on TPU also
-    lowers to an element-count-bound gather). For small count (the walk
-    family's count=1 chains) the flat pick moves C× fewer bytes at the
-    same element count and stays the right shape.
+    nbr_table / cum_table / alias_table are the STORED tables
+    (store_rows; DeviceNeighborTable.tables); every row read is
+    take_rows. TPU gather cost here is bound by the number of gathered
+    ROWS, not by bytes, so the whole [n, C] neighbor row is read once
+    per node and the count columns are picked locally (_pick_cols: a
+    masked lane-sum when ids fit f32, because take_along_axis lowers to
+    an element-count-bound gather), whatever the count: n rows for n
+    nodes, where a flat pick of single elements would issue n·count.
 
     uniform=True (tables whose rows are unit-weight —
     DeviceNeighborTable.uniform_rows) skips the cum-row gather
@@ -938,16 +1031,13 @@ def sample_hop(nbr_table: jax.Array, cum_table: jax.Array,
     cum-row gather). Distribution-identical to the inverse-CDF draw up
     to the uint16 prob quantization (< 1e-5 per slot; chi-squared
     pinned in tests), NOT draw-for-draw (different u consumption).
-    Composes with the count-aware pick on both sides (row pick for
-    count >= 4, flat pick for the walk family's count = 1 chains).
     Replicated split tables only, and exclusive with uniform=True —
     callers resolve precedence explicitly.
 
     gather (make_table_gather) routes table reads for row-sharded
     tables; that path always has the full rows and picks locally."""
-    C = nbr_table.shape[1]
+    info = stored_info(nbr_table)
     n = rows.shape[0]
-    exact = nbr_table.shape[0] <= (1 << 24)  # ids ride f32 exactly
     if alias_table is not None:
         if gather is not None:
             raise ValueError(
@@ -961,19 +1051,13 @@ def sample_hop(nbr_table: jax.Array, cum_table: jax.Array,
                 "sample_hop: uniform=True and alias_table are exclusive "
                 "— resolve the precedence at the call site (the alias "
                 "draw already covers unit-weight tables)")
-        arow = jnp.take(alias_table, rows, axis=0)     # [n, C]
+        arow = take_rows(alias_table, rows, "alias")   # [n, C]
         u = jax.random.uniform(key, (2, n, count))
         col, deg = _alias_pick(arow, u[0], u[1])
-        pad = nbr_table.shape[0] - 1
-        if count < 4:
-            flat = rows[:, None] * C + col             # [n, k]
-            out = jnp.take(nbr_table.reshape(-1),
-                           flat.reshape(-1)).reshape(n, count)
-        else:
-            nbr = jnp.take(nbr_table, rows, axis=0)    # [n, C]
-            out = _pick_cols(nbr, col, exact)
+        nbr = take_rows(nbr_table, rows, "nbr")        # [n, C]
+        out = _pick_cols(nbr, col, info.ids_exact_f32)
         # dead rows (zero degree / zero total weight) resolve to pad
-        return jnp.where(deg[:, None] > 0, out, pad).reshape(-1)
+        return jnp.where(deg[:, None] > 0, out, info.pad_row).reshape(-1)
     if uniform:
         if gather is not None:
             raise ValueError(
@@ -982,30 +1066,20 @@ def sample_hop(nbr_table: jax.Array, cum_table: jax.Array,
                 "model-axis multiple, so the pad id cannot be derived "
                 "from its shape. Use the weighted path (uniform=False) "
                 "with row-sharded tables.")
-        nbr = jnp.take(nbr_table, rows, axis=0)        # [n, C]
-        pad = nbr_table.shape[0] - 1
-        deg = (nbr != pad).sum(-1).astype(jnp.float32)             # [n]
+        nbr = take_rows(nbr_table, rows, "nbr")        # [n, C]
+        deg = (nbr != info.pad_row).sum(-1).astype(jnp.float32)    # [n]
         u = jax.random.uniform(key, (n, count))
         col = jnp.minimum((u * deg[:, None]).astype(jnp.int32),
                           jnp.maximum(
                               deg[:, None].astype(jnp.int32) - 1, 0))
-        return _pick_cols(nbr, col, exact).reshape(-1)
-    if gather is None:
-        cum = jnp.take(cum_table, rows, axis=0)        # [n, C]
-    else:
-        cum = gather(cum_table, rows)
+        return _pick_cols(nbr, col, info.ids_exact_f32).reshape(-1)
+    cum = take_rows(cum_table, rows, "cum", gather)    # [n, C]
     total = cum[:, -1]
     u = jax.random.uniform(key, (n, count)) * total[:, None]   # [n, k]
     col = (cum[:, None, :] <= u[:, :, None]).sum(-1)   # [n, k]
-    col = jnp.clip(col, 0, C - 1).astype(jnp.int32)
-    if gather is None:
-        if count < 4:
-            flat = rows[:, None] * C + col             # [n, k]
-            return jnp.take(nbr_table.reshape(-1), flat.reshape(-1))
-        nbr = jnp.take(nbr_table, rows, axis=0)        # [n, C]
-    else:
-        nbr = gather(nbr_table, rows)                  # [n, C]
-    return _pick_cols(nbr, col, exact).reshape(-1)
+    col = jnp.clip(col, 0, info.cap - 1).astype(jnp.int32)
+    nbr = take_rows(nbr_table, rows, "nbr", gather)    # [n, C]
+    return _pick_cols(nbr, col, info.ids_exact_f32).reshape(-1)
 
 
 def sample_fanout_rows(nbr_table: jax.Array, cum_table: jax.Array,

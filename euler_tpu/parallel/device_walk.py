@@ -33,7 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from euler_tpu.parallel.device_sampler import slot_weights, sample_hop
+from euler_tpu.parallel.device_sampler import (
+    sample_hop, slot_weights, stored_info, take_rows,
+)
 
 
 class DeviceNodeSampler:
@@ -88,7 +90,7 @@ def walk_rows(nbr_table: jax.Array, cum_table: jax.Array,
     path (DeviceNeighborTable.uniform_rows tables, replicated only);
     alias_table routes them through the O(1) alias draw — the walk
     family's chained count=1 draws are where the per-draw constant
-    matters most, and the flat neighbor pick stays. Otherwise node2vec
+    matters most. Otherwise node2vec
     second-order bias: candidate weights are scaled 1/p when returning
     to the previous node, 1 when the candidate is a kept neighbor of
     the previous node, 1/q otherwise — computed over the capped rows
@@ -96,13 +98,9 @@ def walk_rows(nbr_table: jax.Array, cum_table: jax.Array,
     always reads the cum table: the bias math needs raw slot weights,
     so uniform/alias are ignored there).
     """
-    C = nbr_table.shape[1]
+    C = stored_info(nbr_table).cap
     unif = uniform and gather is None and alias_table is None
     atab = alias_table if gather is None else None
-
-    def take(tab, r):
-        return gather(tab, r) if gather is not None else \
-            jnp.take(tab, r, axis=0)
 
     cols = [roots]
     key, sub = jax.random.split(key)
@@ -116,9 +114,10 @@ def walk_rows(nbr_table: jax.Array, cum_table: jax.Array,
             nxt = sample_hop(nbr_table, cum_table, cur, 1, sub, gather,
                              uniform=unif, alias_table=atab)
         else:
-            cand = take(nbr_table, cur)                     # [B, C]
-            w = slot_weights(take(cum_table, cur))          # [B, C]
-            prev_nbr = take(nbr_table, prev)                # [B, C]
+            cand = take_rows(nbr_table, cur, "nbr", gather)     # [B, C]
+            w = slot_weights(
+                take_rows(cum_table, cur, "cum", gather))       # [B, C]
+            prev_nbr = take_rows(nbr_table, prev, "nbr", gather)
             is_prev = cand == prev[:, None]
             in_prev_nbr = (cand[:, :, None]
                            == prev_nbr[:, None, :]).any(-1)
@@ -135,7 +134,7 @@ def walk_rows(nbr_table: jax.Array, cum_table: jax.Array,
             # zero-total rows (dead end / pad): every candidate slot of
             # such a row already holds the table's DATA pad value (the
             # builder fills dead rows with pad), so cand[:, 0] is the
-            # correct sentinel. Deriving it from nbr_table.shape[0]-1
+            # correct sentinel. Deriving it from stored_info's pad_row
             # would be wrong for row-sharded tables, whose row count is
             # padded up to the model-axis multiple (code-review r4).
             nxt = jnp.where(total > 0, nxt, cand[:, 0])

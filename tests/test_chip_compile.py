@@ -76,7 +76,9 @@ def _with_sharding(tree, sharding):
 
 def _abstract_tables(c, rows, table_sharding, small_sharding):
     """ShapeDtypeStructs of the int8 feature / label / nbr / cum tables
-    as the stores place them (rows = N + 1 + any row-shard padding)."""
+    as the stores place them (rows = N + 1 + any row-shard padding; the
+    nbr and cum rows in their stored form, device_sampler.store_rows:
+    int8 [rows, 4 * cap])."""
     def sds(shape, dt, sh):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
@@ -85,8 +87,8 @@ def _abstract_tables(c, rows, table_sharding, small_sharding):
         feature_scale=sds((c["feat_dim"],), jnp.bfloat16, small_sharding),
         labels=sds((rows, c["num_classes"]), jnp.float32, table_sharding))
     sampler = SimpleNamespace(tables={
-        "nbr_table": sds((rows, c["cap"]), jnp.int32, table_sharding),
-        "cum_table": sds((rows, c["cap"]), jnp.float32, table_sharding)})
+        "nbr_table": sds((rows, 4 * c["cap"]), jnp.int8, table_sharding),
+        "cum_table": sds((rows, 4 * c["cap"]), jnp.int8, table_sharding)})
     return store, sampler
 
 
@@ -152,6 +154,27 @@ def test_canonical_scanned_step_compiles_and_fits(one_chip, uniform):
         + (4 if uniform else 8) * c["cap"])
     assert m.argument_size_in_bytes >= table_bytes  # tables are counted
     assert _device_bytes(compiled) < HBM_BYTES, m
+    # the stored form holds the bytes the [rows, cap] words held: the
+    # window's arguments are the parent's (my compiles of ab8870a, PR 29)
+    parent = 730_573_824 if uniform else 1_044_179_968
+    assert abs(m.argument_size_in_bytes - parent) < 1 << 20, m
+    # no [rows, cap] table of words is left in the window (as an argument
+    # it had the nodes on the lanes, {0,1}: a row in cap/8 tiles, 36 ns
+    # a gathered row on the chip); the stored tables come in row-major
+    # and the draw's gathers read their rows whole, 4 * cap bytes wide
+    rows, cap = c["n_nodes"] + 1, c["cap"]
+    text = compiled.as_text()
+    assert not re.search(rf"[sf]32\[{rows},{cap}\]", text)
+    stored = [line for line in text.splitlines()
+              if " parameter(" in line and f"s8[{rows},{4 * cap}]" in line]
+    assert stored and all(f"s8[{rows},{4 * cap}]{{1,0" in p
+                          for p in stored), stored
+    draws = [line for line in text.splitlines()
+             if " gather(" in line and "draw/hop" in line]
+    assert len(draws) == (1 if uniform else 2) * len(c["fanouts"]), draws
+    assert all(f"slice_sizes={{1,{4 * cap}}}" in g
+               and re.match(rf"\s*\S+ = s8\[\d+,{4 * cap}\]\{{1,0", g)
+               for g in draws), draws
 
 
 def test_act_cache_scanned_step_compiles_and_fits(one_chip):

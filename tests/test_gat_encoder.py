@@ -17,6 +17,7 @@ from benchmark.cell import unflatten  # noqa: E402
 from benchmark.reference import common, gat3  # noqa: E402
 from euler_tpu import obs  # noqa: E402
 from euler_tpu.models import DeviceSampledGraphSage  # noqa: E402
+from euler_tpu.parallel.device_sampler import store_rows  # noqa: E402
 from euler_tpu.utils.encoders import (  # noqa: E402
     GATEncoder, GATLayer, neighbor_major_rows,
 )
@@ -162,6 +163,12 @@ def _tables(n=300, cap=6, d=12, classes=5, seed=0):
     return nbr, cum, feat, cls
 
 
+def _stored(nbr, cum):
+    """The batch's sampling tables in the form the program reads."""
+    return {"nbr_table": jnp.asarray(store_rows(nbr, "nbr")),
+            "cum_table": jnp.asarray(store_rows(cum, "cum"))}
+
+
 def test_the_model_matches_the_reference_loss_with_pad_slots():
     """DeviceSampledGraphSage(encoder='gat') through its own draw and
     gather, roots without neighbours among them, against gat3.loss on
@@ -175,7 +182,7 @@ def test_the_model_matches_the_reference_loss_with_pad_slots():
         num_classes=classes, multilabel=False, uniform_sampling=True)
     roots = jnp.arange(0, 32, dtype=jnp.int32)       # 0..7 have no slots
     batch = {"rows": [roots], "sample_seed": jnp.uint32(9),
-             "nbr_table": jnp.asarray(nbr), "cum_table": jnp.asarray(cum),
+             **_stored(nbr, cum),
              "feature_table": jnp.asarray(q),
              "feature_scale": jnp.asarray(scale),
              "label_table": jnp.asarray(np.eye(classes,
@@ -225,7 +232,7 @@ def test_the_logits_hook_leaves_the_mean_models_parameters_as_they_were():
     nbr, cum, feat, cls = _tables()
     batch = {"rows": [jnp.arange(16, dtype=jnp.int32)],
              "sample_seed": jnp.uint32(1),
-             "nbr_table": jnp.asarray(nbr), "cum_table": jnp.asarray(cum),
+             **_stored(nbr, cum),
              "feature_table": jnp.asarray(feat),
              "label_table": jnp.asarray(np.eye(5, dtype=np.float32)[cls])}
     kw = dict(dim=8, fanouts=(3, 2), num_classes=5, multilabel=False)
@@ -271,7 +278,7 @@ def test_one_count_a_layer_a_trace_and_the_encoders_named():
             DeviceSampledGraphSage(encoder="gta", fanouts=(2,)).init,
             jax.random.key(0),
             {"rows": [jnp.arange(4, dtype=jnp.int32)],
-             "sample_seed": jnp.uint32(1), "nbr_table": jnp.asarray(nbr),
-             "cum_table": jnp.asarray(cum),
+             "sample_seed": jnp.uint32(1),
+             **_stored(nbr, cum),
              "feature_table": jnp.asarray(feat),
              "label_table": jnp.asarray(cls)})

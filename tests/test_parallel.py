@@ -19,6 +19,8 @@ from euler_tpu.parallel import (
     shard_batch,
     spmd_init,
 )
+from euler_tpu import obs
+from euler_tpu.parallel.device_sampler import logical_rows, store_rows
 
 # The library's draws as compiled programs, which is how every model runs
 # them (inside its jitted step). Called op by op, each primitive of a draw
@@ -385,7 +387,7 @@ def test_uniform_hub_draws_from_capped_subset():
     t = DeviceNeighborTable(g, cap=4)
     assert t.uniform_rows and t.max_degree == 11
     row0 = g.node_rows(np.array([0], np.uint64))
-    kept = set(int(x) for x in np.asarray(t.neighbors)[int(row0[0])]
+    kept = set(int(x) for x in logical_rows(t.neighbors, "nbr")[int(row0[0])]
                if x != t.pad_row)
     assert len(kept) == 4
     out = sample_hop(t.neighbors, t.cum_weights,
@@ -601,7 +603,7 @@ def test_hub_subset_is_weight_biased():
     total_heavy_slots = 0
     for seed in range(30):
         t = DeviceNeighborTable(g, cap=8, seed=seed)
-        row0 = np.asarray(t.neighbors)[0]
+        row0 = logical_rows(t.neighbors, "nbr")[0]
         kept = set(int(r) for r in row0 if r != t.pad_row)
         heavy = {int(r) for r in g.node_rows(np.arange(1, 9, dtype=np.uint64))}
         heavy_kept += len(kept & heavy)
@@ -643,7 +645,7 @@ def test_hub_few_positive_weights_keeps_them_all():
     t = DeviceNeighborTable(g, cap=6)
     pos_rows = set(int(r) for r in g.node_rows(
         np.array([4, 8], dtype=np.uint64)))
-    row0 = set(np.asarray(t.neighbors)[0].tolist())
+    row0 = set(logical_rows(t.neighbors, "nbr")[0].tolist())
     assert pos_rows <= row0
     out = sample_hop(t.neighbors, t.cum_weights,
                      jnp.zeros(200, jnp.int32), 4, jax.random.key(1))
@@ -946,7 +948,8 @@ def test_fused_sampling_matches_split_tables():
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4)
-    fused = fuse_tables(t.neighbors, t.cum_weights)
+    fused = fuse_tables(logical_rows(t.neighbors, "nbr"),
+                        logical_rows(t.cum_weights, "cum"))
     assert fused.shape == (t.neighbors.shape[0], 8)
     assert fused.dtype == jnp.int32
 
@@ -969,7 +972,8 @@ def test_fused_sampling_matches_split_tables():
 
     np.testing.assert_array_equal(
         np.asarray(fused),
-        fuse_tables_host(np.asarray(t.neighbors), np.asarray(t.cum_weights)))
+        fuse_tables_host(logical_rows(t.neighbors, "nbr"),
+                         logical_rows(t.cum_weights, "cum")))
     t_f = DeviceNeighborTable(g, cap=4, fused=True)
     tab = t_f.tables["nbrcum_table"]
     np.testing.assert_array_equal(np.asarray(tab), np.asarray(fused))
@@ -1219,7 +1223,8 @@ def test_fused_sampling_pad_row_resolves_to_pad():
                 np.array([2], dtype=np.uint64))
     g = b.finalize()
     t = DeviceNeighborTable(g, cap=2)
-    fused = fuse_tables(t.neighbors, t.cum_weights)
+    fused = fuse_tables(logical_rows(t.neighbors, "nbr"),
+                        logical_rows(t.cum_weights, "cum"))
     iso = jnp.asarray(g.node_rows(np.array([2], dtype=np.uint64)),
                       jnp.int32)
     out = sample_hop_fused(fused, iso, 3, jax.random.key(0))
@@ -1827,20 +1832,19 @@ def test_device_sampled_remat_trains():
                                    table_mesh=make_mesh(model_parallel=2))
         batch = {"rows": [jnp.zeros(4, jnp.int32)],
                  "sample_seed": np.uint32(0),
-                 "nbr_table": jnp.zeros((8, 4), jnp.int32),
-                 "cum_table": jnp.ones((8, 4)),
+                 "nbr_table": jnp.zeros((8, 16), jnp.int8),
+                 "cum_table": jnp.zeros((8, 16), jnp.int8),
                  "feature_table": jnp.ones((8, 6)),
                  "label_table": jnp.zeros((8, 3))}
         m.init(jax.random.key(0), batch)
 
 
 def test_sample_hop_count_aware_pick_bit_parity():
-    """sample_hop's local neighbor pick is count-aware (count >= 4
-    gathers whole [n, C] rows and picks with take_along_axis; smaller
-    counts keep the flat single-element pick — round-5 on-chip probe:
-    the flat pick is element-count-bound and loses 77.9ms vs 21.7ms at
-    products scale). Both paths must be draw-for-draw identical: same
-    inverse-CDF cols, same neighbor values."""
+    """sample_hop reads whole [n, C] rows of the stored tables
+    (take_rows) and picks locally at every count; until PR 29 counts
+    under 4 took a flat single-element pick out of the logical table.
+    The draws must be that flat pick's draw for draw: same inverse-CDF
+    cols, same neighbor values."""
 
     rng = np.random.default_rng(3)
     N, C = 200, 8
@@ -1859,11 +1863,187 @@ def test_sample_hop_count_aware_pick_bit_parity():
         return jnp.take(nbr.reshape(-1),
                         (rows[:, None] * C + col).reshape(-1))
 
-    for count in (1, 2, 4, 10):   # spans both sides of the threshold
-        out = sample_hop(nbr, cum, rows, count, key)
+    nbr_s = jnp.asarray(store_rows(np.asarray(nbr), "nbr"))
+    cum_s = jnp.asarray(store_rows(np.asarray(cum), "cum"))
+    for count in (1, 2, 4, 10):   # both sides of the old threshold
+        out = sample_hop(nbr_s, cum_s, rows, count, key)
         ref = flat_pick(count, nbr, cum, rows, key)
         assert (out == ref).all()
         assert out.shape == (300 * count,)
+
+
+# ---------------------------------------------------------------------------
+# The stored form of the row tables (device_sampler.store_rows: int8
+# [N+1, 4C] byte planes) and its one reader, take_rows: the same words
+# bit for bit, and every consumer's draws those of a plain row read of
+# the logical tables (the form every consumer read until PR 29).
+# ---------------------------------------------------------------------------
+_STORED_CAPS = (8, 32, 40)
+_STORED_N = 63   # + the pad row: 64 rows, a multiple of the model axis
+
+
+def _logical_tables(cap, weighted):
+    """Front-packed [N+1, C] nbr / cum / alias host tables with the
+    values a byte-wise form must not lose: degrees 0..C, weights that
+    make large and subnormal cumulative sums, the all-zero pad row."""
+    from euler_tpu.parallel.device_sampler import build_alias_tables
+
+    rng = np.random.default_rng(cap)
+    n = _STORED_N
+    deg = rng.integers(0, cap + 1, n + 1)
+    deg[:3], deg[n] = (0, 1, cap), 0
+    live = np.arange(cap)[None, :] < deg[:, None]
+    nbr = np.where(live, rng.integers(0, n, (n + 1, cap)), n) \
+        .astype(np.int32)
+    w = np.where(live, rng.integers(1, 4, (n + 1, cap)) if weighted else 1,
+                 0).astype(np.float32)
+    if weighted:
+        w[5] *= np.float32(1e-42)      # subnormal cumulative sums
+        w[6] *= np.float32(1e36)       # large ones
+    cum = np.cumsum(w, axis=1, dtype=np.float32)
+    return {"nbr": nbr, "cum": cum,
+            "alias": build_alias_tables(nbr, cum_tab=cum)}
+
+
+@pytest.fixture(scope="module")
+def stored_tables(mesh):
+    """cap -> (logical host tables, the stored ones placed replicated,
+    the same row-sharded over 'model'), weighted rows."""
+    from euler_tpu.parallel import DeviceNeighborTable
+
+    out = {}
+    for cap in _STORED_CAPS:
+        logical = _logical_tables(cap, weighted=True)
+        rep = DeviceNeighborTable.from_arrays(
+            logical["nbr"], logical["cum"], alias=True)
+        sh = DeviceNeighborTable.from_arrays(
+            logical["nbr"], logical["cum"], mesh=mesh, shard_rows=True)
+        assert not rep.uniform_rows and rep.pad_row == _STORED_N
+        np.testing.assert_array_equal(
+            logical_rows(rep.alias_table, "alias"), logical["alias"])
+        out[cap] = (logical, rep.tables, sh.tables)
+    return out
+
+
+@pytest.mark.parametrize("cap", _STORED_CAPS)
+@pytest.mark.parametrize("table", ["nbr", "cum", "alias"])
+def test_take_rows_gives_the_logical_rows_bit_for_bit(table, cap):
+    """store_rows -> take_rows == table[rows] on the bits: int32 ids up
+    to 2**31 - 1, float32 bit patterns (large, subnormal, NaN payloads,
+    -0.0, the all-zero pad row), alias words with their negative
+    sentinels; a row of 32, 128 or 160 bytes."""
+    from euler_tpu.parallel.device_sampler import stored_info, take_rows
+
+    rng = np.random.default_rng(cap)
+    bits = rng.integers(-2 ** 31, 2 ** 31, (65, cap)).astype(np.int32)
+    bits[0] = 2 ** 31 - 1
+    bits[1] = -1                            # alias sentinels, NaN bits
+    bits[2] = np.int32(-2 ** 31)            # -0.0
+    bits[3] = rng.integers(1, 1 << 23, cap)  # subnormals
+    bits[64] = 0                            # the pad row
+    tab = bits.view(np.float32) if table == "cum" else bits
+    stored = store_rows(tab, table)
+    assert stored.dtype == np.int8 and stored.shape == (65, 4 * cap)
+    assert stored.nbytes == tab.nbytes
+    assert stored_info(stored) == (cap, 64, True)
+    assert logical_rows(stored, table).dtype == tab.dtype
+    np.testing.assert_array_equal(
+        logical_rows(stored, table).view(np.int32), bits)
+    rows = np.concatenate([np.arange(65), rng.integers(0, 65, 63)]) \
+        .astype(np.int32)
+    got = jax.jit(lambda s, r: take_rows(s, r, table))(
+        jnp.asarray(stored), jnp.asarray(rows))
+    assert got.dtype == tab.dtype and got.shape == (128, cap)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.int32), bits[rows])
+    with pytest.raises(TypeError, match="STORED table"):
+        take_rows(jnp.asarray(tab), jnp.asarray(rows), table)
+
+
+def _plain_row_read(logical):
+    """take_rows' stand-in: a plain row read of the LOGICAL numpy
+    tables, whatever stored table and exchange it is handed."""
+    def take(stored, rows, table, gather=None):
+        return jnp.asarray(logical[table])[rows]
+    return take
+
+
+_DRAWS = {
+    "hop1": lambda t, r, k, **kw: device_sampler.sample_hop(
+        t["nbr_table"], t["cum_table"], r, 1, k, **kw),
+    "hop5": lambda t, r, k, **kw: device_sampler.sample_hop(
+        t["nbr_table"], t["cum_table"], r, 5, k, **kw),
+    "fanout": lambda t, r, k, **kw: device_sampler.sample_fanout_rows(
+        t["nbr_table"], t["cum_table"], r, (4, 1, 3), k, **kw),
+    "walk": lambda t, r, k, **kw: device_walk.walk_rows(
+        t["nbr_table"], t["cum_table"], r, 3, k, **kw),
+    "walk_biased": lambda t, r, k, **kw: device_walk.walk_rows(
+        t["nbr_table"], t["cum_table"], r, 3, k, p=0.5, q=2.0, **kw),
+    "layerwise": lambda t, r, k, **kw: device_layerwise
+    .sample_layerwise_rows(t["nbr_table"], t["cum_table"], r, (6, 5), k,
+                           **kw),
+}
+
+
+@pytest.mark.parametrize("fn,draw,cap", [
+    ("hop1", "uniform", 8), ("hop5", "uniform", 32),
+    ("hop1", "inverse_cdf", 40), ("hop5", "inverse_cdf", 8),
+    ("hop1", "alias", 32), ("hop5", "alias", 40),
+    ("fanout", "uniform", 40), ("fanout", "inverse_cdf", 32),
+    ("fanout", "alias", 8),
+    ("walk", "uniform", 32), ("walk", "inverse_cdf", 8),
+    ("walk", "alias", 40), ("walk_biased", "inverse_cdf", 32),
+    ("layerwise", "inverse_cdf", 40), ("layerwise", "alias", 8),
+    ("hop1", "row_sharded", 32), ("hop5", "row_sharded", 40),
+    ("fanout", "row_sharded", 8), ("walk", "row_sharded", 40),
+    ("walk_biased", "row_sharded", 8)])
+def test_draws_through_the_stored_tables_equal_a_plain_row_read(
+        fn, draw, cap, mesh, stored_tables, monkeypatch):
+    """Each consumer of the row tables, under each draw and count (1 and
+    >= 4), replicated and row-sharded: the ids drawn through take_rows
+    on the stored tables are, draw for draw, those of the same function
+    reading plain rows of the logical tables, as it did until PR 29."""
+    from euler_tpu.parallel import make_table_gather
+
+    logical, rep, sharded = stored_tables[cap]
+    if draw == "uniform":      # unit weights: its tables are its own
+        from euler_tpu.parallel import DeviceNeighborTable
+
+        logical = _logical_tables(cap, weighted=False)
+        t = DeviceNeighborTable.from_arrays(logical["nbr"], logical["cum"])
+        assert t.uniform_rows
+        rep = t.tables
+    tables = sharded if draw == "row_sharded" else rep
+    kw = {"uniform": {"uniform": True}, "inverse_cdf": {},
+          "alias": {"alias_table": rep.get("alias_table")},
+          "row_sharded": {"gather": make_table_gather(mesh)}}[draw]
+    if fn == "layerwise":
+        kw = {k: v for k, v in kw.items() if k == "alias_table"}
+    roots = jnp.asarray(np.random.default_rng(1).integers(
+        0, _STORED_N + 1, 32).astype(np.int32)).at[:2].set(
+        jnp.asarray([_STORED_N, 0], jnp.int32))   # the pad row, a dead one
+    key = jax.random.key(11)
+
+    def run():
+        with mesh:
+            out = jax.jit(lambda t, r: _DRAWS[fn](t, r, key, **kw))(
+                tables, roots)
+        return [np.asarray(x) for x in jax.tree_util.tree_leaves(out)]
+
+    before = obs.counter("table_rows_stored_traces_total", "",
+                         ("table",)).labels(table="nbr").value
+    got = run()
+    assert obs.counter("table_rows_stored_traces_total", "",
+                       ("table",)).labels(table="nbr").value > before
+    plain = _plain_row_read(logical)
+    for mod in (device_sampler, device_walk, device_layerwise):
+        monkeypatch.setattr(mod, "take_rows", plain)
+    want = run()
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    ids = got[0] if fn != "fanout" else np.concatenate(got)
+    assert ids.min() >= 0 and ids.max() <= _STORED_N
 
 
 # ---------------------------------------------------------------------------
@@ -1885,10 +2065,10 @@ def test_alias_table_layout_and_sentinels():
 
     g, ids = _weighted_ring()
     t = DeviceNeighborTable(g, cap=4, alias=True)
-    tab = np.asarray(t.alias_table)
+    tab = logical_rows(t.alias_table, "alias")
     assert tab.shape == (t.pad_row + 1, 4) and tab.dtype == np.int32
     assert (tab[-1] == -1).all()                   # pad row all-sentinel
-    nbr = np.asarray(t.neighbors)
+    nbr = logical_rows(t.neighbors, "nbr")
     deg = (nbr != t.pad_row).sum(axis=1)
     np.testing.assert_array_equal((tab >= 0).sum(axis=1), deg)
     act = tab[tab >= 0]
@@ -1970,7 +2150,7 @@ def test_alias_hub_draws_from_capped_subset():
 
     g = _star_graph(64, np.ones(64, np.float32))
     t = DeviceNeighborTable(g, cap=8, alias=True)
-    kept = set(int(x) for x in np.asarray(t.neighbors)[0]
+    kept = set(int(x) for x in logical_rows(t.neighbors, "nbr")[0]
                if x != t.pad_row)
     assert len(kept) == 8
     out = sample_hop(t.neighbors, t.cum_weights,

@@ -502,8 +502,8 @@ def test_patch_rows_byte_parity_with_hubs():
                              keep_host=True, alias=True)
     assert np.array_equal(t.host_tables[0], t2.host_tables[0])
     assert np.array_equal(t.host_tables[1], t2.host_tables[1])
-    assert np.array_equal(np.asarray(t.alias_table),
-                          np.asarray(t2.alias_table))
+    # the re-placed STORED tables are the scratch build's byte for byte
+    _assert_stored_equal(t, t2)
     assert t.pad_row == t2.pad_row
     assert t.uniform_rows == t2.uniform_rows
 
@@ -537,10 +537,24 @@ def test_patch_rows_no_growth_edge_only():
     assert np.array_equal(t.host_tables[1], t2.host_tables[1])
     # device copies match the scratch build byte-for-byte too — the
     # scattered rows really landed on device, not just in host_tables
-    assert np.array_equal(np.asarray(t.neighbors), t2.host_tables[0])
-    assert np.array_equal(np.asarray(t.cum_weights), t2.host_tables[1])
-    assert np.array_equal(np.asarray(t.alias_table),
-                          np.asarray(t2.alias_table))
+    _assert_stored_equal(t, t2)
+
+
+def _assert_stored_equal(t, t2):
+    """The stored (byte-plane) device tables of `t` are `t2`'s byte for
+    byte, and read back as t2's logical host tables."""
+    from euler_tpu.parallel.device_sampler import logical_rows
+
+    for name in ("neighbors", "cum_weights", "alias_table"):
+        got, want = np.asarray(getattr(t, name)), np.asarray(
+            getattr(t2, name))
+        assert got.dtype == np.int8 and got.shape == want.shape
+        assert np.array_equal(got, want), name
+    assert np.array_equal(logical_rows(t.neighbors, "nbr"),
+                          t2.host_tables[0])
+    assert np.array_equal(
+        logical_rows(t.cum_weights, "cum").view(np.int32),
+        t2.host_tables[1].view(np.int32))
 
 
 def test_patch_rows_refuses_unsupported_layouts():
