@@ -52,6 +52,35 @@ def gather_feature_rows(batch: Dict[str, Any], rows, gather=None):
     return out
 
 
+# encoders DeviceSampledGraphSage takes, and those whose softmax masks
+# pad slots (by the pad row's id, the table's last)
+FANOUT_ENCODERS = ("sage", "gcn", "genie", "gat", "unimp")
+_ATTENTION_ENCODERS = ("gat", "unimp")
+_LABEL_STREAM = 0x1abe1  # folded into the step's key for the labels' word
+
+
+def label_visible(ids, word, rate: float):
+    """bool per row id: whether the step shows the node's label to the
+    model. A pure function of (id, word): x = id ^ word as uint32, mixed
+    by murmur3's 32-bit finaliser (x ^= x >> 16; x *= 0x85ebca6b;
+    x ^= x >> 13; x *= 0xc2b2ae35; x ^= x >> 16), visible where
+    x < floor(rate * 2**32) (rate 1: all but one word in 2**32). A node
+    drawn twice in a step shows the same thing, another word shows
+    another `rate` share, and nothing table-sized is made."""
+    x = ids.astype(jax.numpy.uint32) ^ word
+    x = (x ^ (x >> 16)) * jax.numpy.uint32(0x85ebca6b)
+    x = (x ^ (x >> 13)) * jax.numpy.uint32(0xc2b2ae35)
+    x = x ^ (x >> 16)
+    return x < jax.numpy.uint32(min(int(rate * 2 ** 32), 2 ** 32 - 1))
+
+
+def among_roots(ids, roots):
+    """bool per id: whether it is one of `roots`. Compare-any with the
+    ids on the minor axis, so the [B, n] compare fuses into its reduce
+    and is never written."""
+    return (roots[:, None] == ids[None, :]).any(axis=0)
+
+
 def _fanout_layers(batch: Dict[str, Any]):
     """Per-hop feature arrays from either batch geometry:
       'layers'               — features shipped from the host (engine path)
@@ -105,28 +134,36 @@ class _GatherEncode(nn.Module):
     aggregator: str
     encoder: str
     gather: Any = None  # make_table_gather closure for sharded tables
-    heads: int = 1      # encoder 'gat' only, as is out_dim
+    heads: int = 1      # encoders 'gat' and 'unimp' only, as is out_dim
     out_dim: int = 0
+    label_rate: float = 0.0  # encoder 'unimp' only
 
     @nn.compact
-    def __call__(self, table, scale, rows):
+    def __call__(self, table, scale, rows, label_in=None):
         from euler_tpu.utils.encoders import (
-            GATEncoder, GCNEncoder, GenieEncoder, neighbor_major_rows,
+            GATEncoder, GCNEncoder, GenieEncoder, UniMPEncoder,
+            neighbor_major_rows,
         )
 
         batch = {"feature_table": table}
         if scale is not None:
             batch["feature_scale"] = scale
-        if self.encoder == "gat":
+        attention = self.encoder in _ATTENTION_ENCODERS
+        if attention:
             rows = neighbor_major_rows(rows, self.fanouts)
         layers = gather_feature_rows(batch, rows, gather=self.gather)
-        if self.encoder == "gat":
+        if attention:
             # the softmax needs to know the pad slots (the mean only
             # needed the pad row's zeros); the pad row is the table's last
             pad = table.shape[0] - 1
+            masks = [r != pad for r in rows]
+        if self.encoder == "unimp":
+            layers = self._with_labels(layers, rows, masks, *label_in)
+            return UniMPEncoder(self.dim, self.fanouts, self.heads,
+                                self.out_dim, name="enc")(layers, masks)
+        if self.encoder == "gat":
             return GATEncoder(self.dim, self.fanouts, self.heads,
-                              self.out_dim, name="enc")(
-                layers, [r != pad for r in rows])
+                              self.out_dim, name="enc")(layers, masks)
         if self.encoder == "gcn":
             return GCNEncoder(self.dim, self.fanouts, name="enc")(layers)
         if self.encoder == "genie":
@@ -135,6 +172,38 @@ class _GatherEncode(nn.Module):
         return SageEncoder(self.dim, self.fanouts, self.aggregator,
                            name="enc")(layers)
 
+    def _with_labels(self, layers, rows, masks, label_table, roots, word):
+        """Labels as inputs (UniMP's masked label embedding; PyG's
+        MaskLabel, "add"): every sampled row of hops 1..L gets its label
+        row, as stored, times `label_emb/kernel` added to its features
+        where the step shows that label: `label_visible` says so, and
+        never for a root of this step (its label is what the loss asks
+        for, also where the root is drawn again as a neighbour), nor for
+        the pad row. The roots' own rows get none. Scopes
+        `labelin/hop<h>`; trace-time counter
+        `label_input_traces_total{hop}`."""
+        from euler_tpu import obs
+
+        emb = nn.Dense(layers[0].shape[-1], use_bias=False,
+                       name="label_emb")
+        out = [layers[0]]
+        for hop in range(1, len(rows)):
+            obs.counter(
+                "label_input_traces_total",
+                "label-row gathers into the model's input traced into a "
+                "program (or run eagerly), one a hop",
+                ("hop",)).labels(hop=str(hop)).inc()
+            with jax.named_scope(f"labelin/hop{hop}"):
+                shown = (label_visible(rows[hop], word, self.label_rate)
+                         & ~among_roots(rows[hop], roots) & masks[hop])
+                # row ids are the program's own draws: clipped, not
+                # tested and filled
+                y = jax.numpy.take(label_table, rows[hop], axis=0,
+                                   mode="clip")
+                out.append(layers[hop] + jax.numpy.where(
+                    shown[:, None], emb(y), 0.0))
+        return out
+
 
 class DeviceSampledGraphSage(SuperviseModel):
     """A fanout model whose sampling runs ON DEVICE (DeviceNeighborTable):
@@ -142,17 +211,23 @@ class DeviceSampledGraphSage(SuperviseModel):
     feature gather, and label lookup all read HBM-resident tables inside
     the jitted step. The TPU-first configuration bench.py measures —
     the host feeder drops out of the critical path entirely. encoder
-    picks any fanout-layer encoder ('sage', 'gcn', 'genie' or 'gat' —
-    all consume the per-hop feature list the on-device sampler
-    produces). 'gat' is multi-head attention (utils/encoders.GATEncoder:
-    `heads` heads of width `dim` a hidden layer) whose last layer emits
-    the class logits itself, so the model has no `out` layer then."""
+    picks any fanout-layer encoder (FANOUT_ENCODERS — all consume the
+    per-hop feature list the on-device sampler produces). 'gat' is
+    multi-head attention (utils/encoders.GATEncoder: `heads` heads of
+    width `dim` a hidden layer) whose last layer emits the class logits
+    itself, so the model has no `out` layer then. 'unimp' is UniMP
+    (Shi et al. 2020): dot-product attention with a gated residual and
+    LayerNorm (utils/encoders.UniMPEncoder, `heads` x `dim` as 'gat'),
+    and the sampled neighbours' labels as inputs, each shown to a step
+    with probability `label_rate` and never a root's own
+    (`_GatherEncode._with_labels`)."""
 
     dim: int = 32
     fanouts: Sequence[int] = (10, 10)
     aggregator: str = "mean"
     encoder: str = "sage"
-    heads: int = 4  # attention heads a layer (encoder='gat')
+    heads: int = 4  # attention heads a layer ('gat', 'unimp')
+    label_rate: float = 0.625  # share of labels a step shows ('unimp')
     # remat: recompute gather+encode in the backward pass
     # (_RematGatherEncode) — unlocks batches whose per-hop feature
     # layers don't fit HBM twice. Replicated tables only.
@@ -170,6 +245,11 @@ class DeviceSampledGraphSage(SuperviseModel):
             sample_fanout_rows_fused,
         )
 
+        if self.encoder not in FANOUT_ENCODERS:
+            *names, last = map(repr, FANOUT_ENCODERS)
+            raise ValueError(
+                f"DeviceSampledGraphSage.encoder must be "
+                f"{', '.join(names)} or {last}, got {self.encoder!r}")
         roots = batch["rows"][0]
         key = jax.random.fold_in(jax.random.key(17), batch["sample_seed"])
         # table_mesh set → tables are row-sharded over 'model' and every
@@ -198,31 +278,37 @@ class DeviceSampledGraphSage(SuperviseModel):
                 uniform=(self.uniform_sampling and not sharded
                          and atab is None),
                 alias_table=atab)
-        if self.encoder not in ("sage", "gcn", "genie", "gat"):
-            raise ValueError(
-                f"DeviceSampledGraphSage.encoder must be 'sage', 'gcn', "
-                f"'genie' or 'gat', got {self.encoder!r}")
         if self.remat and sharded:
             raise ValueError(
                 "DeviceSampledGraphSage(remat=True) supports "
                 "replicated tables only (the re-gather would nest "
                 "shard_map inside jax.checkpoint)")
-        if self.encoder == "gat" and sharded:
+        if self.encoder in _ATTENTION_ENCODERS and sharded:
             raise ValueError(
-                "DeviceSampledGraphSage(encoder='gat') supports "
-                "replicated tables only: its softmax masks pad slots by "
-                "the pad row's id, taken from the table's shape, which "
-                "row-sharding pads to the model-axis multiple")
+                f"DeviceSampledGraphSage(encoder={self.encoder!r}) "
+                "supports replicated tables only: its softmax masks pad "
+                "slots by the pad row's id, taken from the table's "
+                "shape, which row-sharding pads to the model-axis "
+                "multiple")
         mod_cls = nn.remat(_GatherEncode) if self.remat else _GatherEncode
         mod = mod_cls(self.dim, tuple(self.fanouts), self.aggregator,
                       self.encoder, gather=gather if sharded else None,
                       heads=int(self.heads), out_dim=int(self.num_classes),
-                      name="encoder")
+                      label_rate=float(self.label_rate), name="encoder")
+        label_in = None
+        if self.encoder == "unimp":
+            if not 0.0 <= self.label_rate <= 1.0:
+                raise ValueError(
+                    "DeviceSampledGraphSage.label_rate is the share of "
+                    f"labels a step shows, in [0, 1]; got {self.label_rate}")
+            word = jax.random.bits(jax.random.fold_in(key, _LABEL_STREAM),
+                                   (), jax.numpy.uint32)
+            label_in = (batch["label_table"], roots, word)
         return mod(batch["feature_table"], batch.get("feature_scale"),
-                   rows)
+                   rows, label_in)
 
     def logits(self, emb: Array) -> Array:
-        if self.encoder == "gat":
+        if self.encoder in _ATTENTION_ENCODERS:
             return emb  # the last attention layer maps to the classes
         return super().logits(emb)
 

@@ -16,6 +16,7 @@ the reference's TF variable assign machinery (encoders.py:294,629).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -180,22 +181,37 @@ def _block_diagonal(vec: Array) -> Array:
             ).reshape(h * c, h)
 
 
-def _attend(z_t: Array, z_s: Array, e_self: Array, e_nbr: Array,
-            mask: Optional[Array], concat: bool) -> Array:
-    """Softmax attention of every target over its k sampled slots and
-    itself. z_t [M, H*C], z_s [k, M, H*C] projected rows; e_self [H, M],
-    e_nbr [H, k, M] the logits (M minor: dense on the chip's lanes);
-    mask bool [k, M], False where the slot is a pad. A target whose
-    slots are all pads attends to itself only. -> [M, H*C] (heads
-    concatenated) or [M, C] (their mean)."""
-    heads = e_self.shape[0]
+# the own logit of an attention without an own term: finite, under every
+# logit a slot can have
+_NO_SELF = float(jnp.finfo(jnp.float32).min)
+
+
+def _slot_softmax(e_nbr: Array, mask: Optional[Array], e_self: Array):
+    """The masked softmax of every target over its k sampled slots and
+    its own term: e_nbr [H, k, M] and e_self [H, M] logits (M minor:
+    dense on the chip's lanes), mask bool [k, M], False where the slot is
+    a pad -> (a_self [H, M], a_nbr [H, k, M]). Pad slots take no weight;
+    a target whose slots are all pads gives its own term weight 1. An
+    attention with no own term gives `_NO_SELF` logits: that term then
+    weighs 0 beside any slot, and takes the weight of a target that has
+    none, which so aggregates zero with nothing NaN in either pass."""
     if mask is not None:
         e_nbr = jnp.where(mask[None], e_nbr, -jnp.inf)
     top = jnp.maximum(e_self, e_nbr.max(axis=1))        # finite: e_self is
     p_self = jnp.exp(e_self - top)
     p_nbr = jnp.exp(e_nbr - top[:, None])
     total = p_self + p_nbr.sum(axis=1)
-    a_self, a_nbr = p_self / total, p_nbr / total[:, None]
+    return p_self / total, p_nbr / total[:, None]
+
+
+def _attend(z_t: Array, z_s: Array, e_self: Array, e_nbr: Array,
+            mask: Optional[Array], concat: bool) -> Array:
+    """Softmax attention of every target over its k sampled slots and
+    itself (`_slot_softmax`). z_t [M, H*C], z_s [k, M, H*C] projected
+    rows; e_self [H, M], e_nbr [H, k, M] the logits; mask bool [k, M].
+    -> [M, H*C] (heads concatenated) or [M, C] (their mean)."""
+    heads = e_self.shape[0]
+    a_self, a_nbr = _slot_softmax(e_nbr, mask, e_self)
     c = z_t.shape[-1] // heads
     outs = []
     for h in range(heads):
@@ -274,12 +290,11 @@ class GATLayer(nn.Module):
         return out
 
 
-class GATEncoder(nn.Module):
-    """Multi-head attention encoder over a sampled fanout: len(fanouts)
-    GATLayers, deepest pairs first as SageEncoder applies its
-    aggregators; hidden layers concatenate `heads` heads of width `dim`
-    through ELU, the last averages heads of width `out_dim` (the class
-    logits where the model has no output layer of its own).
+class _AttentionEncoder(nn.Module):
+    """len(fanouts) attention layers (`layer_cls(width, heads, concat,
+    name)`, the subclass's) over a sampled fanout, deepest pairs first as
+    SageEncoder applies its aggregators: hidden layers of `heads` heads
+    of width `dim` concatenated, the last of width `out_dim` averaged.
 
     layers[h]: hop h's features NEIGHBOUR-MAJOR (`neighbor_major_rows`);
     masks[h]: bool per row of hop h, False for a pad slot (None: no pads).
@@ -289,6 +304,8 @@ class GATEncoder(nn.Module):
     fanouts: Sequence[int]
     heads: int
     out_dim: int
+
+    layer_cls = None
 
     @nn.compact
     def __call__(self, layers: Sequence[Array],
@@ -300,10 +317,126 @@ class GATEncoder(nn.Module):
         masks = list(masks) if masks is not None else [None] * len(hidden)
         for depth in range(n_hops):
             last = depth == n_hops - 1
-            layer = GATLayer(self.out_dim if last else self.dim, self.heads,
-                             concat=not last, name=f"layer{depth}")
+            layer = self.layer_cls(self.out_dim if last else self.dim,
+                                   self.heads, concat=not last,
+                                   name=f"layer{depth}")
             hidden = layer(hidden, masks)
         return hidden[0]
+
+
+class GATEncoder(_AttentionEncoder):
+    """Multi-head attention encoder over a sampled fanout: GATLayers;
+    hidden layers pass ELU, the last emits the class logits where the
+    model has no output layer of its own."""
+
+    layer_cls = GATLayer
+
+
+class OffsetLayerNorm(nn.Module):
+    """LayerNorm over the last axis whose gain is stored as its OFFSET
+    from one (`gain_offset`, zeros): the same function of x, the same
+    gradient and the same Adam step as `nn.LayerNorm`'s `scale` (ones),
+    but an initialiser that zeroes whatever is no `kernel` (the
+    benchmark's seeded weights) leaves the norm a norm, not a zero."""
+
+    epsilon: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        d = x.shape[-1]
+        gain = 1.0 + self.param("gain_offset", nn.initializers.zeros, (d,))
+        bias = self.param("bias", nn.initializers.zeros, (d,))
+        mean = x.mean(axis=-1, keepdims=True)
+        var = jnp.square(x - mean).mean(axis=-1, keepdims=True)
+        return (x - mean) * jax.lax.rsqrt(var + self.epsilon) * gain + bias
+
+
+class TransformerConvLayer(nn.Module):
+    """One graph-transformer layer (Shi et al. 2020, UniMP; PyG's
+    TransformerConv(beta=True)) applied with shared weights to every
+    (hop h, hop h+1) pair of a NEIGHBOUR-MAJOR fanout:
+
+        q_i = W_q h_i + b_q;  k_j = W_k h_j + b_k;  v_j = W_v h_j + b_v
+        alpha_ij = softmax over i's k sampled slots j of q_i . k_j / sqrt(C)
+                   a head (no term of i's own: `_NO_SELF`)
+        m_i = concat_h or mean_h (sum_j alpha_ij v_j);  r_i = W_r h_i + b_r
+        beta_i = sigmoid(w_b . [r_i ; m_i ; r_i - m_i])
+        o_i = beta_i r_i + (1 - beta_i) m_i, through LayerNorm
+        (`OffsetLayerNorm`) and ReLU where heads are concatenated.
+
+    Keys and values are apart from each other and from the queries; a
+    slot drawn twice counts twice; pad slots (masks False) take no
+    weight, and a target whose slots are all pads aggregates zero. The
+    per-head dot product and the spreading of a head's weight over its
+    C lanes are matrix products with the heads' 0/1 indicator, so the
+    softmax runs with M on the lanes whatever C is. Scopes `qkv` (the
+    four projections), `attn`, `gate` (gate, norm, ReLU) under the
+    layer's name; trace-time counter
+    `unimp_attention_traces_total{layer}`."""
+
+    width: int          # C, one head's
+    heads: int
+    concat: bool        # False: heads averaged, no norm (the last layer)
+
+    @nn.compact
+    def __call__(self, hidden: Sequence[Array],
+                 masks: Sequence[Optional[Array]]) -> list:
+        from euler_tpu import obs
+
+        h, c = self.heads, self.width
+        out_dim = h * c if self.concat else c
+        query, key, value = (nn.Dense(h * c, name=name)
+                             for name in ("query", "key", "value"))
+        skip = nn.Dense(out_dim, name="skip")
+        beta = nn.Dense(1, use_bias=False, name="beta")
+        norm = OffsetLayerNorm(name="norm") if self.concat else None
+        # trace time only: nothing is fetched from the device for it
+        obs.counter(
+            "unimp_attention_traces_total",
+            "graph-transformer layers traced into a program (or run "
+            "eagerly), one a layer whatever its hops",
+            ("layer",)).labels(layer=self.name or "").inc()
+        with jax.named_scope("qkv"):
+            targets = [(query(x), skip(x)) for x in hidden[:-1]]
+            sources = [(key(x), value(x)) for x in hidden[1:]]
+        head_of = _block_diagonal(jnp.ones((c, h), jnp.float32))  # [H*C, H]
+        out = []
+        for hop, ((q, r), (k_s, v_s)) in enumerate(zip(targets, sources)):
+            m = q.shape[0]
+            assert k_s.shape[0] % m == 0, (
+                f"layer of {k_s.shape[0]} rows is not a whole fanout of "
+                f"the {m}-row parent layer")
+            k = k_s.shape[0] // m
+            with jax.named_scope("attn"):
+                k_s, v_s = k_s.reshape(k, m, h * c), v_s.reshape(k, m, h * c)
+                # [H, k, M], M minor: every head's q . k of every slot
+                e = jnp.einsum("fg,kmf->gkm", head_of, q[None] * k_s) \
+                    / math.sqrt(c)
+                mask = masks[hop + 1]
+                _, alpha = _slot_softmax(
+                    e, None if mask is None else mask.reshape(k, m),
+                    jnp.full((h, m), _NO_SELF, e.dtype))
+                # a head's weight on each of its C lanes, then the sum
+                msg = (jnp.einsum("gkm,fg->kmf", alpha, head_of)
+                       * v_s).sum(axis=0)
+                if not self.concat:
+                    msg = msg.reshape(m, h, c).mean(axis=1)
+            with jax.named_scope("gate"):
+                b = nn.sigmoid(beta(
+                    jnp.concatenate([r, msg, r - msg], axis=-1)))
+                o = b * r + (1.0 - b) * msg
+                out.append(nn.relu(norm(o)) if self.concat else o)
+        return out
+
+
+class UniMPEncoder(_AttentionEncoder):
+    """Graph-transformer encoder over a sampled fanout (UniMP's model
+    body as PyG's examples/unimp_arxiv.py stacks it):
+    TransformerConvLayers; hidden layers pass LayerNorm and ReLU, the
+    last emits the class logits. The labels enter before it, in the rows
+    it is given (`models/graphsage._GatherEncode`)."""
+
+    layer_cls = TransformerConvLayer
 
 
 def _ema_update(old: Array, fresh: Array, decay: float) -> Array:

@@ -272,7 +272,7 @@ def test_one_count_a_layer_a_trace_and_the_encoders_named():
     after = count()
     assert {k: after[k] - before[k] for k in after} \
         == {"layer0": 2, "layer1": 2}    # init's trace and the jit's
-    with pytest.raises(ValueError, match="'genie' or 'gat'"):
+    with pytest.raises(ValueError, match="'genie', 'gat' or 'unimp'"):
         nbr, cum, feat, cls = _tables()
         jax.eval_shape(
             DeviceSampledGraphSage(encoder="gta", fanouts=(2,)).init,
