@@ -59,6 +59,24 @@ _ATTENTION_ENCODERS = ("gat", "unimp")
 _LABEL_STREAM = 0x1abe1  # folded into the step's key for the labels' word
 
 
+def _neighbor_major(rows, fanouts, encoder: str):
+    """The device draw's per-hop ids in `neighbor_major_rows`' order, for
+    the encoder that is then told so (`neighbor_major=True`; the
+    attention encoders read no other order). Trace-time counter
+    `neighbor_major_fanout_traces_total{encoder}`."""
+    from euler_tpu import obs
+    from euler_tpu.utils.encoders import neighbor_major_rows
+
+    # trace time only: nothing is fetched from the device for it
+    obs.counter(
+        "neighbor_major_fanout_traces_total",
+        "fanout draws re-ordered neighbour-major before the feature "
+        "gather, traced into a program (or run eagerly), one a fanout "
+        "whatever its hops",
+        ("encoder",)).labels(encoder=encoder).inc()
+    return neighbor_major_rows(rows, fanouts)
+
+
 def label_visible(ids, word, rate: float):
     """bool per row id: whether the step shows the node's label to the
     model. A pure function of (id, word): x = id ^ word as uint32, mixed
@@ -127,7 +145,13 @@ class _GatherEncode(nn.Module):
     layers instead of keeping them alive — at the canonical products
     shape the hop-2 layer alone is ~1GB bf16, the allocation that makes
     batch 65536 OOM. Residuals kept are only the HBM tables (already
-    resident) and the int32 rows."""
+    resident) and the int32 rows.
+
+    The sampled ids are put neighbour-major before the gather, whatever
+    the encoder (`_neighbor_major`): the rows gathered are the same, and
+    every encoder's view of a hop by its parents' slots is then free on
+    the chip where the draw's own order costs a relayout of the gathered
+    features and of their cotangent."""
 
     dim: int
     fanouts: tuple
@@ -142,17 +166,14 @@ class _GatherEncode(nn.Module):
     def __call__(self, table, scale, rows, label_in=None):
         from euler_tpu.utils.encoders import (
             GATEncoder, GCNEncoder, GenieEncoder, UniMPEncoder,
-            neighbor_major_rows,
         )
 
         batch = {"feature_table": table}
         if scale is not None:
             batch["feature_scale"] = scale
-        attention = self.encoder in _ATTENTION_ENCODERS
-        if attention:
-            rows = neighbor_major_rows(rows, self.fanouts)
+        rows = _neighbor_major(rows, self.fanouts, self.encoder)
         layers = gather_feature_rows(batch, rows, gather=self.gather)
-        if attention:
+        if self.encoder in _ATTENTION_ENCODERS:
             # the softmax needs to know the pad slots (the mean only
             # needed the pad row's zeros); the pad row is the table's last
             pad = table.shape[0] - 1
@@ -165,12 +186,13 @@ class _GatherEncode(nn.Module):
             return GATEncoder(self.dim, self.fanouts, self.heads,
                               self.out_dim, name="enc")(layers, masks)
         if self.encoder == "gcn":
-            return GCNEncoder(self.dim, self.fanouts, name="enc")(layers)
+            return GCNEncoder(self.dim, self.fanouts, neighbor_major=True,
+                              name="enc")(layers)
         if self.encoder == "genie":
-            return GenieEncoder(self.dim, self.fanouts,
+            return GenieEncoder(self.dim, self.fanouts, neighbor_major=True,
                                 name="enc")(layers)
         return SageEncoder(self.dim, self.fanouts, self.aggregator,
-                           name="enc")(layers)
+                           neighbor_major=True, name="enc")(layers)
 
     def _with_labels(self, layers, rows, masks, label_table, roots, word):
         """Labels as inputs (UniMP's masked label embedding; PyG's
@@ -577,9 +599,11 @@ class DeviceSampledUnsupervisedSage(nn.Module):
                                       roots, tuple(self.fanouts), kf,
                                       gather=tg, uniform=unif,
                                       alias_table=atab)
+        rows = _neighbor_major(rows, tuple(self.fanouts), "sage")
         layers = gather_feature_rows(batch, rows, gather=gather)
         emb = SageEncoder(self.dim, tuple(self.fanouts), self.aggregator,
-                          concat=False, name="encoder")(layers)   # [B, D]
+                          concat=False, neighbor_major=True,
+                          name="encoder")(layers)                 # [B, D]
         with jax.named_scope("draw/pos"):
             if fused_tab is not None:
                 pos_r = sample_hop_fused(fused_tab, roots, 1, kp, tg)

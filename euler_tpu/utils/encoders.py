@@ -7,8 +7,9 @@ LayerEncoder, SparseSageEncoder, GenieEncoder, LGCEncoder).
 TPU-first redesign: the reference's encoders issue graph queries from
 inside the TF graph; here sampling happens host-side (dataflow builds a
 `FanoutBatch` of per-hop feature tensors with static shapes) and encoders
-are pure flax modules: hop h's neighbors reshape to [n_h, k, D] and
-aggregate densely — no scatter, all MXU-friendly reductions. The
+are pure flax modules: hop h's neighbors reshape to [n_h, k, D] (or
+[k, n_h, D] where the caller drew them neighbour-major) and aggregate
+densely — no scatter, all MXU-friendly reductions. The
 "scalable" encoders keep per-node activation caches as a mutable flax
 variable collection ("cache") updated functionally each step, replacing
 the reference's TF variable assign machinery (encoders.py:294,629).
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.custom_derivatives import SymbolicZero
 
-from euler_tpu.utils.aggregators import get_aggregator
+from euler_tpu.utils.aggregators import get_aggregator, mean_with_self
 from euler_tpu.utils.layers import (
     AttLayer,
     Embedding,
@@ -64,32 +65,52 @@ class ShallowEncoder(nn.Module):
         return jnp.concatenate(parts, axis=-1)
 
 
-def _hop_neighbors(child: Array, parent: Array) -> Array:
-    """Reshape hop h+1's flat layer to [n_h, k, D], deriving k from the
-    (jit-static) shapes. Shared by all fanout encoders so the divisibility
-    invariant lives in one place."""
+def _hop_neighbors(child: Array, parent: Array,
+                   neighbor_major: bool = False) -> Tuple[Array, int]:
+    """Hop h+1's flat layer viewed by its parents' slots, and the axis
+    the k slots lie on: ([n_h, k, D], 1) for the host's target-major
+    order, ([k, n_h, D], 0) for `neighbor_major_rows`' order. k comes
+    from the (jit-static) shapes. Shared by all fanout encoders so the
+    divisibility invariant lives in one place.
+
+    The order cannot be read off a feature array: `neighbor_major` is a
+    fact the caller states, the one that made the order. On the chip the
+    two minor axes are tiled (8, 128), so the target-major view of a
+    gathered [n_h*k, D] array is a relayout whenever k is no multiple of
+    8, forward and backward; the neighbour-major one is a bitcast."""
     n = parent.shape[0]
     assert child.shape[0] % n == 0, (
         f"layer of {child.shape[0]} rows is not a whole fanout of the "
         f"{n}-row parent layer")
-    return child.reshape(n, child.shape[0] // n, -1)
+    k = child.shape[0] // n
+    if neighbor_major:
+        return child.reshape(k, n, -1), 0
+    return child.reshape(n, k, -1), 1
 
 
 class SageEncoder(nn.Module):
     """GraphSAGE encoder over a sampled fanout (reference encoders.py SageEncoder).
 
-    layers[h]: feature tensor of hop h, shape [B·Πk_{<h}, D]. Aggregates
+    layers[h]: feature tensor of hop h, shape [B·Πk_{<h}, D]: row m's k
+    children at m*k .. m*k+k-1 (the host's dataflow), or, where
+    `neighbor_major`, in `neighbor_major_rows`' order. Aggregates
     deepest-first with fresh aggregator params per hop. Per-hop widths k
     are derived from the layer shapes (static under jit), so parameters
     are fanout-independent — evaluation may use wider fanouts than
     training (pass a bigger-fanout eval_dataflow to NodeEstimator);
     `fanouts` only fixes the hop count.
+
+    `neighbor_major` is no option to tune: it is set by the model class
+    that re-ordered the sampled ids (models/graphsage, the device-sampled
+    models), never from a kwarg or a configuration. Same parameters,
+    same function of the same (node, slot) pairs either way.
     """
 
     dim: int
     fanouts: Sequence[int]
     aggregator: str = "mean"
     concat: bool = True
+    neighbor_major: bool = False
 
     @nn.compact
     def __call__(self, layers: Sequence[Array]) -> Array:
@@ -105,18 +126,21 @@ class SageEncoder(nn.Module):
             next_hidden = []
             for hop in range(n_hops - depth):
                 x = hidden[hop]
-                nbr = _hop_neighbors(hidden[hop + 1], x)
-                next_hidden.append(agg(x, nbr))
+                nbr, axis = _hop_neighbors(hidden[hop + 1], x,
+                                           self.neighbor_major)
+                next_hidden.append(agg(x, nbr, axis))
             hidden = next_hidden
         return hidden[0]
 
 
 class GCNEncoder(nn.Module):
     """GCN-style encoder over a fanout (reference GCNEncoder): shared
-    transform of self+neighbors, mean-combined, final layer linear."""
+    transform of self+neighbors, mean-combined, final layer linear.
+    `neighbor_major` as SageEncoder's."""
 
     dim: int
     fanouts: Sequence[int]
+    neighbor_major: bool = False
 
     @nn.compact
     def __call__(self, layers: Sequence[Array]) -> Array:
@@ -130,9 +154,9 @@ class GCNEncoder(nn.Module):
             next_hidden = []
             for hop in range(n_hops - depth):
                 x = hidden[hop]
-                nbr = _hop_neighbors(hidden[hop + 1], x)
-                both = jnp.concatenate([x[:, None, :], nbr], axis=1)
-                h = w(both.mean(axis=1))
+                nbr, axis = _hop_neighbors(hidden[hop + 1], x,
+                                           self.neighbor_major)
+                h = w(mean_with_self(x, nbr, axis))
                 next_hidden.append(h if last else nn.relu(h))
             hidden = next_hidden
         return hidden[0]
@@ -145,9 +169,12 @@ def neighbor_major_rows(rows: Sequence[Array],
     hop consistently: hop h+1's row j*n_h + m is slot j of hop h's row
     m. Its features then reshape to [k, n_h, D] for free and a sum over
     the slots adds k slabs, where the target-major [n_h, k, D] view
-    costs the chip a relayout whenever k is no multiple of 8 (PERF.md:
-    5 ms a step in the mean model). Hop 0 keeps its order; int32 ids
-    only are moved."""
+    costs the chip a relayout whenever k is no multiple of 8 (PERF.md,
+    PR 31: 19 ms of the mean model's 94.9 ms step until it took this
+    order too). Every encoder DeviceSampledGraphSage dispatches reads it
+    (`_hop_neighbors(..., neighbor_major=True)`, the attention layers'
+    own [k, M, D] views). Hop 0 keeps its order; int32 ids only are
+    moved."""
     b = rows[0].shape[0]
     out = [rows[0]]
     for hop in range(1, len(rows)):
@@ -718,10 +745,11 @@ class SparseSageEncoder(nn.Module):
 
 class GenieEncoder(nn.Module):
     """GeniePath (reference GenieEncoder): adaptive breadth (attention) +
-    depth (LSTM gating) over a fanout."""
+    depth (LSTM gating) over a fanout. `neighbor_major` as SageEncoder's."""
 
     dim: int
     fanouts: Sequence[int]
+    neighbor_major: bool = False
 
     @nn.compact
     def __call__(self, layers: Sequence[Array]) -> Array:
@@ -740,8 +768,10 @@ class GenieEncoder(nn.Module):
             next_hidden = []
             for hop in range(n_hops - depth):
                 x = hidden[hop]
-                nbr = _hop_neighbors(hidden[hop + 1], x)
-                pooled = att(jnp.concatenate([x[:, None, :], nbr], axis=1))
+                nbr, axis = _hop_neighbors(hidden[hop + 1], x,
+                                           self.neighbor_major)
+                pooled = att(jnp.concatenate(
+                    [jnp.expand_dims(x, axis), nbr], axis=axis), axis)
                 next_hidden.append(nn.tanh(
                     nn.Dense(self.dim, name=f"w_{depth}_{hop}")(pooled)))
             hidden = next_hidden

@@ -104,19 +104,19 @@ class SparseEmbedding(nn.Module):
 
 
 class AttLayer(nn.Module):
-    """Single-query soft attention pooling over a set [B, L, D] → [B, D].
+    """Single-query soft attention pooling over a set [B, L, D] → [B, D];
+    `axis` names the set's axis (0: a set-major [L, B, D]).
     Parity: reference AttLayer (layers.py:~200)."""
 
     dim: int
 
     @nn.compact
-    def __call__(self, x: Array) -> Array:
+    def __call__(self, x: Array, axis: int = 1) -> Array:
         q = self.param("query", nn.initializers.normal(stddev=0.1),
                        (self.dim,))
-        keys = nn.Dense(self.dim, name="key")(x)            # [B, L, dim]
-        logits = jnp.einsum("bld,d->bl", jnp.tanh(keys), q)
-        att = nn.softmax(logits, axis=-1)
-        return jnp.einsum("bl,bld->bd", att, x)
+        keys = nn.Dense(self.dim, name="key")(x)            # x's, D → dim
+        att = nn.softmax(jnp.tanh(keys) @ q, axis=axis)
+        return (att[..., None] * x).sum(axis=axis)
 
 
 class LSTMLayer(nn.Module):

@@ -289,3 +289,44 @@ def test_pallas_gather_mean_compiles_at_accepted_shape(one_chip):
         compiled = _pallas_gather_mean.lower(
             table, rows, tile_n=8, one_sem=one_sem).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mean_encoder_views_its_hops_without_a_relayout(one_chip):
+    """(f) _GatherEncode(encoder="sage") value-and-grad at the sage3
+    cells' fanouts (15, 10, 5: no multiple of the 8-row tile) and 256
+    roots: the ids go neighbour-major before the gather, so every view
+    of a hop by its parents' slots is a bitcast. In the draw's own order
+    the entry computation held six rank-3 reshapes (my compile of
+    72bcd55, PR 31: `s8[38400,5,128]`, `f32[3840,10,512]`, twice
+    `f32[256,15,512]`, ...), each a copy of the hop on the chip."""
+    from euler_tpu import obs
+    from euler_tpu.models.graphsage import _GatherEncode
+
+    fanouts, roots = (15, 10, 5), 256
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    table, scale = sds((100_001, 128), jnp.int8), sds((128,), jnp.bfloat16)
+    rows = [sds((roots * int(np.prod(fanouts[:h])),), jnp.int32)
+            for h in range(len(fanouts) + 1)]
+    enc = _GatherEncode(256, fanouts, "mean", "sage")
+    params = _with_sharding(
+        jax.eval_shape(enc.init, jax.random.key(0), table, scale, rows),
+        one_chip)
+    counter = obs.counter("neighbor_major_fanout_traces_total", "",
+                          ("encoder",)).labels(encoder="sage")
+    count = counter.value
+    text = jax.jit(jax.value_and_grad(
+        lambda p, t, s, r: enc.apply(p, t, s, r).sum())).lower(
+            params, table, scale, rows).compile().as_text()
+    assert counter.value == count + 1      # one a traced program
+    entry = text[text.index("\nENTRY "):]
+    views = re.findall(
+        r"= ((?:f32|s8)\[\d+,\d+,\d+\])\S* (reshape|copy|bitcast)\(", entry)
+    copied = [v for v in views if v[1] != "bitcast"]
+    assert not copied, copied
+    # the views are there, slots first
+    assert {("s8[5,38400,128]", "bitcast"), ("s8[10,3840,128]", "bitcast"),
+            ("f32[10,3840,512]", "bitcast"),
+            ("f32[15,256,512]", "bitcast")} <= set(views), views
