@@ -6,7 +6,7 @@ ScalableSage) over the dense fanout path (SURVEY.md §2.3 encoders).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -161,6 +161,8 @@ class _GatherEncode(nn.Module):
     heads: int = 1      # encoders 'gat' and 'unimp' only, as is out_dim
     out_dim: int = 0
     label_rate: float = 0.0  # encoder 'unimp' only
+    batch_norm: bool = False  # encoder 'gat' only, as is head_dim
+    head_dim: int = 0
 
     @nn.compact
     def __call__(self, table, scale, rows, label_in=None):
@@ -184,7 +186,8 @@ class _GatherEncode(nn.Module):
                                 self.out_dim, name="enc")(layers, masks)
         if self.encoder == "gat":
             return GATEncoder(self.dim, self.fanouts, self.heads,
-                              self.out_dim, name="enc")(layers, masks)
+                              self.out_dim, self.batch_norm, self.head_dim,
+                              name="enc")(layers, masks)
         if self.encoder == "gcn":
             return GCNEncoder(self.dim, self.fanouts, neighbor_major=True,
                               name="enc")(layers)
@@ -242,7 +245,15 @@ class DeviceSampledGraphSage(SuperviseModel):
     LayerNorm (utils/encoders.UniMPEncoder, `heads` x `dim` as 'gat'),
     and the sampled neighbours' labels as inputs, each shown to a step
     with probability `label_rate` and never a root's own
-    (`_GatherEncode._with_labels`)."""
+    (`_GatherEncode._with_labels`). With 'gat', `norm="batch"` puts a
+    batch normalisation between every hidden layer's skip-add and its
+    ELU, its statistics taken over ALL rows the layer writes (every hop
+    together; utils/encoders.HopBatchNorm) and carried as `batch_stats`
+    in the train state (running ones at evaluation), and `head_dim=W`
+    makes the last layer a hidden one too, followed by the classifier
+    Dense(W) - norm - ReLU - Dense(num_classes): OGB-LSC's MAG240M
+    baseline GAT. Feature rows of any width are gathered as stored (a
+    768-byte int8 row is six lane tiles; `DeviceFeatureStore`)."""
 
     dim: int = 32
     fanouts: Sequence[int] = (10, 10)
@@ -250,6 +261,9 @@ class DeviceSampledGraphSage(SuperviseModel):
     encoder: str = "sage"
     heads: int = 4  # attention heads a layer ('gat', 'unimp')
     label_rate: float = 0.625  # share of labels a step shows ('unimp')
+    # 'batch': normalised hidden layers and head ('gat')
+    norm: Optional[str] = None
+    head_dim: int = 0  # > 0: an MLP head of this width ('gat')
     # remat: recompute gather+encode in the backward pass
     # (_RematGatherEncode) — unlocks batches whose per-hop feature
     # layers don't fit HBM twice. Replicated tables only.
@@ -272,6 +286,14 @@ class DeviceSampledGraphSage(SuperviseModel):
             raise ValueError(
                 f"DeviceSampledGraphSage.encoder must be "
                 f"{', '.join(names)} or {last}, got {self.encoder!r}")
+        if self.norm not in (None, "batch") or self.head_dim < 0:
+            raise ValueError(
+                "DeviceSampledGraphSage.norm must be None or 'batch' and "
+                f"head_dim >= 0, got {self.norm!r} and {self.head_dim}")
+        if (self.norm or self.head_dim) and self.encoder != "gat":
+            raise ValueError(
+                "DeviceSampledGraphSage.norm and head_dim are encoder "
+                f"'gat''s, got encoder={self.encoder!r}")
         roots = batch["rows"][0]
         key = jax.random.fold_in(jax.random.key(17), batch["sample_seed"])
         # table_mesh set → tables are row-sharded over 'model' and every
@@ -316,7 +338,9 @@ class DeviceSampledGraphSage(SuperviseModel):
         mod = mod_cls(self.dim, tuple(self.fanouts), self.aggregator,
                       self.encoder, gather=gather if sharded else None,
                       heads=int(self.heads), out_dim=int(self.num_classes),
-                      label_rate=float(self.label_rate), name="encoder")
+                      label_rate=float(self.label_rate),
+                      batch_norm=self.norm == "batch",
+                      head_dim=int(self.head_dim), name="encoder")
         label_in = None
         if self.encoder == "unimp":
             if not 0.0 <= self.label_rate <= 1.0:

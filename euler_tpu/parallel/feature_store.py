@@ -28,11 +28,19 @@ memory_plan.plan_partitioned_table emits the per-chip fit verdict.
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# float32 elements a quantisation chunk holds (16 MB: it stays in cache
+# between its passes) and the threads that work through the chunks: the
+# transients are a few chunks a thread, whatever the table's size
+_QUANT_CHUNK_ELEMS = 1 << 22
+_QUANT_THREADS = 8
 
 
 def quantize_int8(feats: np.ndarray):
@@ -41,12 +49,40 @@ def quantize_int8(feats: np.ndarray):
     bytes every feature-row gather moves out of HBM vs bf16 (the hop-2
     gather dominates step HBM traffic at products scale) and halves the
     table's HBM footprint; dequant (q·scale) runs after the gather,
-    fused into the consumer by XLA. All-zero columns get scale 1."""
-    scale = np.abs(feats).max(axis=0).astype(np.float32) / 127.0
-    scale[scale == 0] = 1.0
-    q = np.clip(np.rint(feats.astype(np.float32, copy=False) / scale),
-                -127, 127)
-    return q.astype(np.int8), scale
+    fused into the consumer by XLA. All-zero columns get scale 1.
+
+    Works in ROW CHUNKS on a thread pool, straight from the dtype it is
+    handed (each chunk cast to float32 on its thread): the column maxima
+    first, then the rounded quotient chunk by chunk into the int8 table.
+    Nothing table-sized is made but the result, at any row width; the
+    bytes are those of the one-pass arithmetic. Span `quantize`, counter
+    `quantize_chunks_total` (chunks a pass, both passes)."""
+    from euler_tpu import obs
+
+    rows, dim = feats.shape
+    step = max(1, _QUANT_CHUNK_ELEMS // max(dim, 1))
+    starts = range(0, rows, step)
+    chunks = obs.counter(
+        "quantize_chunks_total",
+        "row chunks the feature store's int8 quantisation worked through "
+        "(the column maxima and the rounded quotient are a pass each)")
+
+    def chunk(lo):
+        chunks.inc()
+        return feats[lo:lo + step].astype(np.float32, copy=False)
+
+    def fill(lo):
+        q[lo:lo + step] = np.clip(np.rint(chunk(lo) / scale), -127, 127)
+
+    with obs.span("quantize", rows=rows, dim=dim), \
+            ThreadPoolExecutor(_QUANT_THREADS) as pool:
+        tops = list(pool.map(lambda lo: np.abs(chunk(lo)).max(axis=0),
+                             starts))
+        scale = np.max(tops, axis=0).astype(np.float32) / 127.0
+        scale[scale == 0] = 1.0
+        q = np.empty(feats.shape, np.int8)
+        list(pool.map(fill, starts))
+    return q, scale
 
 
 def dequantize_rows(x, scale):
@@ -73,8 +109,11 @@ class DeviceFeatureStore:
                  keep_host: bool = False, shard_rows: bool = False,
                  quantize: Optional[str] = None):
         """quantize='int8' stores the feature table int8 with a
-        per-column scale (quantize_int8); the store exposes
-        feature_scale and models dequantize after the gather."""
+        per-column scale (quantize_int8, in row chunks: no table-sized
+        float32 copy is made); the store exposes feature_scale and
+        models dequantize after the gather. Rows keep the width they
+        come with, whatever it is: a 768-wide table is gathered as
+        768-byte int8 rows (six lane tiles), nothing is cut or split."""
         self.shard_rows = bool(shard_rows)
         self.mesh = mesh
         # table rows follow ENGINE row order so lookup() is the engine's
@@ -100,7 +139,7 @@ class DeviceFeatureStore:
             (lambda x: put_replicated(x, mesh))
         self.feature_scale = None
         if quantize == "int8":
-            q, scale = quantize_int8(np.asarray(feats, np.float32))
+            q, scale = quantize_int8(feats)
             self.features = put(q)
             self.feature_scale = put_replicated(
                 scale.astype(np.dtype(dtype), copy=False), mesh)
@@ -136,7 +175,11 @@ class DeviceFeatureStore:
         graphs, e.g. the bench cache). pad_dim_to zero-pads the feature
         dim up to a lane multiple (e.g. 128) so each gathered row is an
         aligned tile — a throughput knob; downstream Dense layers see
-        the wider (zero-extended) features."""
+        the wider (zero-extended) features. Rows WIDER than a tile (768
+        features: six tiles) are placed and gathered as they are.
+        quantize='int8' quantises in row chunks on threads from the
+        dtype `features` comes in (pass bfloat16 or float16 as stored:
+        no float32 copy of the table is made, here or inside)."""
         self = cls.__new__(cls)
         self._graph = None
         self.host_arrays = None
@@ -160,8 +203,13 @@ class DeviceFeatureStore:
                           features.dtype)], axis=1)
         self.feature_scale = None
         if quantize == "int8":
-            q, scale = quantize_int8(np.asarray(features, np.float32))
-            self.features = put(np.ascontiguousarray(q))
+            q, scale = quantize_int8(features)
+            # the host's int8 table and its transfer buffer go before the
+            # labels' come: left to overlap, a 768-wide share's placement
+            # held both tables twice beside the caller's own copies and
+            # met a 40 GiB host's limit (PERF.md, PR 32)
+            self.features = jax.block_until_ready(put(q))
+            del q
             self.feature_scale = put_replicated(
                 scale.astype(np.dtype(scale_dtype), copy=False), mesh)
         elif quantize is not None:

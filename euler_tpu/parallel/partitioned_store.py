@@ -213,7 +213,7 @@ class PartitionedFeatureStore:
         if quantize == "int8":
             # scale computed over the FULL table so hub cache, shard and
             # reference share one quantization — parity stays byte-exact
-            feats, scale = quantize_int8(np.asarray(feats, np.float32))
+            feats, scale = quantize_int8(feats)
             self.feature_scale = put_replicated(
                 scale.astype(np.dtype(scale_dtype), copy=False), mesh)
         elif quantize is not None:

@@ -250,6 +250,71 @@ def _attend(z_t: Array, z_s: Array, e_self: Array, e_nbr: Array,
     return sum(outs) / heads
 
 
+class HopBatchNorm(nn.Module):
+    """Batch normalisation (Ioffe & Szegedy 2015; PyTorch's BatchNorm1d)
+    of hop-structured activations: a layer applied pair by pair with
+    shared weights writes one array a hop, and the statistics are taken
+    per channel over EVERY row of EVERY array handed in, the arrays'
+    sums combined before anything is divided, as if they were one array
+    (the one PyG's model normalises). Rows whose mask is False (pad
+    slots' rows) are left out of the statistics; a row drawn twice is
+    two rows.
+
+        y = (1 + g) (x - mu) / sqrt(var + eps) + beta
+
+    with the batch's mu and biased var where the `batch_stats` collection
+    is mutable (a train step), which then also moves the running ones:
+    mean <- (1 - m) mean + m mu, var <- (1 - m) var + m var n / (n - 1);
+    the running ones where it is not (`evaluate`, `infer`, a served
+    bundle). The gain is stored as its OFFSET g from one, as
+    `OffsetLayerNorm`'s. Trace-time counter
+    `batch_norm_traces_total{layer}`."""
+
+    MOMENTUM = 0.1      # PyTorch's defaults, the published model's
+    EPSILON = 1e-5
+
+    @nn.compact
+    def __call__(self, xs: Sequence[Array],
+                 masks: Sequence[Optional[Array]]) -> list:
+        from euler_tpu import obs
+
+        d = xs[0].shape[-1]
+        gain = 1.0 + self.param("gain_offset", nn.initializers.zeros, (d,))
+        bias = self.param("bias", nn.initializers.zeros, (d,))
+        mean = self.variable("batch_stats", "mean",
+                             lambda: jnp.zeros((d,), jnp.float32))
+        var = self.variable("batch_stats", "var",
+                            lambda: jnp.ones((d,), jnp.float32))
+        # trace time only: nothing is fetched from the device for it
+        obs.counter(
+            "batch_norm_traces_total",
+            "batch normalisations traced into a program (or run eagerly), "
+            "one a norm whatever the hops it spans",
+            ("layer",)).labels(layer="/".join(self.path[-2:-1])).inc()
+        if self.is_mutable_collection("batch_stats"):
+            weights = [None if m is None else m.astype(x.dtype)[:, None]
+                       for x, m in zip(xs, masks)]
+
+            def total(parts):
+                return sum((p if w is None else p * w).sum(axis=0)
+                           for p, w in zip(parts, weights))
+
+            n = sum(jnp.float32(x.shape[0]) if m is None
+                    else m.sum().astype(jnp.float32)
+                    for x, m in zip(xs, masks))
+            mu = total(xs) / n
+            sigma2 = total([jnp.square(x - mu) for x in xs]) / n
+            if not self.is_initializing():
+                m = self.MOMENTUM
+                mean.value = (1.0 - m) * mean.value + m * mu
+                var.value = (1.0 - m) * var.value \
+                    + m * sigma2 * n / jnp.maximum(n - 1.0, 1.0)
+        else:
+            mu, sigma2 = mean.value, var.value
+        scale = gain * jax.lax.rsqrt(sigma2 + self.EPSILON)
+        return [(x - mu) * scale + bias for x in xs]
+
+
 class GATLayer(nn.Module):
     """One graph-attention layer (Velickovic et al. 2018; PyG's GATConv
     with a linear skip, as examples/ogbn_products_gat.py stacks them)
@@ -262,13 +327,17 @@ class GATLayer(nn.Module):
         x_i' = y_i + x_i S + s, through ELU where heads are concatenated.
 
     A slot drawn twice counts twice; pad slots (masks False) take no
-    weight. Every hop is projected once a layer. Scopes `proj`, `attn`,
-    `skip` under the layer's name; trace-time counter
+    weight. Every hop is projected once a layer. With `batch_norm`, where
+    heads are concatenated, the sum y_i + x_i S + s passes a
+    `HopBatchNorm` before its ELU, ONE for the layer: its statistics span
+    every hop the layer writes (OGB-LSC's MAG240M baseline). Scopes `proj`, `attn`, `skip` (and `norm`) under
+    the layer's name; trace-time counter
     `gat_attention_traces_total{layer}`."""
 
     width: int          # C, one head's
     heads: int
     concat: bool        # False: heads averaged, no ELU (the last layer)
+    batch_norm: bool = False
 
     @nn.compact
     def __call__(self, hidden: Sequence[Array],
@@ -277,6 +346,8 @@ class GATLayer(nn.Module):
 
         h, c = self.heads, self.width
         out_dim = h * c if self.concat else c
+        # the layer that averages its heads onto the classes has no norm
+        normed = self.batch_norm and self.concat
         proj = nn.Dense(h * c, use_bias=False, name="proj")
         zs = [proj(x) for x in hidden]
         att = jnp.concatenate(
@@ -313,8 +384,30 @@ class GATLayer(nn.Module):
                             self.concat) + bias
             with jax.named_scope("skip"):
                 y = y + skip(x)
-                out.append(nn.elu(y) if self.concat else y)
+                out.append(nn.elu(y) if self.concat and not normed else y)
+        if normed:
+            out = HopBatchNorm(name="norm")(out, masks[:len(out)])
+            with jax.named_scope("norm"):
+                out = [nn.elu(y) for y in out]
         return out
+
+
+class _MLPHead(nn.Module):
+    """Dense(width) - HopBatchNorm - ReLU - Dense(out_dim) on the roots'
+    hidden rows: the classifier OGB-LSC's MAG240M baselines put behind
+    their last layer. Scope `head` (the module's name), its norm under
+    `head/norm`."""
+
+    width: int
+    out_dim: int
+    batch_norm: bool
+
+    @nn.compact
+    def __call__(self, x: Array, mask: Optional[Array]) -> Array:
+        h = nn.Dense(self.width, name="fc")(x)
+        if self.batch_norm:
+            h, = HopBatchNorm(name="norm")([h], [mask])
+        return nn.Dense(self.out_dim, name="out")(nn.relu(h))
 
 
 class _AttentionEncoder(nn.Module):
@@ -322,6 +415,9 @@ class _AttentionEncoder(nn.Module):
     name)`, the subclass's) over a sampled fanout, deepest pairs first as
     SageEncoder applies its aggregators: hidden layers of `heads` heads
     of width `dim` concatenated, the last of width `out_dim` averaged.
+    With `head_dim` the last layer is a hidden one too and an `_MLPHead`
+    of that width maps the roots to `out_dim`; `batch_norm` (GATLayer
+    only) puts a `HopBatchNorm` into every hidden layer and the head.
 
     layers[h]: hop h's features NEIGHBOUR-MAJOR (`neighbor_major_rows`);
     masks[h]: bool per row of hop h, False for a pad slot (None: no pads).
@@ -331,6 +427,8 @@ class _AttentionEncoder(nn.Module):
     fanouts: Sequence[int]
     heads: int
     out_dim: int
+    batch_norm: bool = False
+    head_dim: int = 0
 
     layer_cls = None
 
@@ -342,13 +440,17 @@ class _AttentionEncoder(nn.Module):
             f"need {n_hops + 1} feature layers for {n_hops} fanouts")
         hidden = list(layers)
         masks = list(masks) if masks is not None else [None] * len(hidden)
+        norm = {"batch_norm": True} if self.batch_norm else {}
         for depth in range(n_hops):
-            last = depth == n_hops - 1
+            last = depth == n_hops - 1 and not self.head_dim
             layer = self.layer_cls(self.out_dim if last else self.dim,
                                    self.heads, concat=not last,
-                                   name=f"layer{depth}")
+                                   name=f"layer{depth}", **norm)
             hidden = layer(hidden, masks)
-        return hidden[0]
+        if not self.head_dim:
+            return hidden[0]
+        return _MLPHead(self.head_dim, self.out_dim, self.batch_norm,
+                        name="head")(hidden[0], masks[0])
 
 
 class GATEncoder(_AttentionEncoder):
