@@ -25,7 +25,11 @@ import jax
 import jax.numpy as jnp
 from jax.custom_derivatives import SymbolicZero
 
-from euler_tpu.utils.aggregators import get_aggregator, mean_with_self
+from euler_tpu.utils.aggregators import (
+    Parts,
+    get_aggregator,
+    mean_with_self,
+)
 from euler_tpu.utils.layers import (
     AttLayer,
     Embedding,
@@ -65,13 +69,19 @@ class ShallowEncoder(nn.Module):
         return jnp.concatenate(parts, axis=-1)
 
 
-def _hop_neighbors(child: Array, parent: Array,
-                   neighbor_major: bool = False) -> Tuple[Array, int]:
+def _hop_neighbors(child: Parts, parent: Array,
+                   neighbor_major: bool = False) -> Tuple[Parts, int]:
     """Hop h+1's flat layer viewed by its parents' slots, and the axis
     the k slots lie on: ([n_h, k, D], 1) for the host's target-major
     order, ([k, n_h, D], 0) for `neighbor_major_rows`' order. k comes
     from the (jit-static) shapes. Shared by all fanout encoders so the
     divisibility invariant lives in one place.
+
+    `child` may be a tuple of lane parts (`utils/aggregators`: the
+    [n_{h+1}, D_part] arrays whose concat along the last axis the layer
+    is; only `SageEncoder` makes one, of the deepest hop a depth wrote).
+    Each part is viewed the same way and the tuple handed on, for the
+    aggregator to reduce part by part.
 
     The order cannot be read off a feature array: `neighbor_major` is a
     fact the caller states, the one that made the order. On the chip the
@@ -79,13 +89,13 @@ def _hop_neighbors(child: Array, parent: Array,
     gathered [n_h*k, D] array is a relayout whenever k is no multiple of
     8, forward and backward; the neighbour-major one is a bitcast."""
     n = parent.shape[0]
-    assert child.shape[0] % n == 0, (
-        f"layer of {child.shape[0]} rows is not a whole fanout of the "
+    rows = jax.tree_util.tree_leaves(child)[0].shape[0]
+    assert rows % n == 0, (
+        f"layer of {rows} rows is not a whole fanout of the "
         f"{n}-row parent layer")
-    k = child.shape[0] // n
-    if neighbor_major:
-        return child.reshape(k, n, -1), 0
-    return child.reshape(n, k, -1), 1
+    k = rows // n
+    shape, axis = ((k, n, -1), 0) if neighbor_major else ((n, k, -1), 1)
+    return jax.tree_util.tree_map(lambda p: p.reshape(shape), child), axis
 
 
 class SageEncoder(nn.Module):
@@ -104,6 +114,15 @@ class SageEncoder(nn.Module):
     that re-ordered the sampled ids (models/graphsage, the device-sampled
     models), never from a kwarg or a configuration. Same parameters,
     same function of the same (node, slot) pairs either way.
+
+    The deepest hop a depth writes (every depth but the last has one) is
+    never an `x` of the next depth, only its `nbr`: it is asked of the
+    aggregator in its lane parts (`apart=True`, `utils/aggregators`) and
+    never concatenated at its k-times-the-parents row count. What the
+    next depth's aggregator does with a tuple is its own: `mean` reduces
+    it part by part (PERF.md, PR 33: layer 0's `f32[614400,512]` and its
+    cotangent's passes, gone from the sage3 cells' step), the pooling
+    ones concatenate it. Nothing selects this but the hop's position.
     """
 
     dim: int
@@ -123,12 +142,15 @@ class SageEncoder(nn.Module):
         for depth in range(n_hops):
             agg = agg_cls(dim=self.dim, concat=self.concat,
                           name=f"agg_{depth}")
+            deepest = n_hops - depth - 1
             next_hidden = []
-            for hop in range(n_hops - depth):
+            for hop in range(deepest + 1):
                 x = hidden[hop]
                 nbr, axis = _hop_neighbors(hidden[hop + 1], x,
                                            self.neighbor_major)
-                next_hidden.append(agg(x, nbr, axis))
+                # the next depth reads its deepest hop only as `nbr`
+                next_hidden.append(agg(
+                    x, nbr, axis, apart=deepest > 0 and hop == deepest))
             hidden = next_hidden
         return hidden[0]
 

@@ -298,7 +298,8 @@ def test_mean_encoder_views_its_hops_without_a_relayout(one_chip):
     of a hop by its parents' slots is a bitcast. In the draw's own order
     the entry computation held six rank-3 reshapes (my compile of
     72bcd55, PR 31: `s8[38400,5,128]`, `f32[3840,10,512]`, twice
-    `f32[256,15,512]`, ...), each a copy of the hop on the chip."""
+    `f32[256,15,512]`, ...), each a copy of the hop on the chip. And no
+    instruction yields hop 2 at twice `dim` (PR 33)."""
     from euler_tpu import obs
     from euler_tpu.models.graphsage import _GatherEncode
 
@@ -316,17 +317,27 @@ def test_mean_encoder_views_its_hops_without_a_relayout(one_chip):
         one_chip)
     counter = obs.counter("neighbor_major_fanout_traces_total", "",
                           ("encoder",)).labels(encoder="sage")
-    count = counter.value
+    parts = obs.counter("sage_hop_parts_traces_total", "",
+                        ("aggregator",)).labels(aggregator="mean")
+    count, parts_count = counter.value, parts.value
     text = jax.jit(jax.value_and_grad(
         lambda p, t, s, r: enc.apply(p, t, s, r).sum())).lower(
             params, table, scale, rows).compile().as_text()
     assert counter.value == count + 1      # one a traced program
+    assert parts.value == parts_count + 2  # hops 2 and 1, once each
     entry = text[text.index("\nENTRY "):]
     views = re.findall(
         r"= ((?:f32|s8)\[\d+,\d+,\d+\])\S* (reshape|copy|bitcast)\(", entry)
     copied = [v for v in views if v[1] != "bitcast"]
     assert not copied, copied
-    # the views are there, slots first
+    # the views are there, slots first; a hidden hop's are of its lane
+    # parts (PR 33)
     assert {("s8[5,38400,128]", "bitcast"), ("s8[10,3840,128]", "bitcast"),
-            ("f32[10,3840,512]", "bitcast"),
-            ("f32[15,256,512]", "bitcast")} <= set(views), views
+            ("f32[10,3840,256]", "bitcast"),
+            ("f32[15,256,256]", "bitcast")} <= set(views), views
+    # hop 2 never exists at twice `dim`, forward or backward: layer 0's
+    # pair is averaged over its slots half by half (at the parent
+    # `pad_maximum_fusion` wrote f32[38400,512], `reduce` read it back
+    # and `broadcast_in_dim` spread the cotangent f32[10,3840,512])
+    whole = re.findall(r"= \(?[^=]*?(f32\[(?:38400|10,3840),512\])", entry)
+    assert not whole, whole
