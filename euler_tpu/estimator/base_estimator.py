@@ -29,6 +29,7 @@ import optax
 from flax.training import train_state
 
 from euler_tpu import obs as _obs
+from euler_tpu.obs import first_calls as _first_calls
 from euler_tpu.utils import optimizers as opt_lib
 from euler_tpu.utils.layers import undo_collection
 
@@ -36,6 +37,13 @@ from euler_tpu.utils.layers import undo_collection
 # obs.span is also an "euler.<name>" event on that session's host plane,
 # on the device plane's clock (obs itself imports no jax)
 _obs.install_profiler_annotation(jax.profiler.TraceAnnotation)
+# what each jitted function's first call costs (trace, lower, compile or
+# cache fetch), booked to the function the estimator says it is calling
+# (`_calling`): obs/first_calls.py. Silent once every shape is warm.
+jax.monitoring.register_event_duration_secs_listener(
+    _first_calls.on_duration)
+jax.monitoring.register_event_listener(_first_calls.on_event)
+_calling = _first_calls.calling
 # the device program's kernels are found in a profile by their
 # jax.named_scope names (draw/hop<h>, gather/hop<h>, cache, update ...),
 # which are op metadata. jax leaves metadata out of the compile cache's
@@ -229,6 +237,12 @@ class BaseEstimator:
             "estimator_hook_ms",
             "per-step logging/checkpoint hooks", ("estimator",)
         ).labels(**lab)
+        self._hist_train_call = reg.histogram(
+            "estimator_train_call_ms",
+            "one call of train(), whole: the first batch, state init and "
+            "first calls where it pays them, its steps or dispatches, the "
+            "closing fetches", ("estimator",),
+            buckets=_obs.SETUP_MS_BUCKETS).labels(**lab)
         self._g_steps_per_sec = reg.gauge(
             "estimator_steps_per_sec", "train-loop throughput",
             ("estimator",)).labels(**lab)
@@ -260,39 +274,52 @@ class BaseEstimator:
 
     # -- setup -------------------------------------------------------------
     def _init_state(self, batch: Dict, rng=None) -> None:
-        rng = rng if rng is not None else jax.random.key(
-            int(self.params_cfg.get("seed", 0)))
-        # jitted: op-by-op eager init made the canonical warm-up 112
-        # compiles on one chip and 279 on a 2x2 mesh (18 on both now; ~20 s
-        # and ~40 s of warm-up — PR 21 chip_smoke readings) and held
-        # every unfused full-batch intermediate in HBM at once. The init
-        # runs the same __call__ the jitted train step traces, so
-        # whatever that step accepts as a batch, this does.
-        variables = dict(jax.jit(self.model.init)(rng, batch))
-        params = variables.pop("params")
-        self.state = TrainState.create(
-            apply_fn=self.model.apply, params=params, tx=self.tx,
-            extra_vars=dict(variables),
-            skipped_steps=jnp.zeros((), jnp.int32),
-        )
-        # the mesh the state lives on: the estimator's own, else the one
-        # the feature store was told to place its tables on
-        mesh = self.mesh
-        if mesh is None:
-            mesh = getattr(getattr(self, "feature_store", None), "mesh",
-                           None)
-        if mesh is not None:
-            # commit the fresh state REPLICATED on that mesh. As
-            # created, some of its leaves are uncommitted, while every
-            # jitted step returns it committed — so without this the
-            # first scanned window compiles for "unspecified" state
-            # shardings and the second one compiles the whole window
-            # again (found by chip_smoke's no-compile-after-warm-up
-            # check, PR 21)
-            from jax.sharding import NamedSharding, PartitionSpec
+        span = self._span
+        with span("init_state"), _calling("init"):
+            with span("model_init"):
+                rng = rng if rng is not None else jax.random.key(
+                    int(self.params_cfg.get("seed", 0)))
+                # jitted: op-by-op eager init made the canonical warm-up
+                # 112 compiles on one chip and 279 on a 2x2 mesh (18 on
+                # both now; ~20 s and ~40 s of warm-up — PR 21 chip_smoke
+                # readings) and held every unfused full-batch intermediate
+                # in HBM at once. The init runs the same __call__ the
+                # jitted train step traces, so whatever that step accepts
+                # as a batch, this does.
+                variables = dict(jax.jit(self.model.init)(rng, batch))
+            with span("create_state"):
+                params = variables.pop("params")
+                self.state = TrainState.create(
+                    apply_fn=self.model.apply, params=params, tx=self.tx,
+                    extra_vars=dict(variables),
+                    skipped_steps=jnp.zeros((), jnp.int32),
+                )
+            # the mesh the state lives on: the estimator's own, else the
+            # one the feature store was told to place its tables on
+            mesh = self.mesh
+            if mesh is None:
+                mesh = getattr(getattr(self, "feature_store", None),
+                               "mesh", None)
+            if mesh is not None:
+                # commit the fresh state REPLICATED on that mesh. As
+                # created, some of its leaves are uncommitted, while every
+                # jitted step returns it committed — so without this the
+                # first scanned window compiles for "unspecified" state
+                # shardings and the second one compiles the whole window
+                # again (found by chip_smoke's no-compile-after-warm-up
+                # check, PR 21)
+                from jax.sharding import NamedSharding, PartitionSpec
 
-            self.state = jax.device_put(
-                self.state, NamedSharding(mesh, PartitionSpec()))
+                with span("commit_state"):
+                    self.state = jax.device_put(
+                        self.state, NamedSharding(mesh, PartitionSpec()))
+
+    def _first_state(self, batch: Dict) -> None:
+        """A fresh state from `batch`, then the newest checkpoint over
+        it where there is one."""
+        self._init_state(batch)
+        with self._span("restore_checkpoint"):
+            self.restore_checkpoint()
 
     def _make_one_step(self):
         """The single SGD step shared by the per-step jit and the scanned
@@ -332,13 +359,10 @@ class BaseEstimator:
             # compiler copies the whole table twice a step (PERF.md, PR 27)
             by_rows = undo if guarded else {}
             for k in by_rows:
-                # trace time only, as act_cache_fused_traces_total
-                _obs.counter(
-                    "guard_row_rollback_traces_total",
-                    "variables of a mutable collection that the non-finite "
-                    "guard rolls back by rows, counted each time a train "
-                    "step is traced", ("collection",)
-                ).labels(collection=k).inc(
+                # the variables of collection k rolled back by rows,
+                # counted each time a train step is traced
+                _obs.traced_path(
+                    "guard_row_rollback", k,
                     len(jax.tree_util.tree_leaves(new_vars[k])))
             if by_rows:
                 state = state.replace(extra_vars={
@@ -545,6 +569,10 @@ class BaseEstimator:
         to the loops' break handlers unchanged."""
         return _obs.timed_span(name, hist, estimator=self._obs_name)
 
+    def _span(self, name: str, **attrs):
+        """A span of this estimator's that no histogram keeps."""
+        return _obs.span(name, estimator=self._obs_name, **attrs)
+
     def _emergency_checkpoint(self, err: BaseException) -> None:
         """Best-effort checkpoint before an unrecoverable input error
         re-raises — the run dies, the progress doesn't. Never masks the
@@ -666,22 +694,27 @@ class BaseEstimator:
     # -- drivers -----------------------------------------------------------
     def train(self, input_fn: Callable[[], Iterator[Dict]],
               max_steps: int = 1000) -> Dict[str, float]:
-        if self.feeder_workers > 1 and callable(input_fn):
-            # multi-worker feeder: K sampler threads over the input
-            # stream; it owns worker threads, so train() reclaims it on
-            # every exit path and recreation-on-failure rebuilds it
-            use_factory = input_fn == getattr(self, "train_input_fn",
-                                              None)
-            it = self._wrap_feeder(input_fn, use_factory)
-            self._input_factory = lambda: self._wrap_feeder(input_fn,
-                                                            use_factory)
-            try:
-                return self._train_impl(it, max_steps)
-            finally:
-                self._close_live_feeder()
-        it = input_fn() if callable(input_fn) else input_fn
-        self._input_factory = input_fn if callable(input_fn) else None
-        return self._train_impl(it, max_steps)
+        # one parent span a call: everything below is its child, so
+        # set-up's calls (state init, the first steps, the first scanned
+        # dispatch) and the window's lie on one timeline, each whole
+        with _obs.timed_span("train", self._hist_train_call,
+                             estimator=self._obs_name, max_steps=max_steps):
+            if self.feeder_workers > 1 and callable(input_fn):
+                # multi-worker feeder: K sampler threads over the input
+                # stream; it owns worker threads, so train() reclaims it
+                # on every exit path and recreation-on-failure rebuilds it
+                use_factory = input_fn == getattr(self, "train_input_fn",
+                                                  None)
+                it = self._wrap_feeder(input_fn, use_factory)
+                self._input_factory = lambda: self._wrap_feeder(
+                    input_fn, use_factory)
+                try:
+                    return self._train_impl(it, max_steps)
+                finally:
+                    self._close_live_feeder()
+            it = input_fn() if callable(input_fn) else input_fn
+            self._input_factory = input_fn if callable(input_fn) else None
+            return self._train_impl(it, max_steps)
 
     def _train_impl(self, it, max_steps: int) -> Dict[str, float]:
         with self._phase("input_wait", self._hist_input_wait):
@@ -689,10 +722,11 @@ class BaseEstimator:
             raw_first = _to_device_tree(raw0, self.max_id)
         first = _merged(raw_first, self.static_batch)
         if self.state is None:
-            self._init_state(first)
-            self.restore_checkpoint()
+            self._first_state(first)
         if self._train_step is None:
-            self._train_step = self._build_train_step()
+            with self._span("build_fn", fn="train_step"), \
+                    _calling("train_step"):
+                self._train_step = self._build_train_step()
         if self.profiling and self.model_dir:
             jax.profiler.start_trace(os.path.join(self.model_dir, "prof"))
         if self.steps_per_loop > 1:
@@ -711,7 +745,8 @@ class BaseEstimator:
         while step < max_steps:
             with _obs.span("train_step", estimator=self._obs_name,
                            step=step):
-                with self._phase("device_step", self._hist_device_step):
+                with self._phase("device_step", self._hist_device_step), \
+                        _calling("train_step"):
                     self.state, loss, metric = self._train_step(
                         self.state, _merged(batch, self.static_batch))
                 step += 1
@@ -752,28 +787,32 @@ class BaseEstimator:
                             batch = _to_device_tree(raw, self.max_id)
                     except StopIteration:
                         break
-        if self.ckpt_steps:
-            self.save_checkpoint(step)
-        self.finalize_checkpoints()
-        if self.profiling and self.model_dir:
-            jax.profiler.stop_trace()
-        rate = (step - start_step) / max(time.monotonic() - t0, 1e-9)
-        skipped = self._skipped_steps()
-        self._g_steps_per_sec.set(rate)
-        self._g_skipped_steps.set(skipped)
-        self._g_global_step.set(step)
-        return {
-            # guard-skipped steps report NaN loss/metric; exclude them
-            # from the summary so one bad batch doesn't blank the run's
-            # headline numbers (the skip itself is in skipped_steps)
-            "loss": _last_finite(losses),
-            "metric": float(jnp.nanmean(jnp.stack(metrics)))
-            if metrics else 0.0,
-            "steps_per_sec": rate,
-            "global_step": step,
-            "skipped_steps": skipped,
-            "skipped_batches": self.input_health["skipped_batches"],
-        }
+        # the closing checkpoint and the fetches of the summary: on this
+        # path the one place the host waits for the steps it enqueued
+        with self._span("train_finish"):
+            if self.ckpt_steps:
+                self.save_checkpoint(step)
+            self.finalize_checkpoints()
+            if self.profiling and self.model_dir:
+                jax.profiler.stop_trace()
+            rate = (step - start_step) / max(time.monotonic() - t0, 1e-9)
+            skipped = self._skipped_steps()
+            self._g_steps_per_sec.set(rate)
+            self._g_skipped_steps.set(skipped)
+            self._g_global_step.set(step)
+            return {
+                # guard-skipped steps report NaN loss/metric; exclude
+                # them from the summary so one bad batch doesn't blank
+                # the run's headline numbers (the skip itself is in
+                # skipped_steps)
+                "loss": _last_finite(losses),
+                "metric": float(jnp.nanmean(jnp.stack(metrics)))
+                if metrics else 0.0,
+                "steps_per_sec": rate,
+                "global_step": step,
+                "skipped_steps": skipped,
+                "skipped_batches": self.input_health["skipped_batches"],
+            }
 
     def _run_looped(self, it, first: Dict, max_steps: int) -> Dict[str, float]:
         """steps_per_loop > 1 train path: full K-step windows dispatch as
@@ -816,11 +855,14 @@ class BaseEstimator:
                     break
                 if len(buf) == K:
                     if self._train_loop is None:
-                        self._train_loop = self._build_train_loop()
+                        with self._span("build_fn", fn="train_loop"), \
+                                _calling("train_loop"):
+                            self._train_loop = self._build_train_loop()
                     with _obs.span("stack", estimator=self._obs_name):
                         stacked = jax.tree_util.tree_map(stack, *buf)
                     with self._phase("device_step",
-                                     self._hist_device_step):
+                                     self._hist_device_step), \
+                            _calling("train_loop"):
                         self.state, l_arr, m_arr = self._train_loop(
                             self.state, stacked, self.static_batch)
                     with self._phase("result_wait",
@@ -843,14 +885,18 @@ class BaseEstimator:
                     # entered)
                     for b in buf:
                         with self._phase("device_step",
-                                         self._hist_device_step):
+                                         self._hist_device_step), \
+                                _calling("train_step"):
                             self.state, l, m = self._train_step(
                                 self.state,
                                 _merged(b, self.static_batch))
                         loop_losses.append((l, 1))
                         loop_metrics.append((m, 1))
-                        if np.isfinite(float(l)):
-                            last_loss = float(l)
+                        with self._phase("result_wait",
+                                         self._hist_result_wait):
+                            fin = float(l)
+                        if np.isfinite(fin):
+                            last_loss = fin
                     done = len(buf)
                 prev = step
                 step += done
@@ -877,36 +923,40 @@ class BaseEstimator:
                             self.save_checkpoint(step)
             if exhausted:
                 break
-        if self.ckpt_steps:
-            self.save_checkpoint(step)
-        self.finalize_checkpoints()
-        if self.profiling and self.model_dir:
-            jax.profiler.stop_trace()
-        # step-weighted mean so the reported train metric matches what
-        # the same run would report with steps_per_loop=1; NaN entries
-        # (guard-skipped steps / all-skipped windows) drop out with
-        # their weight
-        if loop_metrics:
-            w = np.asarray([c for _, c in loop_metrics], np.float64)
-            vals = np.asarray([float(v) for v, _ in loop_metrics])
-            keep = np.isfinite(vals)
-            metric = float(np.dot(vals[keep], w[keep] / w[keep].sum())) \
-                if keep.any() else float("nan")
-        else:
-            metric = 0.0
-        rate = (step - start_step) / max(time.monotonic() - t0, 1e-9)
-        skipped = self._skipped_steps()
-        self._g_steps_per_sec.set(rate)
-        self._g_skipped_steps.set(skipped)
-        self._g_global_step.set(step)
-        return {
-            "loss": float(last_loss),
-            "metric": metric,
-            "steps_per_sec": rate,
-            "global_step": step,
-            "skipped_steps": skipped,
-            "skipped_batches": self.input_health["skipped_batches"],
-        }
+        # the closing checkpoint and the summary's fetches (the windows'
+        # metrics, the skip counter)
+        with self._span("train_finish"):
+            if self.ckpt_steps:
+                self.save_checkpoint(step)
+            self.finalize_checkpoints()
+            if self.profiling and self.model_dir:
+                jax.profiler.stop_trace()
+            # step-weighted mean so the reported train metric matches what
+            # the same run would report with steps_per_loop=1; NaN entries
+            # (guard-skipped steps / all-skipped windows) drop out with
+            # their weight
+            if loop_metrics:
+                w = np.asarray([c for _, c in loop_metrics], np.float64)
+                vals = np.asarray([float(v) for v, _ in loop_metrics])
+                keep = np.isfinite(vals)
+                metric = float(
+                    np.dot(vals[keep], w[keep] / w[keep].sum())) \
+                    if keep.any() else float("nan")
+            else:
+                metric = 0.0
+            rate = (step - start_step) / max(time.monotonic() - t0, 1e-9)
+            skipped = self._skipped_steps()
+            self._g_steps_per_sec.set(rate)
+            self._g_skipped_steps.set(skipped)
+            self._g_global_step.set(step)
+            return {
+                "loss": float(last_loss),
+                "metric": metric,
+                "steps_per_sec": rate,
+                "global_step": step,
+                "skipped_steps": skipped,
+                "skipped_batches": self.input_health["skipped_batches"],
+            }
 
     def evaluate(self, input_fn, steps: int = 100) -> Dict[str, float]:
         it = input_fn() if callable(input_fn) else input_fn
@@ -920,11 +970,11 @@ class BaseEstimator:
                 break
             batch = _to_device_tree(raw, self.max_id)
             if self.state is None:
-                self._init_state(_merged(batch, self.static_batch))
-                self.restore_checkpoint()
+                self._first_state(_merged(batch, self.static_batch))
                 self._eval_step = self._build_eval_step()
-            loss, metric, _ = self._eval_step(
-                self.state, _merged(batch, self.static_batch))
+            with _calling("eval_step"):
+                loss, metric, _ = self._eval_step(
+                    self.state, _merged(batch, self.static_batch))
             losses.append(float(loss))
             metrics.append(float(metric))
             # masked batches (graph packing / node eval sweeps) report
@@ -958,11 +1008,11 @@ class BaseEstimator:
                 break
             batch = _to_device_tree(raw, self.max_id)
             if self.state is None:
-                self._init_state(_merged(batch, self.static_batch))
-                self.restore_checkpoint()
+                self._first_state(_merged(batch, self.static_batch))
                 self._eval_step = self._build_eval_step()
-            _, _, emb = self._eval_step(
-                self.state, _merged(batch, self.static_batch))
+            with _calling("eval_step"):
+                _, _, emb = self._eval_step(
+                    self.state, _merged(batch, self.static_batch))
             embs.append(np.asarray(emb))
             key = id_key if id_key in raw else ("ids" if "ids" in raw else None)
             if key is not None:

@@ -62,18 +62,13 @@ _LABEL_STREAM = 0x1abe1  # folded into the step's key for the labels' word
 def _neighbor_major(rows, fanouts, encoder: str):
     """The device draw's per-hop ids in `neighbor_major_rows`' order, for
     the encoder that is then told so (`neighbor_major=True`; the
-    attention encoders read no other order). Trace-time counter
-    `neighbor_major_fanout_traces_total{encoder}`."""
+    attention encoders read no other order). Counted at trace time, one
+    a fanout whatever its hops:
+    `traced_paths_total{path="neighbor_major_fanout",detail=<encoder>}`."""
     from euler_tpu import obs
     from euler_tpu.utils.encoders import neighbor_major_rows
 
-    # trace time only: nothing is fetched from the device for it
-    obs.counter(
-        "neighbor_major_fanout_traces_total",
-        "fanout draws re-ordered neighbour-major before the feature "
-        "gather, traced into a program (or run eagerly), one a fanout "
-        "whatever its hops",
-        ("encoder",)).labels(encoder=encoder).inc()
+    obs.traced_path("neighbor_major_fanout", encoder)
     return neighbor_major_rows(rows, fanouts)
 
 
@@ -205,19 +200,15 @@ class _GatherEncode(nn.Module):
         never for a root of this step (its label is what the loss asks
         for, also where the root is drawn again as a neighbour), nor for
         the pad row. The roots' own rows get none. Scopes
-        `labelin/hop<h>`; trace-time counter
-        `label_input_traces_total{hop}`."""
+        `labelin/hop<h>`; counted at trace time, one a hop:
+        `traced_paths_total{path="label_input",detail=<hop>}`."""
         from euler_tpu import obs
 
         emb = nn.Dense(layers[0].shape[-1], use_bias=False,
                        name="label_emb")
         out = [layers[0]]
         for hop in range(1, len(rows)):
-            obs.counter(
-                "label_input_traces_total",
-                "label-row gathers into the model's input traced into a "
-                "program (or run eagerly), one a hop",
-                ("hop",)).labels(hop=str(hop)).inc()
+            obs.traced_path("label_input", hop)
             with jax.named_scope(f"labelin/hop{hop}"):
                 shown = (label_visible(rows[hop], word, self.label_rate)
                          & ~among_roots(rows[hop], roots) & masks[hop])

@@ -13,6 +13,8 @@ surfaces (`RemoteGraphEngine.health()`, `BaseEstimator.health()`,
                chrome://tracing JSON exporter
   server.py    obs.serve(port): /metrics + /healthz on a stdlib
                http.server daemon thread
+  first_calls.py  jax.monitoring's compile-path events booked to the
+               jitted function whose call paid them
 
 Module-level convenience API (the process-global default registry and
 tracer — what the wired layers use)::
@@ -27,12 +29,24 @@ tracer — what the wired layers use)::
 
 Wired out of the box: `graph/remote.py` (per-call spans; retry /
 failover / degrade counters — `health()` is a view over these),
-`estimator/base_estimator.py` (single-step path: `train_step` →
-`device_step` / `hook` / `input_wait`; scanned path, one parent
-`train_dispatch` a window of steps_per_loop → `input_wait` / `stack` /
-`device_step` (the enqueue) / `result_wait` (the host waiting for the
-chip) / `hook`, back to back; histograms estimator_input_wait_ms,
-_device_step_ms, _result_wait_ms, _hook_ms), `estimator/prefetch.py`
+`estimator/base_estimator.py` (one parent `train` a call of est.train,
+estimator_train_call_ms, → the first batch's `input_wait` / `init_state`
+(→ `model_init` / `create_state` / `commit_state`) /
+`restore_checkpoint` / `build_fn` / the steps / `train_finish`;
+single-step path: `train_step` → `device_step` / `hook` / `input_wait`;
+scanned path, one parent `train_dispatch` a window of steps_per_loop →
+`input_wait` / `build_fn` / `stack` / `device_step` (the enqueue) /
+`result_wait` (the host waiting for the chip) / `hook`, back to back;
+histograms estimator_input_wait_ms, _device_step_ms, _result_wait_ms,
+_hook_ms; under whichever of them is open when a jitted function is
+called the first time, `first_call` spans with estimator_compile_ms
+{fn,stage} and estimator_compiles_total{fn,cache}: first_calls.py),
+`parallel/feature_store.py` and `parallel/device_sampler.py` (set-up's
+placement: parents `place_features` / `place_neighbors` → `pad` / `cast`
+/ `quantize` / `detect_uniform_rows` / `store_rows` / `transfer`, each
+an observation in placement_ms{table,stage}; PERF.md section 3 has the
+tree), the models' trace-time branches (`traced_path`:
+traced_paths_total{path,detail}), `estimator/prefetch.py`
 (both feeders, on the producing thread: `feeder_produce` →
 `feeder_transform`; feeder_produce_ms, feeder_queue_depth,
 feeder_batches_total), `parallel/train.py`, `gql.py` (engine-side
@@ -77,10 +91,15 @@ from euler_tpu.obs.server import (  # noqa: F401
 )
 from euler_tpu.obs.trace import NULL_SPAN, Span, Tracer  # noqa: F401
 
+# bounds for set-up's phases, which last seconds where a step's phases
+# last milliseconds: 1 ms .. 131 s
+SETUP_MS_BUCKETS = log2_buckets(1.0, 18)
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Tracer", "Span",
     "ObsServer", "default_registry", "default_tracer", "counter", "gauge",
-    "histogram", "span", "timed_span", "serve", "snapshot",
+    "histogram", "span", "timed_span", "record_span", "traced_path",
+    "SETUP_MS_BUCKETS", "serve", "snapshot",
     "snapshot_delta", "render_prometheus", "dump_trace", "clear_trace",
     "enable", "disable", "enabled", "install_profiler_annotation",
     "register_health",
@@ -174,6 +193,28 @@ def timed_span(name: str, hist, **attrs) -> _TimedSpan:
     on the default tracer whose wall time also lands in `hist` (in ms),
     success or raise."""
     return _TimedSpan(span(name, **attrs), hist)
+
+
+def record_span(name: str, dur_s: float, **attrs) -> None:
+    """A finished span that ended now and lasted `dur_s`, timed by
+    somebody else (first_calls.py: jax.monitoring's durations); nothing
+    when tracing is disabled."""
+    if _enabled:
+        default_tracer().record(name, dur_s, **attrs)
+
+
+def traced_path(path: str, detail="", n: int = 1) -> None:
+    """Count a branch taken while a program is TRACED (or run eagerly):
+    traced_paths_total{path,detail} says which form of a layer went into
+    the compiled step without fetching anything from the device. Tests
+    read it; `path` names the branch, `detail` the layer, hop, table or
+    collection it was taken for."""
+    counter(
+        "traced_paths_total",
+        "branches of the models, the draw and the guard taken while a "
+        "program was traced (or run eagerly), by branch and by the layer, "
+        "hop, table, encoder or collection it was taken for",
+        ("path", "detail")).labels(path=path, detail=str(detail)).inc(n)
 
 
 def dump_trace(path: str) -> str:

@@ -172,6 +172,25 @@ class Tracer:
             return NULL_SPAN
         return Span(self, name, attrs)
 
+    def record(self, name: str, dur_s: float, **attrs) -> None:
+        """A span somebody else timed (a jax.monitoring duration): it
+        ended now, lasted `dur_s`, and is a child of this thread's
+        current span. Ring only: the profiler's sink cannot be entered
+        in the past."""
+        if not self.enabled:
+            return
+        sp = Span(self, name, attrs)
+        sp.dur_us = dur_s * 1e6
+        sp.ts_us = (time.perf_counter() - self._epoch) * 1e6 - sp.dur_us
+        sp.tid = threading.get_ident()
+        stack = self._stack()
+        if stack:
+            sp.parent_id = stack[-1].span_id
+            sp.trace_id = stack[-1].trace_id
+        else:
+            sp.trace_id = self._trace_base + next(self._trace_ids)
+        self._record(sp)
+
     def current_span(self):
         """Innermost active span on THIS thread (None outside any)."""
         st = self._stack()

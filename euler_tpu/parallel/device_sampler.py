@@ -46,6 +46,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu.parallel.placement import (
+    placement_stage as _stage, put_replicated, put_row_sharded,
+)
+
 
 class DeviceNeighborTable:
     """Builds the HBM neighbor/cum-weight tables from a graph engine.
@@ -88,18 +92,23 @@ class DeviceNeighborTable:
         n = len(ids)
         self.cap = int(cap)
         self.pad_row = n
-        offs, nbrs, ws, _ = graph.get_full_neighbor(ids, edge_types)
-        offs = offs.astype(np.int64)
-        deg = np.diff(offs)
-        nbr_rows = graph.node_rows(nbrs, missing=n).astype(np.int32)
-        del nbrs
-        ws = ws.astype(np.float32)
-        nbr_tab, cum, alias_tab = self._build_tables(
-            n, deg, nbr_rows, ws, seed)
-        # host copies are opt-in (cache writers like bench): pinning them
-        # by default would double host RAM for every training caller
-        self.host_tables = (nbr_tab, cum) if keep_host else None
-        self._place(nbr_tab, cum, mesh, alias_tab)
+        with _stage("place_neighbors", "neighbors", rows=n + 1,
+                    row_bytes=4 * self.cap, shard_rows=self.shard_rows):
+            with _stage("read_graph", "neighbors"):
+                offs, nbrs, ws, _ = graph.get_full_neighbor(ids, edge_types)
+                offs = offs.astype(np.int64)
+                deg = np.diff(offs)
+                nbr_rows = graph.node_rows(nbrs, missing=n).astype(np.int32)
+                del nbrs
+                ws = ws.astype(np.float32)
+            with _stage("build_tables", "neighbors"):
+                nbr_tab, cum, alias_tab = self._build_tables(
+                    n, deg, nbr_rows, ws, seed)
+            # host copies are opt-in (cache writers like bench): pinning
+            # them by default would double host RAM for every training
+            # caller
+            self.host_tables = (nbr_tab, cum) if keep_host else None
+            self._place(nbr_tab, cum, mesh, alias_tab)
 
     @classmethod
     def from_arrays(cls, nbr_tab: np.ndarray, cum_tab: np.ndarray,
@@ -126,33 +135,40 @@ class DeviceNeighborTable:
         self.pad_row = int(nbr_tab.shape[0]) - 1
         for k in ("hub_frac", "edge_keep_frac", "max_degree"):
             setattr(self, k, (stats or {}).get(k))
-        # caches written before the round-5 uniform lever carry no
-        # uniform_rows stat — recompute from the tables (the slot
-        # weights are exactly recoverable from the inclusive cumsum).
-        # Chunked: a full-table astype + diff would hold two ~3.5GB
-        # transients at products scale (advisor r5)
-        u = (stats or {}).get("uniform_rows")
-        if u is None:
-            u = True
-            pad = self.pad_row
-            for lo in range(0, cum_tab.shape[0], _CHUNK_ROWS):
-                cc = np.asarray(cum_tab[lo:lo + _CHUNK_ROWS]) \
-                    .astype(np.float32, copy=False)
-                w = np.diff(cc, axis=1,
-                            prepend=np.zeros((cc.shape[0], 1),
-                                             np.float32))
-                if not _detect_uniform_rows(
-                        np.asarray(nbr_tab[lo:lo + _CHUNK_ROWS]), w,
-                        pad=pad):
-                    u = False
-                    break
-        self.uniform_rows = bool(u)
-        self.host_tables = None
-        alias_tab = build_alias_tables(
-            np.asarray(nbr_tab), cum_tab=np.asarray(cum_tab)) \
-            if self.alias else None
-        self._place(np.ascontiguousarray(nbr_tab),
-                    np.ascontiguousarray(cum_tab), mesh, alias_tab)
+        with _stage("place_neighbors", "neighbors", rows=self.pad_row + 1,
+                    row_bytes=4 * self.cap, shard_rows=self.shard_rows):
+            # caches written before the round-5 uniform lever carry no
+            # uniform_rows stat — recompute from the tables (the slot
+            # weights are exactly recoverable from the inclusive cumsum).
+            # Chunked: a full-table astype + diff would hold two ~3.5GB
+            # transients at products scale (advisor r5)
+            u = (stats or {}).get("uniform_rows")
+            if u is None:
+                with _stage("detect_uniform_rows", "neighbors"):
+                    u = True
+                    pad = self.pad_row
+                    for lo in range(0, cum_tab.shape[0], _CHUNK_ROWS):
+                        cc = np.asarray(cum_tab[lo:lo + _CHUNK_ROWS]) \
+                            .astype(np.float32, copy=False)
+                        w = np.diff(cc, axis=1,
+                                    prepend=np.zeros((cc.shape[0], 1),
+                                                     np.float32))
+                        if not _detect_uniform_rows(
+                                np.asarray(nbr_tab[lo:lo + _CHUNK_ROWS]), w,
+                                pad=pad):
+                            u = False
+                            break
+            self.uniform_rows = bool(u)
+            self.host_tables = None
+            alias_tab = None
+            if self.alias:
+                with _stage("build_alias", "neighbors"):
+                    alias_tab = build_alias_tables(
+                        np.asarray(nbr_tab), cum_tab=np.asarray(cum_tab))
+            with _stage("cast", "neighbors"):
+                nbr_tab = np.ascontiguousarray(nbr_tab)
+                cum_tab = np.ascontiguousarray(cum_tab)
+            self._place(nbr_tab, cum_tab, mesh, alias_tab)
         return self
 
     def _build_tables(self, n, deg, nbr_rows, ws, seed):
@@ -179,9 +195,16 @@ class DeviceNeighborTable:
         return nbr_tab, cum, alias_tab
 
     def _place(self, nbr_tab, cum, mesh, alias_tab=None):
-        from euler_tpu.parallel.placement import (
-            put_replicated, put_row_sharded,
-        )
+        """The tables' stored form and its transfer, table by table, each
+        under its span (placement.placement_stage: `store_rows`,
+        `transfer`; `fuse` in fused mode). Every transfer is left in
+        flight: the span holds the enqueue, and the first step that
+        draws from the table waits for it."""
+        def place(tab, table, put):
+            with _stage("store_rows", table):
+                stored = store_rows(tab, table)
+            with _stage("transfer", table):
+                return put(stored, mesh)
 
         if getattr(self, "fused", False):
             # one [N+1, 2C] i32 table (ids + bitcast cum): one row gather
@@ -193,19 +216,18 @@ class DeviceNeighborTable:
             # shard contributes the bits, all others contribute i32
             # zeros), so the HBM-capacity lever and the gather-count
             # lever stack.
-            fused_tab = fuse_tables_host(nbr_tab, cum)
-            if self.shard_rows:
-                self.fused_table = put_row_sharded(fused_tab, mesh)
-            else:
-                self.fused_table = put_replicated(fused_tab, mesh)
+            put = put_row_sharded if self.shard_rows else put_replicated
+            with _stage("fuse", "nbrcum"):
+                fused_tab = fuse_tables_host(nbr_tab, cum)
+            with _stage("transfer", "nbrcum"):
+                self.fused_table = put(fused_tab, mesh)
             self.neighbors = None
             self.cum_weights = None
         else:
             put = put_row_sharded if self.shard_rows else put_replicated
-            self.neighbors = put(store_rows(nbr_tab, "nbr"), mesh)
-            self.cum_weights = put(store_rows(cum, "cum"), mesh)
-        self.alias_table = \
-            put_replicated(store_rows(alias_tab, "alias"), mesh) \
+            self.neighbors = place(nbr_tab, "nbr", put)
+            self.cum_weights = place(cum, "cum", put)
+        self.alias_table = place(alias_tab, "alias", put_replicated) \
             if alias_tab is not None else None
 
     @property
@@ -765,7 +787,8 @@ def take_rows(stored: jax.Array, rows: jax.Array, table: str,
     tested and filled (a second pass over the gathered bytes). gather
     (make_table_gather) routes the read of a row-sharded table: its
     masked take + psum is exact on bytes (the owner's plus zeros).
-    Counted at trace time: table_rows_stored_traces_total{table}."""
+    Counted at trace time:
+    traced_paths_total{path="table_rows_stored",detail=<table>}."""
     from euler_tpu import obs
 
     if stored.dtype != jnp.int8:
@@ -773,11 +796,7 @@ def take_rows(stored: jax.Array, rows: jax.Array, table: str,
             f"take_rows reads a STORED table (store_rows: int8 "
             f"[N+1, 4C]), got {stored.dtype}{list(stored.shape)}: a "
             "logical [N+1, C] table would be read as garbage")
-    obs.counter(
-        "table_rows_stored_traces_total",
-        "row reads of a stored (byte-plane) neighbour / cumulative-"
-        "weight / alias table traced into a program (or run eagerly)",
-        ("table",)).labels(table=table).inc()
+    obs.traced_path("table_rows_stored", table)
     if gather is None:
         got = jnp.take(stored, rows, axis=0, mode="clip")  # [n, 4C] i8
     else:

@@ -35,6 +35,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu.parallel.placement import (
+    placement_stage as _stage, put_replicated, put_row_sharded,
+)
+
 
 # float32 elements a quantisation chunk holds (16 MB: it stays in cache
 # between its passes) and the threads that work through the chunks: the
@@ -55,8 +59,9 @@ def quantize_int8(feats: np.ndarray):
     handed (each chunk cast to float32 on its thread): the column maxima
     first, then the rounded quotient chunk by chunk into the int8 table.
     Nothing table-sized is made but the result, at any row width; the
-    bytes are those of the one-pass arithmetic. Span `quantize`, counter
-    `quantize_chunks_total` (chunks a pass, both passes)."""
+    bytes are those of the one-pass arithmetic. Span `quantize` (a stage
+    of the feature table's placement: placement_ms{features,quantize}),
+    counter `quantize_chunks_total` (chunks a pass, both passes)."""
     from euler_tpu import obs
 
     rows, dim = feats.shape
@@ -74,7 +79,7 @@ def quantize_int8(feats: np.ndarray):
     def fill(lo):
         q[lo:lo + step] = np.clip(np.rint(chunk(lo) / scale), -127, 127)
 
-    with obs.span("quantize", rows=rows, dim=dim), \
+    with _stage("quantize", "features", rows=rows, dim=dim), \
             ThreadPoolExecutor(_QUANT_THREADS) as pool:
         tops = list(pool.map(lambda lo: np.abs(chunk(lo)).max(axis=0),
                              starts))
@@ -125,39 +130,74 @@ class DeviceFeatureStore:
         # pads gather zeros, matching GetDenseFeature's unknown-id
         # behavior on the host path
         self.pad_row = len(ids)
-        feats = graph.get_dense_feature(ids, list(feature_ids))
-        if isinstance(feats, list):
-            feats = np.concatenate(feats, axis=1)
-        feats = np.concatenate(
-            [feats, np.zeros((1, feats.shape[1]), feats.dtype)])
-        feats = feats.astype(np.dtype(dtype), copy=False)
-        from euler_tpu.parallel.placement import (
-            put_replicated, put_row_sharded,
-        )
-
-        put = (lambda x: put_row_sharded(x, mesh)) if shard_rows else \
-            (lambda x: put_replicated(x, mesh))
-        self.feature_scale = None
-        if quantize == "int8":
-            q, scale = quantize_int8(feats)
-            self.features = put(q)
-            self.feature_scale = put_replicated(
-                scale.astype(np.dtype(dtype), copy=False), mesh)
-        elif quantize is not None:
-            raise ValueError(f"unknown quantize mode {quantize!r}")
-        else:
-            self.features = put(feats)
-        self.labels = None
-        labels = None
-        if label_fid is not None:
-            labels = graph.get_dense_feature(ids, label_fid, label_dim)
-            labels = np.concatenate(
-                [labels, np.zeros((1, labels.shape[1]), labels.dtype)])
-            labels = labels.astype(np.float32, copy=False)
-            self.labels = put(labels)
+        with _stage("place_features", "features", rows=len(ids) + 1,
+                    shard_rows=self.shard_rows) as parent:
+            with _stage("read_graph", "features"):
+                feats = graph.get_dense_feature(ids, list(feature_ids))
+                if isinstance(feats, list):
+                    feats = np.concatenate(feats, axis=1)
+                feats = np.concatenate(
+                    [feats, np.zeros((1, feats.shape[1]), feats.dtype)])
+            with _stage("cast", "features"):
+                feats = feats.astype(np.dtype(dtype), copy=False)
+            self._place_features(parent, feats, quantize, dtype, wait=False)
+            labels = None
+            if label_fid is not None:
+                with _stage("read_graph", "labels"):
+                    labels = graph.get_dense_feature(ids, label_fid,
+                                                     label_dim)
+                    labels = np.concatenate(
+                        [labels,
+                         np.zeros((1, labels.shape[1]), labels.dtype)])
+            labels = self._place_labels(parent, labels)
         # host copies are opt-in (cache writers like bench): pinning them
         # by default would double host RAM for every training caller
         self.host_arrays = (feats, labels) if keep_host else None
+
+    def _put(self, x):
+        put = put_row_sharded if self.shard_rows else put_replicated
+        return put(x, self.mesh)
+
+    def _place_features(self, parent, features, quantize, scale_dtype,
+                        wait: bool) -> None:
+        """The feature table's way to the device, for both constructors,
+        each stage under its span (placement.placement_stage): `quantize`
+        or `cast`, `transfer` of the table and of its scale. `parent`, the
+        constructor's own span, is told the row bytes as stored. A
+        transfer is left in flight (its span holds the enqueue; the first
+        step that reads the table waits for it) unless `wait`."""
+        self.feature_scale = None
+        if quantize == "int8":
+            features, scale = quantize_int8(features)
+        elif quantize is not None:
+            raise ValueError(f"unknown quantize mode {quantize!r}")
+        else:
+            with _stage("cast", "features"):
+                features = np.ascontiguousarray(features)
+        with _stage("transfer", "features"):
+            self.features = self._put(features)
+            if wait:
+                jax.block_until_ready(self.features)
+        parent.set(row_bytes=features.shape[1] * features.dtype.itemsize)
+        if quantize == "int8":
+            with _stage("transfer", "scale"):
+                self.feature_scale = put_replicated(
+                    scale.astype(np.dtype(scale_dtype), copy=False),
+                    self.mesh)
+
+    def _place_labels(self, parent, labels):
+        """The label table, float32, left in flight; returns the host
+        array that was sent (None without labels)."""
+        self.labels = None
+        if labels is None:
+            return None
+        with _stage("cast", "labels"):
+            labels = np.ascontiguousarray(
+                labels.astype(np.float32, copy=False))
+        with _stage("transfer", "labels"):
+            self.labels = self._put(labels)
+        parent.set(label_row_bytes=labels.shape[1] * 4)
+        return labels
 
     @classmethod
     def from_arrays(cls, features: np.ndarray,
@@ -189,37 +229,22 @@ class DeviceFeatureStore:
         self._sorted_ids = ids is not None
         self.shard_rows = bool(shard_rows)
         self.mesh = mesh
-        from euler_tpu.parallel.placement import (
-            put_replicated, put_row_sharded,
-        )
-
-        put = (lambda x: put_row_sharded(x, mesh)) if shard_rows else \
-            (lambda x: put_replicated(x, mesh))
-        if pad_dim_to is not None and features.shape[1] < pad_dim_to:
-            features = np.concatenate(
-                [features,
-                 np.zeros((features.shape[0],
-                           pad_dim_to - features.shape[1]),
-                          features.dtype)], axis=1)
-        self.feature_scale = None
-        if quantize == "int8":
-            q, scale = quantize_int8(features)
+        with _stage("place_features", "features", rows=features.shape[0],
+                    shard_rows=self.shard_rows) as parent:
+            if pad_dim_to is not None and features.shape[1] < pad_dim_to:
+                with _stage("pad", "features"):
+                    features = np.concatenate(
+                        [features,
+                         np.zeros((features.shape[0],
+                                   pad_dim_to - features.shape[1]),
+                                  features.dtype)], axis=1)
             # the host's int8 table and its transfer buffer go before the
             # labels' come: left to overlap, a 768-wide share's placement
             # held both tables twice beside the caller's own copies and
             # met a 40 GiB host's limit (PERF.md, PR 32)
-            self.features = jax.block_until_ready(put(q))
-            del q
-            self.feature_scale = put_replicated(
-                scale.astype(np.dtype(scale_dtype), copy=False), mesh)
-        elif quantize is not None:
-            raise ValueError(f"unknown quantize mode {quantize!r}")
-        else:
-            self.features = put(np.ascontiguousarray(features))
-        self.labels = None
-        if labels is not None:
-            self.labels = put(
-                np.ascontiguousarray(labels.astype(np.float32, copy=False)))
+            self._place_features(parent, features, quantize, scale_dtype,
+                                 wait=quantize == "int8")
+            self._place_labels(parent, labels)
         return self
 
     @property

@@ -12,6 +12,10 @@ Two policies, one helper module so the table classes cannot diverge:
   axis, and gathers become a masked local take + psum over 'model'
   (device_sampler.make_table_gather). Right when the graph outgrows one
   chip.
+
+`placement_stage` times what the two classes do to a table on its way to
+the device: a span and an observation in placement_ms{table,stage}, so
+that set-up can be split from inside the program (PERF.md section 3).
 """
 
 from __future__ import annotations
@@ -20,6 +24,25 @@ from typing import Optional
 
 import jax
 import numpy as np
+
+
+def placement_stage(stage: str, table: str, **attrs):
+    """`with placement_stage("transfer", "nbr"):` around one stage of a
+    table's placement: span `stage` (attribute `table`, and `attrs`) and
+    its length in placement_ms{table,stage}. A constructor's parent span
+    (`place_features`, `place_neighbors`) is a stage under its own name.
+    The span times what the host thread does: a transfer the code leaves
+    in flight is timed as its enqueue."""
+    from euler_tpu import obs
+
+    hist = obs.histogram(
+        "placement_ms",
+        "host time of each stage of placing a table on the device (pad, "
+        "cast, quantize, detect_uniform_rows, store_rows, transfer) and, "
+        "under the constructor's own name, of the whole of it",
+        ("table", "stage"), buckets=obs.SETUP_MS_BUCKETS)
+    return obs.timed_span(stage, hist.labels(table=table, stage=stage),
+                          table=table, **attrs)
 
 
 def _global_put(x: np.ndarray, sharding) -> jax.Array:

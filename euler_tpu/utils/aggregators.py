@@ -45,19 +45,14 @@ def _over_slots(reduce: Callable, nbr: Parts, axis: int,
                 aggregator: str) -> Array:
     """`reduce(nbr, axis=axis)` for a reduction that acts lane by lane:
     a tuple of lane parts is reduced part by part and the small results
-    concatenated. Trace-time counter
-    `sage_hop_parts_traces_total{aggregator}`."""
+    concatenated. Counted at trace time, one a depth whose deepest hop
+    stayed in its lane parts:
+    `traced_paths_total{path="sage_hop_parts",detail=<aggregator>}`."""
     if not isinstance(nbr, tuple):
         return reduce(nbr, axis=axis)
     from euler_tpu import obs
 
-    # trace time only: nothing is fetched from the device for it
-    obs.counter(
-        "sage_hop_parts_traces_total",
-        "depths of a fanout encoder whose deepest hop stayed in its "
-        "lane parts and was reduced over its slots part by part, traced "
-        "into a program (or run eagerly)",
-        ("aggregator",)).labels(aggregator=aggregator).inc()
+    obs.traced_path("sage_hop_parts", aggregator)
     return jnp.concatenate([reduce(p, axis=axis) for p in nbr], axis=-1)
 
 

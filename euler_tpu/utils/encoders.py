@@ -289,8 +289,9 @@ class HopBatchNorm(nn.Module):
     mean <- (1 - m) mean + m mu, var <- (1 - m) var + m var n / (n - 1);
     the running ones where it is not (`evaluate`, `infer`, a served
     bundle). The gain is stored as its OFFSET g from one, as
-    `OffsetLayerNorm`'s. Trace-time counter
-    `batch_norm_traces_total{layer}`."""
+    `OffsetLayerNorm`'s. Counted at trace time, one a norm whatever
+    the hops it spans:
+    `traced_paths_total{path="batch_norm",detail=<layer>}`."""
 
     MOMENTUM = 0.1      # PyTorch's defaults, the published model's
     EPSILON = 1e-5
@@ -307,12 +308,7 @@ class HopBatchNorm(nn.Module):
                              lambda: jnp.zeros((d,), jnp.float32))
         var = self.variable("batch_stats", "var",
                             lambda: jnp.ones((d,), jnp.float32))
-        # trace time only: nothing is fetched from the device for it
-        obs.counter(
-            "batch_norm_traces_total",
-            "batch normalisations traced into a program (or run eagerly), "
-            "one a norm whatever the hops it spans",
-            ("layer",)).labels(layer="/".join(self.path[-2:-1])).inc()
+        obs.traced_path("batch_norm", "/".join(self.path[-2:-1]))
         if self.is_mutable_collection("batch_stats"):
             weights = [None if m is None else m.astype(x.dtype)[:, None]
                        for x, m in zip(xs, masks)]
@@ -353,8 +349,8 @@ class GATLayer(nn.Module):
     heads are concatenated, the sum y_i + x_i S + s passes a
     `HopBatchNorm` before its ELU, ONE for the layer: its statistics span
     every hop the layer writes (OGB-LSC's MAG240M baseline). Scopes `proj`, `attn`, `skip` (and `norm`) under
-    the layer's name; trace-time counter
-    `gat_attention_traces_total{layer}`."""
+    the layer's name; counted at trace time, one a layer whatever its
+    hops: `traced_paths_total{path="gat_attention",detail=<layer>}`."""
 
     width: int          # C, one head's
     heads: int
@@ -377,12 +373,7 @@ class GATLayer(nn.Module):
              for name in ("att_src", "att_dst")], axis=1)     # [H*C, 2H]
         bias = self.param("bias", nn.initializers.zeros, (out_dim,))
         skip = nn.Dense(out_dim, name="skip")
-        # trace time only: nothing is fetched from the device for it
-        obs.counter(
-            "gat_attention_traces_total",
-            "graph-attention layers traced into a program (or run "
-            "eagerly), one a layer whatever its hops",
-            ("layer",)).labels(layer=self.name or "").inc()
+        obs.traced_path("gat_attention", self.name or "")
         with jax.named_scope("attn"):
             # [2H, n] a hop, n minor: a_src . z then a_dst . z, every head
             scores = [jnp.einsum("fg,nf->gn", att, z) for z in zs]
@@ -522,8 +513,8 @@ class TransformerConvLayer(nn.Module):
     C lanes are matrix products with the heads' 0/1 indicator, so the
     softmax runs with M on the lanes whatever C is. Scopes `qkv` (the
     four projections), `attn`, `gate` (gate, norm, ReLU) under the
-    layer's name; trace-time counter
-    `unimp_attention_traces_total{layer}`."""
+    layer's name; counted at trace time, one a layer whatever its hops:
+    `traced_paths_total{path="unimp_attention",detail=<layer>}`."""
 
     width: int          # C, one head's
     heads: int
@@ -541,12 +532,7 @@ class TransformerConvLayer(nn.Module):
         skip = nn.Dense(out_dim, name="skip")
         beta = nn.Dense(1, use_bias=False, name="beta")
         norm = OffsetLayerNorm(name="norm") if self.concat else None
-        # trace time only: nothing is fetched from the device for it
-        obs.counter(
-            "unimp_attention_traces_total",
-            "graph-transformer layers traced into a program (or run "
-            "eagerly), one a layer whatever its hops",
-            ("layer",)).labels(layer=self.name or "").inc()
+        obs.traced_path("unimp_attention", self.name or "")
         with jax.named_scope("qkv"):
             targets = [(query(x), skip(x)) for x in hidden[:-1]]
             sources = [(key(x), value(x)) for x in hidden[1:]]
@@ -754,14 +740,11 @@ def _store_then_neighbors(enc: nn.Module, layer: int, ids: Array,
     store = _ScalableCache(enc.max_id, enc.dim, dtype=enc.cache_dtype,
                            decay=enc.store_decay, name=f"cache_{layer}")
     if enc.is_mutable_collection("cache"):
-        # trace time only: nothing is fetched from the device for it
+        # traced_paths_total{path="act_cache_fused",detail=<encoder>}:
+        # one a cache layer whose write and read are one operation
         from euler_tpu import obs
 
-        obs.counter(
-            "act_cache_fused_traces_total",
-            "fused activation-cache write-then-read operations traced "
-            "into a program (or run eagerly), one a cache layer",
-            ("encoder",)).labels(encoder=type(enc).__name__).inc()
+        obs.traced_path("act_cache_fused", type(enc).__name__)
     with jax.named_scope("cache"):
         return store(ids, fresh, nbr_ids)
 

@@ -395,8 +395,9 @@ def test_save_and_restore_round_trip_the_running_statistics(program,
 
 
 def test_one_count_a_norm_a_trace():
-    counter = obs.counter("batch_norm_traces_total", "", ("layer",))
-    before = counter.labels(layer="").value
+    counter = obs.counter("traced_paths_total", "", ("path", "detail")).labels(
+        path="batch_norm", detail="")
+    before = counter.value
     xs = _hops()
     norm = HopBatchNorm()
     variables = norm.init(jax.random.key(0), xs, [None, None])
@@ -404,7 +405,7 @@ def test_one_count_a_norm_a_trace():
                                           mutable=[STATS]))
     fn(variables, xs)
     fn(variables, xs)                   # cached: no new trace, no count
-    assert counter.labels(layer="").value - before == 2   # init, the jit
+    assert counter.value - before == 2   # init, the jit
 
 
 # -- the chunked quantisation ------------------------------------------------

@@ -315,10 +315,9 @@ def test_mean_encoder_views_its_hops_without_a_relayout(one_chip):
     params = _with_sharding(
         jax.eval_shape(enc.init, jax.random.key(0), table, scale, rows),
         one_chip)
-    counter = obs.counter("neighbor_major_fanout_traces_total", "",
-                          ("encoder",)).labels(encoder="sage")
-    parts = obs.counter("sage_hop_parts_traces_total", "",
-                        ("aggregator",)).labels(aggregator="mean")
+    paths = obs.counter("traced_paths_total", "", ("path", "detail"))
+    counter = paths.labels(path="neighbor_major_fanout", detail="sage")
+    parts = paths.labels(path="sage_hop_parts", detail="mean")
     count, parts_count = counter.value, parts.value
     text = jax.jit(jax.value_and_grad(
         lambda p, t, s, r: enc.apply(p, t, s, r).sum())).lower(

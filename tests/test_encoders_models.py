@@ -279,8 +279,9 @@ def test_act_cache_gradient_builds_nothing_table_sized(kind):
     from euler_tpu import obs
 
     name = ENCODERS[kind].__name__
-    counter = obs.counter("act_cache_fused_traces_total",
-                          labelnames=("encoder",)).labels(encoder=name)
+    counter = obs.counter(
+        "traced_paths_total", labelnames=("path", "detail")).labels(
+            path="act_cache_fused", detail=name)
     for num_layers in (2, 3):
         fused, _, params, caches = _cache_losses(kind, jnp.bfloat16,
                                                  num_layers)
@@ -519,9 +520,9 @@ def test_guard_cond_never_holds_the_cache(kind, tmp_path):
     num_layers = 3
     est = _guard_estimator(kind, jnp.bfloat16, num_layers,
                            model_dir=str(tmp_path), steps_per_loop=2)
-    counter = obs.counter("guard_row_rollback_traces_total",
-                          labelnames=("collection",)).labels(
-                              collection="cache")
+    counter = obs.counter(
+        "traced_paths_total", labelnames=("path", "detail")).labels(
+            path="guard_row_rollback", detail="cache")
     stacked = jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs), _guard_batch(1), _guard_batch(2))
     before = counter.value
@@ -577,9 +578,9 @@ def test_guard_without_a_mutable_collection_traces_as_before():
              if k in ("x", "labels")}
     est._init_state(batch)
     assert est.state.extra_vars == {}
-    counter = obs.counter("guard_row_rollback_traces_total",
-                          labelnames=("collection",)).labels(
-                              collection="cache")
+    counter = obs.counter(
+        "traced_paths_total", labelnames=("path", "detail")).labels(
+            path="guard_row_rollback", detail="cache")
     before = counter.value
     now = jax.make_jaxpr(est._make_one_step())(est.state, batch)
     was = jax.make_jaxpr(_parent_one_step(est))(est.state, batch)

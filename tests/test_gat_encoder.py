@@ -256,10 +256,10 @@ def test_the_logits_hook_leaves_the_mean_models_parameters_as_they_were():
 
 
 def test_one_count_a_layer_a_trace_and_the_encoders_named():
-    counter = obs.counter("gat_attention_traces_total", "", ("layer",))
+    counter = obs.counter("traced_paths_total", "", ("path", "detail"))
 
     def count():
-        return {k: counter.labels(layer=k).value
+        return {k: counter.labels(path="gat_attention", detail=k).value
                 for k in ("layer0", "layer1")}
 
     enc = GATEncoder(4, (3, 2), heads=2, out_dim=5)

@@ -44,8 +44,8 @@ def _filled(shapes, seed):
 
 def _reorders():
     """The trace-time count of `sage` fanouts put neighbour-major."""
-    return obs.counter("neighbor_major_fanout_traces_total", "",
-                       ("encoder",)).labels(encoder="sage").value
+    return obs.counter("traced_paths_total", "", ("path", "detail")).labels(
+        path="neighbor_major_fanout", detail="sage").value
 
 
 @pytest.fixture(scope="module")

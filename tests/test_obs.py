@@ -783,9 +783,13 @@ def test_scanned_dispatch_is_one_parent_fully_covered():
     covered = sum(s.dur_us for s in kids)
     assert covered >= 0.95 * parent.dur_us, (covered, parent.dur_us)
     assert covered <= parent.dur_us
-    # the first batch's wait is a span of the same thread, before it
+    # the first batch's wait is a span of the same thread, before it;
+    # both are children of the call's one `train` span (PR 34)
+    (call,) = [s for s in spans if s.name == "train"]
+    assert parent.parent_id == call.span_id and call.parent_id == 0
+    assert call.attrs["max_steps"] == 8
     first = [s for s in spans if s.name == "input_wait"
-             and s.parent_id == 0]
+             and s.parent_id == call.span_id]
     assert len(first) == 1 and first[0].tid == parent.tid
     assert first[0].ts_us + first[0].dur_us <= parent.ts_us
     assert est._hist_result_wait.value["count"] - rw0 == 1

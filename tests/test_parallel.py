@@ -2030,11 +2030,10 @@ def test_draws_through_the_stored_tables_equal_a_plain_row_read(
                 tables, roots)
         return [np.asarray(x) for x in jax.tree_util.tree_leaves(out)]
 
-    before = obs.counter("table_rows_stored_traces_total", "",
-                         ("table",)).labels(table="nbr").value
+    stored = obs.counter("traced_paths_total", "", ("path", "detail")).labels(path="table_rows_stored", detail="nbr")
+    before = stored.value
     got = run()
-    assert obs.counter("table_rows_stored_traces_total", "",
-                       ("table",)).labels(table="nbr").value > before
+    assert stored.value > before
     plain = _plain_row_read(logical)
     for mod in (device_sampler, device_walk, device_layerwise):
         monkeypatch.setattr(mod, "take_rows", plain)

@@ -47,8 +47,8 @@ class ConcatEveryHop(nn.Module):
 
 def _parts(aggregator):
     """The trace-time count of depths reduced part by part."""
-    return obs.counter("sage_hop_parts_traces_total", "",
-                       ("aggregator",)).labels(aggregator=aggregator).value
+    return obs.counter("traced_paths_total", "", ("path", "detail")).labels(
+        path="sage_hop_parts", detail=aggregator).value
 
 
 def _value_and_grads(enc, params, layers):

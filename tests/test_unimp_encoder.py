@@ -403,13 +403,13 @@ def test_a_roots_own_label_never_reaches_its_logits():
 
 
 def test_one_count_a_layer_and_a_hop_a_trace_and_the_encoders_named():
-    layers = obs.counter("unimp_attention_traces_total", "", ("layer",))
-    hops = obs.counter("label_input_traces_total", "", ("hop",))
+    paths = obs.counter("traced_paths_total", "", ("path", "detail"))
 
     def count():
-        return ({k: layers.labels(layer=k).value
+        return ({k: paths.labels(path="unimp_attention", detail=k).value
                  for k in ("layer0", "layer1")},
-                {k: hops.labels(hop=k).value for k in ("1", "2")})
+                {k: paths.labels(path="label_input", detail=k).value
+                 for k in ("1", "2")})
 
     enc = UniMPEncoder(4, (3, 2), heads=2, out_dim=5)
     xs = [jnp.ones((2, 6)), jnp.ones((6, 6)), jnp.ones((12, 6))]
