@@ -82,6 +82,15 @@ def _to_device_tree(batch: Dict, max_id: int = 0) -> Dict:
     return jax.tree_util.tree_map(conv, batch)
 
 
+# a leaf of K device-resident batches stacked by ONE dispatch. Eager
+# jnp.stack is K reshapes and a concatenate, K + 1 dispatches a leaf, and
+# the runtime holds a dispatch back while too many are in flight: called
+# while a scanned window runs, it came back only when the window was done,
+# and its operations then ran with the device idle (~10 ms a window on the
+# chip, PERF.md, PR 35)
+_stack_on_device = jax.jit(lambda *xs: jnp.stack(xs))
+
+
 def _merged(batch: Dict, static_batch: Dict) -> Dict:
     return {**batch, **static_batch} if static_batch else batch
 
@@ -202,6 +211,10 @@ class BaseEstimator:
                 "remote leg for the hub cache to absorb")
         self._live_feeder = None
         self._input_factory = None
+        # what the last train call took from a caller's iterator and did
+        # not train on: (the iterator, its raw batches in order, their
+        # stacked window if one was made, whether the stream ended)
+        self._ahead = None
         # input-path counters live on the obs registry (children labeled
         # by estimator instance); input_health / health() are VIEWS over
         # them — the same numbers a /metrics scrape reports
@@ -218,6 +231,15 @@ class BaseEstimator:
             "estimator_skipped_batches_total",
             "input batches abandoned under skip_batch_budget",
             ("estimator",)).labels(**lab)
+        batches = reg.counter(
+            "estimator_window_batches_total",
+            "batches a train window consumed, by how it got them: ahead "
+            "(in hand before the window began: read while the device ran "
+            "the window before, or carried from the last call on the same "
+            "iterator) or waited (pulled with nothing in flight)",
+            ("estimator", "how"))
+        self._ctr_batches_ahead = batches.labels(how="ahead", **lab)
+        self._ctr_batches_waited = batches.labels(how="waited", **lab)
         self._hist_input_wait = reg.histogram(
             "estimator_input_wait_ms",
             "per-step host wait for the next batch (sampling + RPC + "
@@ -691,9 +713,29 @@ class BaseEstimator:
                             pass
                     it = self._input_factory()
 
+    def _pull(self, it, buf) -> Iterator:
+        """One more batch from `it` (through `_next_input`) onto `buf`, as
+        the device takes it; returns the iterator, which a retry may have
+        made anew."""
+        raw, it = self._next_input(it)
+        buf.append(_to_device_tree(raw, self.max_id))
+        return it
+
     # -- drivers -----------------------------------------------------------
     def train(self, input_fn: Callable[[], Iterator[Dict]],
               max_steps: int = 1000) -> Dict[str, float]:
+        """Train until the global step reaches `max_steps`; step s trains
+        on the s-th batch the input gave.
+
+        `input_fn` is a callable that makes the iterator, or an iterator.
+        With steps_per_loop > 1 the next window's batches are read, and
+        stacked, while the device runs the current one. A callable's
+        iterator dies with the call, so exactly the batches the call
+        trains on are taken from it. An ITERATOR is the caller's and
+        outlives the call: up to steps_per_loop batches may be taken from
+        it beyond `max_steps`. They are kept on the estimator and trained
+        on first by the next call that passes the same object (a call
+        that passes another one drops them)."""
         # one parent span a call: everything below is its child, so
         # set-up's calls (state init, the first steps, the first scanned
         # dispatch) and the window's lie on one timeline, each whole
@@ -716,11 +758,32 @@ class BaseEstimator:
             self._input_factory = input_fn if callable(input_fn) else None
             return self._train_impl(it, max_steps)
 
+    def _take_ahead(self, it):
+        """(raw batches, their stacked window or None, stream ended) the
+        last call read from `it` and did not train on; nothing when that
+        call read another iterator."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None and ahead[0] is it:
+            return ahead[1:]
+        return [], None, False
+
+    def _keep_ahead(self, it, buf, stacked, exhausted) -> None:
+        """Leave what this call took from a caller's iterator and did not
+        train on to the next call on it. An iterator made from a callable
+        dies with the call, and nothing beyond the call was read from
+        it."""
+        if self._input_factory is None and (buf or exhausted):
+            self._ahead = (it, buf, stacked, exhausted)
+
     def _train_impl(self, it, max_steps: int) -> Dict[str, float]:
-        with self._phase("input_wait", self._hist_input_wait):
-            raw0, it = self._next_input(it)
-            raw_first = _to_device_tree(raw0, self.max_id)
-        first = _merged(raw_first, self.static_batch)
+        buf, stacked, exhausted = self._take_ahead(it)
+        carried = len(buf)
+        if not buf:
+            if exhausted:
+                raise StopIteration
+            with self._phase("input_wait", self._hist_input_wait):
+                it = self._pull(it, buf)
+        first = _merged(buf[0], self.static_batch)
         if self.state is None:
             self._first_state(first)
         if self._train_step is None:
@@ -730,9 +793,10 @@ class BaseEstimator:
         if self.profiling and self.model_dir:
             jax.profiler.start_trace(os.path.join(self.model_dir, "prof"))
         if self.steps_per_loop > 1:
-            # pass the UNMERGED batch: the looped path stacks raw batches
-            # and merges static_batch inside the scanned body
-            return self._run_looped(it, raw_first, max_steps)
+            # pass the UNMERGED batches: the looped path stacks raw
+            # batches and merges static_batch inside the scanned body
+            return self._run_looped(it, buf, stacked, exhausted, carried,
+                                    max_steps)
         step = int(self.state.step)
         start_step = step
         losses, metrics = [], []
@@ -740,15 +804,15 @@ class BaseEstimator:
         # run must not corrupt rates (same bug class PR 2 fixed in
         # FileBarrier.wait)
         t0 = time.monotonic()
-        batch = first
         last_log = t0
-        while step < max_steps:
+        while step < max_steps and buf:
             with _obs.span("train_step", estimator=self._obs_name,
                            step=step):
                 with self._phase("device_step", self._hist_device_step), \
                         _calling("train_step"):
                     self.state, loss, metric = self._train_step(
-                        self.state, _merged(batch, self.static_batch))
+                        self.state,
+                        _merged(buf.pop(0), self.static_batch))
                 step += 1
                 losses.append(loss)
                 metrics.append(metric)
@@ -779,14 +843,14 @@ class BaseEstimator:
                                   f"({rate:.1f} steps/s)", flush=True)
                         if do_ckpt:
                             self.save_checkpoint(step)
-                if step < max_steps:
+                if step < max_steps and not buf and not exhausted:
                     try:
                         with self._phase("input_wait",
                                          self._hist_input_wait):
-                            raw, it = self._next_input(it)
-                            batch = _to_device_tree(raw, self.max_id)
+                            it = self._pull(it, buf)
                     except StopIteration:
-                        break
+                        exhausted = True
+        self._keep_ahead(it, buf, None, exhausted)
         # the closing checkpoint and the fetches of the summary: on this
         # path the one place the host waits for the steps it enqueued
         with self._span("train_finish"):
@@ -814,10 +878,58 @@ class BaseEstimator:
                 "skipped_batches": self.input_health["skipped_batches"],
             }
 
-    def _run_looped(self, it, first: Dict, max_steps: int) -> Dict[str, float]:
+    def _stack(self, buf):
+        """The K raw batches of `buf` as one pytree stacked on axis 0,
+        what the scanned window takes."""
+        def stack(*xs):
+            if isinstance(xs[0], np.ndarray):
+                return np.stack(xs)
+            return _stack_on_device(*xs)
+
+        with _obs.span("stack", estimator=self._obs_name):
+            return jax.tree_util.tree_map(stack, *buf)
+
+    def _read_ahead(self, it, in_flight, step: int, want: int):
+        """Up to `want` batches of the window that starts at `step`,
+        pulled while the device runs the window whose losses `in_flight`
+        are, and stacked where a whole window of them is in hand. Returns
+        (raw batches, their stacked form or None, the iterator, stream
+        ended).
+
+        Never later than a loop that reads nothing ahead: between pulls
+        the in-flight result is asked whether it is done, and once it is,
+        reading stops where it stands and the next window waits for the
+        rest as `input_wait`. A StopIteration ends the stream for the
+        next window, not the one in flight."""
+        buf, stacked, exhausted = [], None, False
+        if in_flight.is_ready():
+            return buf, stacked, it, exhausted
+        with self._span("read_ahead", step=step, K=want) as sp:
+            with self._phase("input_wait", self._hist_input_wait):
+                while len(buf) < want and not in_flight.is_ready():
+                    try:
+                        it = self._pull(it, buf)
+                    except StopIteration:
+                        exhausted = True
+                        break
+            if len(buf) == self.steps_per_loop \
+                    and not in_flight.is_ready():
+                stacked = self._stack(buf)
+            sp.set(got=len(buf))
+        return buf, stacked, it, exhausted
+
+    def _run_looped(self, it, buf, stacked, exhausted: bool, carried: int,
+                    max_steps: int) -> Dict[str, float]:
         """steps_per_loop > 1 train path: full K-step windows dispatch as
         one scanned device call; a tail shorter than K falls back to the
-        single-step function (no partial-scan recompile)."""
+        single-step function (no partial-scan recompile).
+
+        The loop is software-pipelined on this thread: once a window is
+        enqueued, and before its losses are fetched, the next window's
+        batches are pulled and stacked while the device works
+        (`_read_ahead`). `buf` holds the raw batches in hand, in order
+        (the first `carried` of them left by the last call on this
+        iterator), `stacked` their stacked form where one was made."""
         K = self.steps_per_loop
         step = int(self.state.step)
         start_step = step
@@ -826,45 +938,50 @@ class BaseEstimator:
         t0 = time.monotonic()
         last_log = t0
         logged_at = step
-        buf = [first]
-        exhausted = False
-
-        def stack(*xs):
-            if isinstance(xs[0], np.ndarray):
-                return np.stack(xs)
-            return jnp.stack(xs)
-
-        while step < max_steps:
+        # batches of `buf` that were in hand before their window began
+        ahead = carried
+        while step < max_steps and (buf or not exhausted):
             want = min(K, max_steps - step)
             # one parent per window; input_wait, stack, device_step,
-            # result_wait and hook follow each other under it with
-            # nothing between them, so a device-idle gap inside a window
-            # always lies under one named phase
+            # read_ahead, result_wait and hook follow each other under it
+            # with nothing between them, so a device-idle gap inside a
+            # window always lies under one named phase
             with _obs.span("train_dispatch", estimator=self._obs_name,
                            step=step, K=want):
                 if len(buf) < want and not exhausted:
                     with self._phase("input_wait", self._hist_input_wait):
                         while len(buf) < want and not exhausted:
                             try:
-                                raw, it = self._next_input(it)
-                                buf.append(
-                                    _to_device_tree(raw, self.max_id))
+                                it = self._pull(it, buf)
                             except StopIteration:
                                 exhausted = True
                 if not buf:
                     break
-                if len(buf) == K:
+                done = min(len(buf), want)
+                self._ctr_batches_ahead.inc(min(ahead, done))
+                self._ctr_batches_waited.inc(done - min(ahead, done))
+                if done == K:
                     if self._train_loop is None:
                         with self._span("build_fn", fn="train_loop"), \
                                 _calling("train_loop"):
                             self._train_loop = self._build_train_loop()
-                    with _obs.span("stack", estimator=self._obs_name):
-                        stacked = jax.tree_util.tree_map(stack, *buf)
+                    if stacked is None:
+                        stacked = self._stack(buf)
                     with self._phase("device_step",
                                      self._hist_device_step), \
                             _calling("train_loop"):
                         self.state, l_arr, m_arr = self._train_loop(
                             self.state, stacked, self.static_batch)
+                    # a caller's iterator outlives the call, and the next
+                    # call's window is read from it; one made from a
+                    # callable dies with the call: nothing is read from it
+                    # that this call does not train on
+                    buf, stacked = [], None
+                    room = K if self._input_factory is None else min(
+                        K, max_steps - step - K)
+                    if room > 0 and not exhausted:
+                        buf, stacked, it, exhausted = self._read_ahead(
+                            it, l_arr, step + K, room)
                     with self._phase("result_wait",
                                      self._hist_result_wait):
                         # nanmean / last-finite: guard-skipped steps
@@ -878,12 +995,12 @@ class BaseEstimator:
                     fin = fin[np.isfinite(fin)]
                     if fin.size:
                         last_loss = float(fin[-1])
-                    done = K
                 else:
                     # tail shorter than K: single-step dispatches (the
                     # jit was built in train() before this path was
-                    # entered)
-                    for b in buf:
+                    # entered), nothing read ahead; what a carried
+                    # window holds beyond the tail stays in hand, raw
+                    for b in buf[:done]:
                         with self._phase("device_step",
                                          self._hist_device_step), \
                                 _calling("train_step"):
@@ -897,10 +1014,10 @@ class BaseEstimator:
                             fin = float(l)
                         if np.isfinite(fin):
                             last_loss = fin
-                    done = len(buf)
+                    buf, stacked = buf[done:], None
+                ahead = len(buf)
                 prev = step
                 step += done
-                buf = []
                 do_log = step - logged_at >= self.log_steps
                 do_ckpt = self.ckpt_steps and \
                     step // self.ckpt_steps > prev // self.ckpt_steps
@@ -921,8 +1038,7 @@ class BaseEstimator:
                             last_log, logged_at = now, step
                         if do_ckpt:
                             self.save_checkpoint(step)
-            if exhausted:
-                break
+        self._keep_ahead(it, buf, stacked, exhausted)
         # the closing checkpoint and the summary's fetches (the windows'
         # metrics, the skip counter)
         with self._span("train_finish"):
@@ -1144,6 +1260,10 @@ class BaseEstimator:
             self.ckpt_steps = saved_ckpt_steps
             if owned_feeder:
                 self._close_live_feeder()
+            if callable(train_input_fn):
+                # the segments' iterator was made here and dies here,
+                # with what the last segment read ahead from it
+                self._ahead = None
         if keep_best and best_snap is not None:
             self.state = self.state.replace(
                 params=jax.tree_util.tree_map(jnp.asarray,

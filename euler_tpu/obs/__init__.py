@@ -36,9 +36,11 @@ estimator_train_call_ms, → the first batch's `input_wait` / `init_state`
 single-step path: `train_step` → `device_step` / `hook` / `input_wait`;
 scanned path, one parent `train_dispatch` a window of steps_per_loop →
 `input_wait` / `build_fn` / `stack` / `device_step` (the enqueue) /
-`result_wait` (the host waiting for the chip) / `hook`, back to back;
-histograms estimator_input_wait_ms, _device_step_ms, _result_wait_ms,
-_hook_ms; under whichever of them is open when a jitted function is
+`read_ahead` (→ the NEXT window's `input_wait` and `stack`, while the
+chip runs this one) / `result_wait` (the host waiting for the chip) /
+`hook`, back to back; histograms estimator_input_wait_ms,
+_device_step_ms, _result_wait_ms, _hook_ms; counter
+estimator_window_batches_total{how=ahead|waited}; under whichever of them is open when a jitted function is
 called the first time, `first_call` spans with estimator_compile_ms
 {fn,stage} and estimator_compiles_total{fn,cache}: first_calls.py),
 `parallel/feature_store.py` and `parallel/device_sampler.py` (set-up's

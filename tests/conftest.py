@@ -45,3 +45,84 @@ def ring_graph():
     b.set_edge_dense(src, dst, et, 0,
                      np.stack([w, -w], axis=1).astype(np.float32))
     return b.finalize()
+
+
+class NumberedSource:
+    """Batches numbered in the order they are pulled (`n` is the batch's
+    number, also the model's metric), each made from its number alone.
+    `pulled` counts them; `delay_s` slows every pull; `fail_at` raises
+    OSError once at that pull; `stop_at` ends the stream there."""
+
+    def __init__(self, delay_s=0.0, fail_at=None, stop_at=None):
+        self.pulled = 0
+        self.delay_s = delay_s
+        self.fail_at = fail_at
+        self.stop_at = stop_at
+
+    @staticmethod
+    def batch(n):
+        r = np.random.default_rng(n)
+        return {"x": r.standard_normal((8, 4)).astype(np.float32),
+                "y": r.standard_normal((8, 1)).astype(np.float32),
+                "n": np.full((1,), n, np.float32)}
+
+    def __call__(self):
+        """A stateless stream in the estimator's sense: a recreated
+        iterator goes on where the numbering stands."""
+        while self.stop_at is None or self.pulled < self.stop_at:
+            if self.delay_s:
+                import time
+
+                time.sleep(self.delay_s)
+            if self.fail_at == self.pulled:
+                self.fail_at = None
+                raise OSError("planted input failure")
+            self.pulled += 1
+            yield self.batch(self.pulled - 1)
+
+
+@pytest.fixture
+def slow_step_estimator():
+    """make(spin, **params) -> a BaseEstimator over a one-Dense regression
+    whose every device step first runs `spin` small matrix products that
+    change nothing (~40 us each: on the CPU a window of a tiny model would
+    otherwise be done before the host has read anything ahead; a host
+    callback that sleeps would make the dispatch synchronous), its state
+    made, so every estimator starts from the same parameters."""
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    from euler_tpu.estimator import BaseEstimator
+    from euler_tpu.mp_utils.base import ModelOutput
+
+    mix = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (128, 128)).astype(np.float32) / 12)
+
+    class SlowStep(nn.Module):
+        spin: int
+
+        @nn.compact
+        def __call__(self, batch):
+            x = batch["x"]
+            rows, cols = x.shape
+            a = jnp.zeros_like(mix).at[:rows, :cols].set(x)
+            a = jax.lax.fori_loop(0, self.spin,
+                                  lambda _, a: jnp.tanh(a @ mix), a)
+            x = x + 0.0 * a[:rows, :cols]
+            loss = jnp.mean((nn.Dense(1)(x) - batch["y"]) ** 2)
+            return ModelOutput(x, loss, "n", batch["n"][0])
+
+    def make(spin=0, **params):
+        est = BaseEstimator(SlowStep(spin), {
+            "learning_rate": 0.05, "log_steps": 1 << 30,
+            "checkpoint_steps": 0, "input_backoff_s": 0.001, **params})
+        est._init_state({k: jnp.asarray(v)
+                         for k, v in NumberedSource.batch(0).items()})
+        return est
+
+    return make
+
+
+@pytest.fixture
+def numbered_source():
+    return NumberedSource

@@ -796,6 +796,55 @@ def test_scanned_dispatch_is_one_parent_fully_covered():
     assert "ENQUEUE" in obs.snapshot()["estimator_device_step_ms"]["help"]
 
 
+@pytest.mark.parametrize("carried", [False, True])
+def test_read_ahead_is_a_child_of_the_window_it_runs_under(
+        slow_step_estimator, numbered_source, carried):
+    """A two-window call, and a call that finds its window carried: the
+    children of train_dispatch are [input_wait, stack,] device_step,
+    read_ahead, result_wait in time order; read_ahead holds the next
+    window's input_wait and one stack; all on the train thread, the
+    parent covered."""
+    K = 4
+    est = slow_step_estimator(150, steps_per_loop=K)
+    if carried:
+        it = numbered_source()()
+        est.train(it, max_steps=K)
+        obs.clear_trace()
+        assert est.train(it, max_steps=2 * K)["global_step"] == 2 * K
+    else:
+        est.train(numbered_source(), max_steps=K)    # compiles
+        obs.clear_trace()
+        res = est.train(numbered_source(), max_steps=3 * K)
+        assert res["global_step"] == 3 * K
+    spans = obs.default_tracer().spans()
+    windows = sorted((s for s in spans if s.name == "train_dispatch"),
+                     key=lambda s: s.ts_us)
+    assert len(windows) == (1 if carried else 2)
+    if not carried:
+        # a callable's last window reads nothing ahead: as it was
+        last = windows.pop()
+        assert [s.name for s in spans if s.parent_id == last.span_id] == [
+            "device_step", "result_wait"]
+
+    def kids(parent):
+        return sorted((s for s in spans if s.parent_id == parent.span_id),
+                      key=lambda s: s.ts_us)
+
+    for window in windows:
+        names = [s.name for s in kids(window)]
+        # the first window of a call on a fresh iterator waits and stacks
+        own = [] if carried else ["input_wait", "stack"]
+        assert names == own + ["device_step", "read_ahead", "result_wait"]
+        (ahead,) = [s for s in kids(window) if s.name == "read_ahead"]
+        assert ahead.attrs["step"] == window.attrs["step"] + K
+        assert ahead.attrs["K"] == ahead.attrs["got"] == K
+        inner = kids(ahead)
+        assert [s.name for s in inner] == ["input_wait", "stack"]
+        assert {s.tid for s in kids(window) + inner} == {window.tid}
+        covered = sum(s.dur_us for s in kids(window))
+        assert 0.95 * window.dur_us <= covered <= window.dur_us
+
+
 @pytest.mark.parametrize("workers", [0, 2])
 def test_feeders_report_batches_and_produce_time(workers):
     from euler_tpu.estimator.prefetch import make_feeder

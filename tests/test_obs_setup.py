@@ -184,18 +184,32 @@ def test_every_train_call_is_one_parent_with_its_phases_back_to_back(run):
     names = [[k.name for k in _kids(spans, c)] for c in calls]
     assert names[0] == ["input_wait", "init_state", "restore_checkpoint",
                         "build_fn", "train_finish"]
-    assert all(n == ["input_wait", "train_dispatch", "train_finish"]
+    # a call that finds batches the last call on its iterator read ahead
+    # has no first batch to wait for (PR 35)
+    assert all(n in (["input_wait", "train_dispatch", "train_finish"],
+                     ["train_dispatch", "train_finish"])
                for n in names[1:])
+    assert names[1] == names[3] == names[-1] == [
+        "input_wait", "train_dispatch", "train_finish"]
     (init,) = [s for s in spans if s.name == "init_state"]
     assert [k.name for k in _kids(spans, init)] == [
         "model_init", "create_state"]        # no mesh: nothing to commit
-    dispatches = [_kids(spans, c)[1] for c in calls[1:]]
-    assert [[k.name for k in _kids(spans, d)] for d in dispatches] == [
-        ["device_step", "result_wait"], ["device_step", "result_wait"],
-        ["input_wait", "build_fn", "stack", "device_step", "result_wait"],
-        ["device_step", "result_wait"],
-        ["input_wait", "stack", "device_step", "result_wait"],
-        ["device_step", "result_wait"]]
+    dispatches = [_kids(spans, c)[-2] for c in calls[1:]]
+    assert all(d.name == "train_dispatch" for d in dispatches)
+    phases = [[k.name for k in _kids(spans, d)] for d in dispatches]
+    # the single steps read nothing ahead; a scanned window on the feed
+    # (an iterator: the caller's) reads the next one's batches while the
+    # device works, if it is not done already: a matter of timing at this
+    # size. The second window still waits and stacks: the single step
+    # before it took the first of the batches read ahead
+    assert phases[0] == phases[1] == phases[3] == phases[5] == [
+        "device_step", "result_wait"]
+    assert [n for n in phases[2] if n != "read_ahead"] == [
+        "input_wait", "build_fn", "stack", "device_step", "result_wait"]
+    assert [n for n in phases[4] if n != "read_ahead"] == [
+        "input_wait", "stack", "device_step", "result_wait"]
+    assert all(p[-2:] == ["read_ahead", "result_wait"]
+               for p in phases if "read_ahead" in p)
     # every parent long enough for its spans' own cost not to count
     parents = [s for s in calls + dispatches + [init] if s.dur_us > 20e3]
     assert len(parents) >= 7     # init, first step, first dispatch, planted
