@@ -8,6 +8,7 @@ test and its histograms, nothing of bench.py, chip_smoke.py or tools/.
 from __future__ import annotations
 
 import importlib
+import inspect
 import json
 import os
 import time
@@ -33,6 +34,18 @@ def resolve(dotted: str):
 
 def load_config(bench_dir: str, name: str) -> dict:
     return load_json(os.path.join(bench_dir, "configs", name + ".json"))
+
+
+def bind_model_fields(cfg: dict) -> None:
+    """The configuration's model fields (`model.kwargs` and the keys of
+    `from_run`) bound to the model class's signature: the TypeError
+    `Program` would raise, raised before any table is made. A program
+    that lacks a field would otherwise meet it only after its tables
+    were made, quantised and placed, which at a wide configuration's
+    size can take the host's memory first."""
+    fields = {**cfg["model"]["kwargs"],
+              **dict.fromkeys(cfg["model"].get("from_run", {}))}
+    inspect.signature(resolve(cfg["model"]["class"])).bind(**fields)
 
 
 class CompileWatch:

@@ -1,10 +1,13 @@
 """Per-layer metrics of the GraphSAGE-LSTM configuration, read from the
 scope its recurrences carry in the one trace
 (`euler_tpu/utils/aggregators.LSTMAggregator`: `.../encoder/enc/
-agg_<l>/lstm`, the scanned slots with their input and recurrent
-products, forward and backward). The trace is loaded and its
-compiler-made operations named by `scope_readers`; the FLOP and byte
-counts are `work_lstm.py`'s.
+agg_<l>/lstm`). On the TPU, where the shapes tile, a recurrence there is
+two Pallas calls, `lstm_fwd` and `lstm_bwd` (`ops/slot_lstm.py`), with
+the mask's re-layout beside them; elsewhere it is the `lax.scan` over
+the slots (the input and recurrent products, the gates, the saved
+carries, forward and backward, the loop itself). Either way the scope
+holds it whole. The trace is loaded and its compiler-made operations
+named by `scope_readers`; the FLOP and byte counts are `work_lstm.py`'s.
 
 `lstm_ms` is a PART of `encode_ms` (the encoder module's whole self
 time), not beside it: what `encode_ms` holds besides it is the S and N
@@ -53,7 +56,7 @@ def lstm_ms(ctx):
     return 1e3 * secs / (win[2] * ctx["window"]["spl"])
 
 
-def lstm_roofline_pct(ctx):
+def lstm_roofline(ctx):
     """The least time the recurrences could take a step, the larger of
     work_lstm's `lstm_flops` over the bf16 peak and its `lstm_bytes` over
     the HBM peak, as a share of `lstm_ms`; None where either is missing."""

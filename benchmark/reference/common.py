@@ -19,12 +19,21 @@ _BF16 = np.dtype(jnp.bfloat16)
 _ROOT_KEY = 17   # the key every device-sampled model folds its seed into
 
 
-def quantize_int8(feat: np.ndarray, chunk_rows: int = 262_144):
+def quantize_int8(feat: np.ndarray, chunk_rows: int | None = None):
     """Features as the configuration states they are stored: rounded to
     bfloat16, then per-column symmetric int8 (scale = colmax|x| / 127,
     all-zero columns 1, q = rint(x / scale) clipped to +-127), the scale
     itself kept in bfloat16. Returns (q int8 [rows, D], scale bf16 [D]).
-    Chunked: the transients stay chunk-sized."""
+
+    Chunked by bytes: 16 MB of float32 a chunk unless `chunk_rows` says
+    otherwise, at any width. Each chunk makes a few transients of its
+    size on each of 8 threads; arrays over glibc's mmap threshold are
+    mapped and unmapped one by one, and on a 40 GiB host that churn at
+    768 columns and 262,144 rows a chunk (805 MB) used up free memory
+    faster than the kernel returned it. The arithmetic is per element
+    and per column, so the bytes do not depend on the chunking."""
+    if chunk_rows is None:
+        chunk_rows = max(1, (1 << 22) // feat.shape[1])
     starts = range(0, feat.shape[0], chunk_rows)
 
     def rounded(lo):

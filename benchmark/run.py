@@ -79,7 +79,8 @@ def run_cell(bench: dict, args, cells_dir: str = BENCH_DIR,
     import euler_tpu.estimator  # noqa: F401 - absent program: fail now
     from benchmark import check, reduce_trace, tables
     from benchmark.cell import (
-        CompileWatch, Program, load_config, load_json, resolve,
+        CompileWatch, Program, bind_model_fields, load_config, load_json,
+        resolve,
     )
     from benchmark.traffic import load_traffic
 
@@ -94,6 +95,7 @@ def run_cell(bench: dict, args, cells_dir: str = BENCH_DIR,
               file=sys.stderr)
         raise SystemExit(2)
     cfg = load_config(cells_dir, cell["config"])
+    bind_model_fields(cfg)
     traffic = load_traffic(cells_dir, cell["traffic"])
     peaks_all = load_json(os.path.join(BENCH_DIR, "peaks.json"))
     if require_tpu and dev.device_kind not in peaks_all:

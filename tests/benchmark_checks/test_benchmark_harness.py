@@ -15,6 +15,7 @@ import argparse
 import json
 import os
 import re
+import shutil
 import sys
 from pathlib import Path
 
@@ -27,7 +28,7 @@ sys.path.insert(0, str(ROOT))
 from benchmark import (  # noqa: E402
     check, readers, reduce_trace, run, tables, work,
 )
-from benchmark.cell import load_config  # noqa: E402
+from benchmark.cell import load_config, resolve  # noqa: E402
 from benchmark.traffic import RootSource, load_traffic  # noqa: E402
 
 TINY = str(Path(__file__).resolve().parent / "tiny")
@@ -186,6 +187,57 @@ def test_the_bfloat16_control_is_not_correct():
         assert max(same.values()) == 0.0
 
 
+class _TablesAsked(Exception):
+    """Raised where a run makes its tables: it got that far."""
+
+
+def cells_dir_with(tmp: Path, cfg: dict) -> str:
+    """A cells directory that holds `cfg` as its configuration's file
+    beside the benchmark's traffic mixes."""
+    (tmp / "configs").mkdir(parents=True)
+    (tmp / "configs" / (cfg["name"] + ".json")).write_text(json.dumps(cfg))
+    shutil.copytree(ROOT / "benchmark" / "traffic", tmp / "traffic")
+    return str(tmp)
+
+
+def tables_made_by_a_run(workload: str, cells_dir: str, monkeypatch,
+                         raises=_TablesAsked, match=None) -> int:
+    """Runs a cell of BENCHMARK.json from `cells_dir` up to the making
+    of its tables, which raises in their place; how often it got there."""
+    asked = []
+
+    def make_tables(*args, **kw):
+        asked.append(args)
+        raise _TablesAsked
+
+    monkeypatch.setattr(tables, "make_tables", make_tables)
+    with pytest.raises(raises, match=match):
+        run.run_cell(BENCH, argparse.Namespace(
+            workload=workload, seed=SEED, seconds=1, trace=0),
+            cells_dir=cells_dir, require_tpu=False)
+    return len(asked)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_the_model_fields_are_bound_before_any_table_is_made(
+        config, tmp_path, monkeypatch):
+    """Each configuration's fields bind to the program's model class, so
+    its run comes to its tables; a field no model class takes, handed as
+    a keyword or as a fact of the run, stops the run before them."""
+    workload = next(w["name"] for w in BENCH["workloads"]
+                    if w["config"] == config)
+    assert tables_made_by_a_run(workload, str(ROOT / "benchmark"),
+                                monkeypatch) == 1
+    for group, field, value in (("kwargs", "a_field_no_model_has", 1),
+                                ("from_run", "a_fact_no_model_takes",
+                                 "num_nodes")):
+        cfg = load_config(str(ROOT / "benchmark"), config)
+        cfg["model"].setdefault(group, {})[field] = value
+        assert tables_made_by_a_run(
+            workload, cells_dir_with(tmp_path / group, cfg), monkeypatch,
+            raises=TypeError, match=field) == 0
+
+
 def test_no_tpu_means_no_result(capsys):
     with pytest.raises(SystemExit) as e:
         run.run_cell(BENCH, argparse.Namespace(
@@ -338,66 +390,163 @@ def test_reference_quantisation_is_the_stores():
     assert np.array_equal(scale, s2.astype(bf16))
 
 
-def _entries():
-    for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        for e in BENCH[group]:
-            yield group, e
-
-
-def test_benchmark_json_keeps_to_the_contract():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def keeps_to_the_contract(bench: dict, root: Path) -> None:
+    """The rules BENCHMARK.json keeps, as a function of its dict and of
+    the tree its files lie in (`root`), so that the file as a later PR
+    leaves it, with a metric or a cell appended, is held to them as
+    they stand."""
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
+    assert 1 <= bench["run_seconds"] <= 51
     names = {}
-    for group, e in _entries():
-        assert NAME.match(e["name"]), e["name"]
-        names.setdefault(group in ("end_to_end", "per_layer") and "metric"
-                         or group, []).append(e["name"])
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        for e in bench[group]:
+            assert NAME.match(e["name"]), e["name"]
+            names.setdefault(group in ("end_to_end", "per_layer")
+                             and "metric" or group, []).append(e["name"])
     for group, got in names.items():
         assert len(got) == len(set(got)), group
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    cells = set(names["workloads"])
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for m in BENCH["end_to_end"]:
+        # a list of cells names cells of this benchmark, and some
+        assert set(m.get("workloads", cells)) <= cells, m["name"]
+        assert m.get("workloads", cells), m["name"]
+    for m in bench["end_to_end"]:
         assert 0 < m["bound"] <= 0.1
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    e2e = {m["name"] for m in bench["end_to_end"]}
     assert "setup_s" in e2e
-    for m in BENCH["per_layer"]:
+    metrics = root / "benchmark" / "metrics"
+    for m in bench["per_layer"]:
         assert m["moves"] in e2e and 1 <= len(m["layer"]) <= 200
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        spec = json.loads((ROOT / "benchmark" / "metrics"
-                           / (m["name"] + ".json")).read_text())
-        for k in ("unit", "better", "source", "layer", "moves"):
+        spec = json.loads((metrics / (m["name"] + ".json")).read_text())
+        for k in ("name", "unit", "better", "source", "layer", "moves"):
             assert spec[k] == m[k], (m["name"], k)
-        from benchmark.cell import resolve
         assert callable(resolve(spec["reader"]))
-    configs = {c["name"]: c for c in BENCH["configs"]}
+    # a metric's file and its entry come together: no file is left over
+    assert {f.stem for f in metrics.glob("*.json")} \
+        == {m["name"] for m in bench["per_layer"]}
+    configs = {c["name"]: c for c in bench["configs"]}
     used = set()
-    for w in BENCH["workloads"]:
+    for w in bench["workloads"]:
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert NAME.match(w["traffic"]) and w["config"] in configs
-        assert (ROOT / "benchmark" / "traffic"
+        assert (root / "benchmark" / "traffic"
                 / (w["traffic"] + ".json")).is_file()
         used.add(w["config"])
     assert used == set(configs)
-    for c in BENCH["configs"]:
+    for c in bench["configs"]:
         assert len(c["source"]) <= 200 and len(c["why"]) <= 200
-        assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
-        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+        cfg = json.loads((root / c["file"]).read_text())
         assert cfg["reduced"] == c["reduced"] == ["num_nodes"]
         assert cfg["source"] == c["source"]
         # no width is cut: the published ones stand in the file
         for k in ("feature_dim", "num_classes"):
             assert cfg[k] == cfg["published"][k]
-        assert cfg["published"]["num_nodes"] // 32 == cfg["num_nodes"]
+        # one chip's row share of the published graph: 1/32 unless the
+        # configuration states its own
+        assert cfg["published"]["num_nodes"] // cfg.get("row_share", 32) \
+            == cfg["num_nodes"]
         assert {"assumed", "deployment", "limits"} <= set(cfg)
-    for p in BENCH["paths"]:
-        for f in (ROOT / p).rglob("*"):
+    for p in bench["paths"]:
+        for f in (root / p).rglob("*"):
             if "__pycache__" in f.parts or f.suffix == ".pyc":
                 continue
             assert re.fullmatch(r"[A-Za-z0-9_.\-/]+",
-                                str(f.relative_to(ROOT))), f
+                                str(f.relative_to(root))), f
+
+
+def test_benchmark_json_keeps_to_the_contract():
+    keeps_to_the_contract(BENCH, ROOT)
+
+
+LATER_CELL = "sagelstm2-papers100m-s8.unit-b1024"
+
+
+def _later_tree(tmp: Path) -> Path:
+    """A copy of the benchmark's files, as a later PR starts from."""
+    shutil.copytree(ROOT / "benchmark", tmp / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp
+
+
+def _add_metric(root: Path, bench: dict, name: str, cells: list,
+                entry: bool = True, file: bool = True) -> dict:
+    spec = {"name": name, "unit": "ms/step", "better": "lower",
+            "source": "device_trace",
+            "layer": bench["per_layer"][-1]["layer"],
+            "moves": "train_nodes_per_s"}
+    if file:
+        (root / "benchmark" / "metrics" / (name + ".json")).write_text(
+            json.dumps({**spec, "reader": "benchmark.lstm_readers.lstm_ms"}))
+    if not entry:
+        return bench
+    return {**bench, "per_layer": bench["per_layer"]
+            + [{**spec, "workloads": cells}]}
+
+
+def _add_cell(root: Path, bench: dict, row_share) -> dict:
+    """A configuration that is another share of a graph the benchmark
+    already holds, and its cell."""
+    (base,) = [c for c in bench["configs"]
+               if c["name"] == "sagelstm2-papers100m-s32"]
+    cfg = json.loads((root / base["file"]).read_text())
+    cfg["name"] = LATER_CELL.split(".")[0]
+    cfg["num_nodes"] = cfg["published"]["num_nodes"] // 8
+    if row_share is not None:
+        cfg["row_share"] = row_share
+    file = f"benchmark/configs/{cfg['name']}.json"
+    (root / file).write_text(json.dumps(cfg))
+    return {**bench,
+            "configs": bench["configs"] + [{**base, "name": cfg["name"],
+                                            "file": file}],
+            "workloads": bench["workloads"] + [{
+                "name": LATER_CELL, "config": cfg["name"],
+                "traffic": "unit-b1024", "chips": 1, "why": "test"}]}
+
+
+@pytest.mark.parametrize("append", ["metric", "cell_and_metric"])
+def test_a_later_append_keeps_to_the_contract(tmp_path, append):
+    """What a later PR adds, with no existing file or test edited: a
+    metric with its file and the cell it is read in; or a configuration
+    that states its own row share, its cell, and a metric of that cell."""
+    root = _later_tree(tmp_path)
+    bench = json.loads(json.dumps(BENCH))
+    cells = ["sagelstm2-papers100m-s32.unit-b1024"]
+    if append == "cell_and_metric":
+        bench = _add_cell(root, bench, row_share=8)
+        cells = [LATER_CELL]
+    bench = _add_metric(root, bench, "later_ms", cells)
+    keeps_to_the_contract(bench, root)
+    # the tests that pin the list's head and the set-up four still hold
+    assert bench["per_layer"][:len(BENCH["per_layer"])] == BENCH["per_layer"]
+    import test_setup_readers
+
+    test_setup_readers.assert_the_four_stand_together(bench)
+
+
+@pytest.mark.parametrize("fault", [
+    "file_without_entry", "entry_without_file", "unknown_cell",
+    "empty_cells", "share_not_stated"])
+def test_an_append_that_breaks_the_contract_is_refused(tmp_path, fault):
+    root = _later_tree(tmp_path)
+    bench = json.loads(json.dumps(BENCH))
+    if fault == "share_not_stated":
+        bench = _add_cell(root, bench, row_share=None)
+    else:
+        bench = _add_metric(
+            root, bench, "later_ms",
+            {"unknown_cell": ["no-such-cell.unit-b1"],
+             "empty_cells": []}.get(
+                fault, ["sagelstm2-papers100m-s32.unit-b1024"]),
+            entry=fault != "file_without_entry",
+            file=fault != "entry_without_file")
+    with pytest.raises((AssertionError, FileNotFoundError)):
+        keeps_to_the_contract(bench, root)

@@ -35,7 +35,7 @@ REAL = "sagelstm2-papers100m-s32.unit-b1024"
 CELL = "sagelstm2-tiny.unit-b64"
 TINY_CELL = {"name": CELL, "config": "sagelstm2-tiny", "traffic": "unit-b64",
              "chips": 1, "why": "test"}
-NEW = ("lstm_ms", "lstm_roofline_pct")
+NEW = ("lstm_ms", "lstm_roofline")
 MS = 1e6
 STEP = "jit(train_loop)/while/body/closed_call/"
 FWD = STEP + "jvp(M)/M.embed/encoder/"
@@ -43,23 +43,19 @@ BWD = STEP + "transpose(jvp(M))/M.embed/encoder/"
 
 
 def _entry(name: str) -> dict:
-    """The BENCHMARK.json entry a metric's file stands for. The two of
-    this cell are files and readers only: `test_setup_readers.py` holds
-    `per_layer`'s last four to be set-up's, so an entry appended after
-    them fails it, and one put before them reads as a change to the
-    list: both wait for a `benchmark` PR (PERF.md section 7)."""
-    spec = json.loads((ROOT / "benchmark" / "metrics"
-                       / (name + ".json")).read_text())
-    return {k: v for k, v in spec.items() if k != "reader"}
+    """The cell's metric as BENCHMARK.json lists it."""
+    (entry,) = [m for m in harness.BENCH["per_layer"] if m["name"] == name]
+    return entry
 
 
 def _bench():
     """BENCHMARK.json with the tiny cell in the place of the real one,
-    and the cell's two metrics as entries that name it."""
+    in `workloads` and in the lists of the metrics that name it."""
     b = dict(harness.BENCH)
     b["workloads"] = [TINY_CELL]
-    b["per_layer"] = [m for m in b["per_layer"] if m["name"] not in NEW] \
-        + [{**_entry(n), "workloads": [CELL]} for n in NEW]
+    b["per_layer"] = [
+        {**m, "workloads": [CELL]} if m["name"] in NEW else m
+        for m in b["per_layer"]]
     return b
 
 
@@ -73,14 +69,22 @@ def test_the_benchmark_names_the_cell_and_its_two_metrics():
         == real["traffic"]
     from benchmark.cell import resolve
 
-    layers = {m["layer"] for m in harness.BENCH["per_layer"]}
+    layers = {m["layer"] for m in harness.BENCH["per_layer"]
+              if m["name"] not in NEW}
     for name in NEW:
         entry = _entry(name)
         assert (entry["name"], entry["source"], entry["moves"]) \
             == (name, "device_trace", "train_nodes_per_s")
         assert entry["layer"] in layers and harness.UNIT.match(entry["unit"])
-        assert resolve(f"benchmark.lstm_readers.{name}") is getattr(
-            lstm_readers, name)
+        # read in this cell alone; the file is the entry and its reader
+        assert entry["workloads"] == [REAL]
+        spec = json.loads((ROOT / "benchmark" / "metrics"
+                           / (name + ".json")).read_text())
+        assert {**spec, "workloads": [REAL]} == {
+            **entry, "reader": f"benchmark.lstm_readers.{name}"}
+        assert resolve(spec["reader"]) is getattr(lstm_readers, name)
+    # a kernel's share of its roofline is named for the kernel
+    assert _entry("lstm_roofline")["unit"] == "%"
     cfg = load_config(str(ROOT / "benchmark"), "sagelstm2-papers100m-s32")
     kw = cfg["model"]["kwargs"]
     assert (cfg["feature_dim"], cfg["num_classes"], cfg["lstm_hidden"],
@@ -307,7 +311,7 @@ def test_the_two_readers_by_hand(monkeypatch):
     w = work_lstm.sage(_HAND, 64, weighted=False)
     floor = max(w["lstm_flops"] / 1e9, w["lstm_bytes"] / 1e6)
     assert w["lstm_bytes"] / 1e6 > w["lstm_flops"] / 1e9   # bytes bound here
-    assert lstm_readers.lstm_roofline_pct(ctx) == pytest.approx(
+    assert lstm_readers.lstm_roofline(ctx) == pytest.approx(
         100 * floor / (lstm * 1e-3 / steps))
 
 
@@ -323,6 +327,6 @@ def test_the_readers_find_nothing_where_nothing_is(monkeypatch):
     # a configuration whose work function counts no recurrence
     ctx = _ctx(monkeypatch, full, load_config(TINY, "sage3-tiny"))
     assert lstm_readers.lstm_ms(ctx) is not None
-    assert lstm_readers.lstm_roofline_pct(ctx) is None
+    assert lstm_readers.lstm_roofline(ctx) is None
     ctx["window"]["trace"] = None                     # an untraced run
     assert lstm_readers.lstm_ms(ctx) is None

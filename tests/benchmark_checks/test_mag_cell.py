@@ -23,7 +23,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 from benchmark import (  # noqa: E402
     cell, check, gat2bn_norm_by_pair, gat_readers, kernel_work, mag_readers,
-    mag_reference, run, scope_readers, tables, work_mag,
+    run, scope_readers, tables, work_mag,
 )
 from benchmark.cell import load_config  # noqa: E402
 from benchmark.reference import gat2bn  # noqa: E402
@@ -92,41 +92,50 @@ def test_the_benchmark_names_the_cell_and_its_five_metrics():
     assert sum(int(np.prod(s)) for s in shapes.values()) == 4_890_777
 
 
-def test_a_program_without_the_fields_fails_before_anything_is_placed():
-    """`mag_reference.param_shapes` is the first thing of the
-    configuration the harness resolves (`check.make_weights`, before
-    `cell.Program`): a model class that lacks `norm` or `head_dim` (any
-    commit before this one) raises there what `cell.Program` would
-    raise after quantising and placing an 11.7 GB table."""
+def test_a_program_without_the_fields_fails_before_anything_is_placed(
+        tmp_path, monkeypatch):
+    """A model class that lacks a field of the configuration (`norm` or
+    `head_dim`: any program older than this encoder) fails in `run_cell`
+    before a table is made, with the TypeError `cell.Program` would
+    raise only after making, quantising and placing 11.7 GB of float32
+    features on the host."""
     cfg = load_config(str(ROOT / "benchmark"), "gat2-mag240m-s32")
-    assert cfg["reference"] == "benchmark.mag_reference"
-    assert check.make_weights(cfg, SEED).keys() \
-        == gat2bn.param_shapes(cfg).keys()
-    assert mag_reference.loss is gat2bn.loss
-    assert mag_reference.init_extra is gat2bn.init_extra
-    older = {**cfg, "model": {**cfg["model"], "kwargs": {
-        **cfg["model"]["kwargs"], "a_field_no_model_has": 1}}}
-    with pytest.raises(TypeError, match="a_field_no_model_has"):
-        check.make_weights(older, SEED)
+    assert cfg["reference"] == "benchmark.reference.gat2bn"
+    cfg["model"]["kwargs"]["a_field_no_model_has"] = 1
+    made = harness.tables_made_by_a_run(
+        REAL, harness.cells_dir_with(tmp_path, cfg), monkeypatch,
+        raises=TypeError, match="a_field_no_model_has")
+    assert made == 0
 
 
-def test_the_rebound_chunk_leaves_the_reference_quantisation_its_bytes():
-    """`mag_reference.param_shapes` sets the default chunk of the
-    reference's quantisation to 16 MB of float32 at 768 columns (the
-    file chunks by rows; mag_reference.py says why): the table and the
-    scale are the same bytes at any chunking."""
+@pytest.mark.parametrize("columns", [128, 768])
+def test_the_reference_quantisation_chunks_by_bytes_at_any_width(
+        columns, monkeypatch):
+    """16 MB of float32 a chunk, at the accepted cells' 128 columns and
+    at this configuration's 768; the table and the scale are the same
+    bytes at any chunking."""
+    from concurrent.futures import ThreadPoolExecutor
+
     from benchmark.reference import common
 
-    check.make_weights(load_config(TINY, "gat2-tiny"), SEED)
-    assert common.quantize_int8.__defaults__ == (mag_reference.CHUNK_ROWS,)
-    assert mag_reference.CHUNK_ROWS == 5461
+    starts = []
+
+    class Recording(ThreadPoolExecutor):
+        def map(self, fn, *iterables, **kw):
+            starts.append(list(iterables[0]))
+            return super().map(fn, *iterables, **kw)
+
+    rows = (1 << 22) // columns
     f = np.random.default_rng(2).standard_normal(
-        (3 * mag_reference.CHUNK_ROWS + 5, 8), dtype=np.float32)
+        (2 * rows + 5, columns), dtype=np.float32)
     f[:, 2] = 0
-    q, scale = common.quantize_int8(f)                 # 4 chunks
-    q1, scale1 = common.quantize_int8(f, chunk_rows=262_144)    # one
-    assert q.tobytes() == q1.tobytes()
-    assert scale.tobytes() == scale1.tobytes()
+    monkeypatch.setattr(common, "ThreadPoolExecutor", Recording)
+    q, scale = common.quantize_int8(f)                      # 3 chunks
+    assert starts[0] == [0, rows, 2 * rows]
+    for chunk_rows in (f.shape[0], 999):                    # one, many
+        q1, scale1 = common.quantize_int8(f, chunk_rows=chunk_rows)
+        assert q.tobytes() == q1.tobytes()
+        assert scale.tobytes() == scale1.tobytes()
 
 
 @pytest.fixture(scope="module")

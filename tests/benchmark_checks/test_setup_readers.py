@@ -104,10 +104,32 @@ def test_the_metric_files_are_the_entries(name):
     assert resolve(spec["reader"]) is getattr(setup_readers, name)
     assert entry["moves"] == "setup_s" and "workloads" not in entry
     assert entry["better"] == "lower"
-    # the last four of the list: appended, nothing moved
-    assert [m["name"] for m in BENCH["per_layer"][-4:]] == list(NEW)
-    layers = {m["layer"] for m in BENCH["per_layer"][:-4]}
-    assert entry["layer"] in layers
+    assert_the_four_stand_together(BENCH)
+
+
+def assert_the_four_stand_together(bench: dict) -> None:
+    """The four were appended as one block, in this order, each in a
+    layer an entry before them names: a rule that later appends keep."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == list(NEW)
+    layers = {m["layer"] for m in bench["per_layer"][:at]}
+    for m in bench["per_layer"][at:at + len(NEW)]:
+        assert m["layer"] in layers, m["name"]
+
+
+def test_the_four_stand_together_after_an_append():
+    appended = {"name": "later_ms", "unit": "ms", "better": "lower",
+                "source": "device_trace",
+                "layer": BENCH["per_layer"][0]["layer"],
+                "moves": "train_nodes_per_s",
+                "workloads": [BENCH["workloads"][0]["name"]]}
+    assert_the_four_stand_together(
+        {**BENCH, "per_layer": BENCH["per_layer"] + [appended]})
+    moved = [m for m in BENCH["per_layer"] if m["name"] != NEW[2]] \
+        + [m for m in BENCH["per_layer"] if m["name"] == NEW[2]]
+    with pytest.raises(AssertionError):
+        assert_the_four_stand_together({**BENCH, "per_layer": moved})
 
 
 def test_the_readers_on_a_tiny_programs_own_registry():
