@@ -48,6 +48,40 @@ def bind_model_fields(cfg: dict) -> None:
     inspect.signature(resolve(cfg["model"]["class"])).bind(**fields)
 
 
+REFERENCE_FUNCTIONS = ("param_shapes", "init_extra", "loss")
+
+
+def resolve_functions(cfg: dict) -> dict:
+    """Every function the configuration's file names by dotted path:
+    `kernels`, `work`, and its reference's REFERENCE_FUNCTIONS with
+    `init_weights` where the module defines it -> {name: function}.
+    Raises LookupError before any table is made, where a run would
+    otherwise meet the name only later (`kernels` in a traced run's
+    readers, after its tables were made, placed and stepped)."""
+    ref = cfg.get("reference")
+    named = {key: cfg.get(key) for key in ("kernels", "work")}
+    named.update({f: f"{ref}.{f}" for f in REFERENCE_FUNCTIONS})
+    out = {}
+    for name, dotted in named.items():
+        if not isinstance(dotted, str):
+            raise LookupError(f"the configuration's file names no {name}")
+        try:
+            out[name] = resolve(dotted)
+        except (AttributeError, ImportError, ValueError) as e:
+            raise LookupError(
+                f"the configuration's {name} names {dotted!r}, which is "
+                f"no function ({e})") from e
+        if not callable(out[name]):
+            raise LookupError(f"the configuration's {name} names "
+                              f"{dotted!r}, which is no function")
+    init = getattr(importlib.import_module(ref), "init_weights", None)
+    if init is not None:
+        if not callable(init):
+            raise LookupError(f"{ref}.init_weights is no function")
+        out["init_weights"] = init
+    return out
+
+
 class CompileWatch:
     """Counts the executables jax builds or fetches from its persistent
     cache (jax.monitoring); a copy of chip_smoke.CompileWatch."""

@@ -37,6 +37,7 @@ file and is left out (PERF.md gives the readings).
 
 from __future__ import annotations
 
+import importlib
 import statistics
 import sys
 
@@ -50,9 +51,16 @@ ZERO_GRAD_SHARE = 1e-3
 
 
 def make_weights(cfg: dict, seed: int) -> dict:
-    ref = resolve(cfg["reference"] + ".param_shapes")
+    """The seed's initial weights, {leaf path: float32 array}: the
+    reference's own `init_weights(cfg, rng, shapes)` where its module
+    defines one, else common.lecun_normal, from the same stream."""
+    mod = cfg["reference"]
+    shapes = resolve(mod + ".param_shapes")(cfg)
     rng = np.random.default_rng([int(seed), _WEIGHT_STREAM])
-    return common.lecun_normal(rng, ref(cfg))
+    init = getattr(importlib.import_module(mod), "init_weights", None)
+    if init is None:
+        return common.lecun_normal(rng, shapes)
+    return init(cfg, rng, shapes)
 
 
 def place_tables(cfg: dict, traffic: dict, host: dict) -> dict:

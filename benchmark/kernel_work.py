@@ -1,20 +1,25 @@
 """What the two table kernels of one training step have to read, from
-shapes alone: rows and the bytes of a row as stored. The whole step's
-counts are work.py's; these are its `_tables` term taken apart, one
-entry a kernel, for the per-kernel shares of the HBM peak.
+shapes alone, for the configurations whose draw is a fanout: rows and the
+bytes of a row as stored. The whole step's counts are work.py's; these
+are its `_tables` term taken apart, one entry a kernel, for the
+per-kernel shares of the HBM peak.
 
 gather: every feature row of every hop (the roots' too), as stored.
 draw: one neighbour row (cap int32 slots) for every node drawn from,
 i.e. every hop but the last, plus its cumulative-weight row (cap
 float32) where the edges are weighted.
 
-Each function is named as the configuration's `work` function is
-(`for_config` picks it by that name), takes what that one takes and
-returns {"gather": (rows, row_bytes), "draw": (rows, row_bytes)}.
+A configuration names its counts under `kernels` (a dotted path, as
+`work`): `sage` where the model takes `fanouts`, `scalablesage` where it
+takes one `fanout`. A configuration whose draw is not a fanout names a
+function of its own, in a file of its own, with the same signature:
+(cfg, batch, weighted) -> {"gather": (rows, row_bytes),
+"draw": (rows, row_bytes)}.
 """
 
 from __future__ import annotations
 
+from .cell import resolve
 from .work import _F32, _I32, _STORED
 
 
@@ -41,5 +46,5 @@ def scalablesage(cfg: dict, batch: int, weighted: bool) -> dict:
 
 
 def for_config(cfg: dict):
-    """The function here that bears the name of cfg["work"]'s."""
-    return globals()[cfg["work"].rpartition(".")[2]]
+    """The function the configuration names under `kernels`."""
+    return resolve(cfg["kernels"])

@@ -80,7 +80,7 @@ def run_cell(bench: dict, args, cells_dir: str = BENCH_DIR,
     from benchmark import check, reduce_trace, tables
     from benchmark.cell import (
         CompileWatch, Program, bind_model_fields, load_config, load_json,
-        resolve,
+        resolve, resolve_functions,
     )
     from benchmark.traffic import load_traffic
 
@@ -96,6 +96,7 @@ def run_cell(bench: dict, args, cells_dir: str = BENCH_DIR,
         raise SystemExit(2)
     cfg = load_config(cells_dir, cell["config"])
     bind_model_fields(cfg)
+    resolve_functions(cfg)
     traffic = load_traffic(cells_dir, cell["traffic"])
     peaks_all = load_json(os.path.join(BENCH_DIR, "peaks.json"))
     if require_tpu and dev.device_kind not in peaks_all:

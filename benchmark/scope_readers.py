@@ -258,9 +258,10 @@ def unscoped_pct(ctx):
 
 
 def _hbm_pct(ctx, kernel: str):
-    """The kernel's rows a step x a row's stored bytes over its time, as
-    a share of the HBM peak. Far under 100 by nature: these gathers are
-    bound by the number of rows, not by bytes."""
+    """The kernel's rows a step x a row's stored bytes (the counts the
+    configuration names under `kernels`) over its time, as a share of
+    the HBM peak. Far under 100 by nature: these gathers are bound by the
+    number of rows, not by bytes."""
     ms = _scope_ms(ctx, kernel)
     if not ms or not ctx["peaks"]:
         return None

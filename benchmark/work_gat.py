@@ -1,12 +1,10 @@
 """What one training step of the graph-attention configuration has to
 compute and to move, from shapes alone, by the rules of work.py.
 
-**The function is named `sage` on purpose.** `kernel_work.for_config`
-looks the table kernels' row counts up by the NAME of the
-configuration's work function, and this model's draw and feature gather
-are the fanout ones `kernel_work.sage` counts from `fanouts`; under any
-other name `gather_hbm_pct` and `draw_hbm_pct` raise `KeyError` in a
-traced run (kernel_work.py is not this file's to edit).
+The function is named `sage` for the fanout draw it counts under. The
+table kernels' row counts are not here: this model's draw and feature
+gather are the fanout ones, so its configuration names
+`kernel_work.sage` under `kernels`.
 
 FLOPs: the projections `x W` of every hop a layer reads (once a hop),
 the linear skips of its targets, and the two attention contractions a

@@ -1,11 +1,11 @@
 """What one training step of the MAG240M baseline GAT has to compute and
 to move, from shapes alone, by the rules of work.py and work_gat.py.
 
-**The function is named `sage` on purpose**, as `work_gat.py`'s is:
-`kernel_work.for_config` looks the table kernels' row counts up by the
-NAME of the configuration's work function, and this model's draw and
-feature gather are the fanout ones `kernel_work.sage` counts from
-`fanouts` (a feature row then counts `feature_dim` stored bytes: 768).
+The function is named `sage` for the fanout draw it counts under, as
+`work_gat.py`'s is. The table kernels' row counts are not here: this
+model's draw and feature gather are the fanout ones, so its
+configuration names `kernel_work.sage` under `kernels` (a feature row
+then counts `feature_dim` stored bytes: 768).
 
 Every layer is a hidden one (heads concatenated, `heads * dim` wide) and
 an MLP head `Dense(head_dim) - norm - ReLU - Dense(classes)` follows on

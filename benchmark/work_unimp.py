@@ -1,11 +1,10 @@
 """What one training step of the UniMP configuration has to compute and
 to move, from shapes alone, by the rules of work.py.
 
-**The function is named `sage` on purpose**, as work_gat.py's is:
-`kernel_work.for_config` looks the table kernels' row counts up by the
-NAME of the configuration's work function, and this model's draw and
-feature gather are the fanout ones `kernel_work.sage` counts from
-`fanouts` (kernel_work.py is not this file's to edit).
+The function is named `sage` for the fanout draw it counts under, as
+work_gat.py's is. The table kernels' row counts are not here: this
+model's draw and feature gather are the fanout ones, so its
+configuration names `kernel_work.sage` under `kernels`.
 
 FLOPs, 2 m k n a matrix product forward: the label embedding
 `onehot(y) W_d` of every sampled row of hops 1..L; a layer's four

@@ -12,7 +12,10 @@ correct here as they do on the chip at the cells' own size (PERF.md).
 """
 
 import argparse
+import contextlib
+import importlib
 import json
+import numbers
 import os
 import re
 import shutil
@@ -28,7 +31,10 @@ sys.path.insert(0, str(ROOT))
 from benchmark import (  # noqa: E402
     check, readers, reduce_trace, run, tables, work,
 )
-from benchmark.cell import load_config, resolve  # noqa: E402
+from benchmark.cell import (  # noqa: E402
+    load_config, resolve, resolve_functions,
+)
+from benchmark.reference import common  # noqa: E402
 from benchmark.traffic import RootSource, load_traffic  # noqa: E402
 
 TINY = str(Path(__file__).resolve().parent / "tiny")
@@ -238,6 +244,32 @@ def test_the_model_fields_are_bound_before_any_table_is_made(
             raises=TypeError, match=field) == 0
 
 
+@pytest.mark.parametrize("key,value,match", [
+    ("kernels", None, "names no kernels"),
+    ("kernels", "benchmark.kernel_work.no_such_count", "no_such_count"),
+    ("kernels", "benchmark.no_such_module.sage", "no_such_module"),
+    ("work", "benchmark.work.no_such_work", "no_such_work"),
+    ("reference", "benchmark.reference.common", "param_shapes")],
+    ids=["kernels_missing", "kernels_no_function", "kernels_no_module",
+         "work_no_function", "reference_without_param_shapes"])
+def test_the_named_functions_are_resolved_before_any_table_is_made(
+        key, value, match, tmp_path, monkeypatch):
+    """A function the configuration's file names that does not exist
+    (its table kernels' counts, its work counts, its reference's
+    functions) stops the run before its tables, not in a traced run's
+    readers after them."""
+    cfg = load_config(str(ROOT / "benchmark"), "sage3-papers100m-s32")
+    if value is None:
+        del cfg[key]
+    else:
+        cfg[key] = value
+    workload = next(w["name"] for w in BENCH["workloads"]
+                    if w["config"] == cfg["name"])
+    assert tables_made_by_a_run(
+        workload, cells_dir_with(tmp_path, cfg), monkeypatch,
+        raises=LookupError, match=match) == 0
+
+
 def test_no_tpu_means_no_result(capsys):
     with pytest.raises(SystemExit) as e:
         run.run_cell(BENCH, argparse.Namespace(
@@ -342,6 +374,44 @@ def test_a_recorded_trace_is_read(tmp_path):
     assert reduce_trace.reduce(planes)["busy_s"] is None
 
 
+CONFIG_FILES = sorted((ROOT / "benchmark" / "configs").glob("*.json")) \
+    + sorted((Path(TINY) / "configs").glob("*.json"))
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES, ids=lambda p: p.stem)
+def test_the_weights_are_lecun_normal_where_the_reference_seeds_none(path):
+    """No reference of these configurations defines `init_weights`: the
+    weights are common.lecun_normal's from the seed's weight stream, bit
+    for bit what they were before a reference could seed its own."""
+    cfg = json.loads(path.read_text())
+    shapes = resolve(cfg["reference"] + ".param_shapes")(cfg)
+    want = common.lecun_normal(np.random.default_rng([SEED, 1 << 22]),
+                               shapes)
+    got = check.make_weights(cfg, SEED)
+    assert "init_weights" not in resolve_functions(cfg)
+    assert list(got) == list(want)
+    for leaf, w in want.items():
+        assert got[leaf].dtype == w.dtype == np.float32, leaf
+        assert np.array_equal(got[leaf], w), leaf
+
+
+def test_a_reference_that_seeds_its_own_weights_is_used(tmp_path):
+    root = _later_tree(tmp_path)
+    bench = _add_pools_cell(root, json.loads(json.dumps(BENCH)))
+    cfg = json.loads((root / bench["configs"][-1]["file"]).read_text())
+    with modules_of(root):
+        ref = importlib.import_module(cfg["reference"])
+        shapes = ref.param_shapes(cfg)
+        want = ref.init_weights(
+            cfg, np.random.default_rng([SEED, 1 << 22]), shapes)
+        got = check.make_weights(cfg, SEED)
+        assert list(got) == list(want)
+        for leaf, w in want.items():
+            assert np.array_equal(got[leaf], w), leaf
+        # ... which is not what common.lecun_normal would make
+        assert np.any(got["out/bias"] != 0)
+
+
 # -- the data the harness is driven by ----------------------------------------
 def test_tables_do_not_depend_on_the_threads(monkeypatch):
     kw = dict(n_nodes=3000, feat_dim=8, cap=4, num_classes=5,
@@ -390,11 +460,54 @@ def test_reference_quantisation_is_the_stores():
     assert np.array_equal(scale, s2.astype(bf16))
 
 
+@contextlib.contextmanager
+def modules_of(root: Path):
+    """The modules a later tree at `root` adds under `benchmark/` and
+    `benchmark/reference/` importable by their dotted names while it
+    lasts, after the repo's own, as a checkout of that tree has them;
+    dropped again at its end."""
+    if root.resolve() == ROOT:
+        yield
+        return
+    import benchmark.reference
+
+    pkgs = {benchmark: root / "benchmark",
+            benchmark.reference: root / "benchmark" / "reference"}
+    for pkg, d in pkgs.items():
+        pkg.__path__.append(str(d))
+    importlib.invalidate_caches()
+    before = set(sys.modules)
+    try:
+        yield
+    finally:
+        for pkg, d in pkgs.items():
+            pkg.__path__.remove(str(d))
+        for name in set(sys.modules) - before:
+            file = getattr(sys.modules[name], "__file__", None)
+            if name.startswith("benchmark.") and file and Path(
+                    file).resolve().is_relative_to(root.resolve()):
+                del sys.modules[name]
+                parent, _, leaf = name.rpartition(".")
+                if getattr(sys.modules[parent], leaf, None) is not None:
+                    delattr(sys.modules[parent], leaf)
+
+
 def keeps_to_the_contract(bench: dict, root: Path) -> None:
     """The rules BENCHMARK.json keeps, as a function of its dict and of
     the tree its files lie in (`root`), so that the file as a later PR
     leaves it, with a metric or a cell appended, is held to them as
-    they stand."""
+    they stand; the functions its files name are looked up in that
+    tree."""
+    with modules_of(root):
+        _keeps_to_the_contract(bench, root)
+
+
+def _positive_int(v) -> bool:
+    return isinstance(v, numbers.Integral) and not isinstance(v, bool) \
+        and v > 0
+
+
+def _keeps_to_the_contract(bench: dict, root: Path) -> None:
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= bench["run_seconds"] <= 51
@@ -455,6 +568,19 @@ def keeps_to_the_contract(bench: dict, root: Path) -> None:
         assert cfg["published"]["num_nodes"] // cfg.get("row_share", 32) \
             == cfg["num_nodes"]
         assert {"assumed", "deployment", "limits"} <= set(cfg)
+        # every function the file names exists; the table kernels' counts
+        # are rows and row bytes at each of its cells' batch and weights
+        kernels = resolve_functions(cfg)["kernels"]
+        for w in bench["workloads"]:
+            if w["config"] != c["name"]:
+                continue
+            mix = load_traffic(str(root / "benchmark"), w["traffic"])
+            counts = kernels(cfg, int(mix["root_batch"]),
+                             mix["edge_weights"]["kind"] != "unit")
+            for k in ("gather", "draw"):
+                rows, row_bytes = counts[k]
+                assert _positive_int(rows) and _positive_int(row_bytes), \
+                    (c["name"], k, counts[k])
     for p in bench["paths"]:
         for f in (root / p).rglob("*"):
             if "__pycache__" in f.parts or f.suffix == ".pyc":
@@ -512,17 +638,100 @@ def _add_cell(root: Path, bench: dict, row_share) -> dict:
                 "traffic": "unit-b1024", "chips": 1, "why": "test"}]}
 
 
-@pytest.mark.parametrize("append", ["metric", "cell_and_metric"])
+POOLS_CELL = "pools-papers100m-s32.unit-b1024"
+# what a later PR would add as files of its own: the table kernels' counts
+# and the work counts of a draw that is no fanout (a pool of sampled nodes
+# a layer), and a reference that seeds its own weights
+POOLS_WORK = '''"""Counts of the pools configuration: a pool of sampled
+nodes a layer, no fanout."""
+
+
+def kernels(cfg, batch, weighted):
+    pools = [batch, *cfg["model"]["kwargs"]["layer_sizes"]]
+    draw_row = cfg["cap"] * 4 * (2 if weighted else 1)
+    return {"gather": (sum(pools), cfg["feature_dim"]),
+            "draw": (sum(pools[:-1]), draw_row)}
+
+
+def work(cfg, batch, weighted):
+    return {"flops": 1, "bytes": 1}
+'''
+POOLS_REFERENCE = '''"""The pools configuration's reference: it seeds its
+own weights, biases too."""
+
+import numpy as np
+
+
+def param_shapes(cfg):
+    dim = cfg["model"]["kwargs"]["dim"]
+    return {"encoder/layer_0/kernel": (cfg["feature_dim"], dim),
+            "encoder/layer_0/bias": (dim,),
+            "out/kernel": (dim, cfg["num_classes"]),
+            "out/bias": (cfg["num_classes"],)}
+
+
+def init_weights(cfg, rng, shapes):
+    return {p: rng.uniform(-0.1, 0.1, shapes[p]).astype(np.float32)
+            for p in sorted(shapes)}
+
+
+def init_extra(cfg, rows):
+    return {}
+
+
+def loss(params, extra, tables, roots, sample_seed, cfg, uniform, dtype):
+    raise NotImplementedError
+'''
+
+
+def _add_pools_cell(root: Path, bench: dict, kernels=True) -> dict:
+    """A configuration whose model takes no `fanouts`, with its own
+    `kernels` function and a reference with `init_weights` in files of
+    their own, and its cell. `kernels`: True names the function, None
+    leaves the key out, a string is named in its place."""
+    (base,) = [c for c in bench["configs"]
+               if c["name"] == "sage3-papers100m-s32"]
+    cfg = json.loads((root / base["file"]).read_text())
+    cfg["name"] = POOLS_CELL.split(".")[0]
+    cfg["model"] = {"class": "euler_tpu.models.DeviceSampledLayerwiseGCN",
+                    "kwargs": {"dim": 256, "layer_sizes": [512, 512],
+                               "num_classes": 172}}
+    (root / "benchmark" / "work_pools_in_test.py").write_text(POOLS_WORK)
+    (root / "benchmark" / "reference" / "pools_in_test.py").write_text(
+        POOLS_REFERENCE)
+    cfg["work"] = "benchmark.work_pools_in_test.work"
+    cfg["reference"] = "benchmark.reference.pools_in_test"
+    del cfg["kernels"]
+    if kernels is not None:
+        cfg["kernels"] = "benchmark.work_pools_in_test.kernels" \
+            if kernels is True else kernels
+    file = f"benchmark/configs/{cfg['name']}.json"
+    (root / file).write_text(json.dumps(cfg))
+    return {**bench,
+            "configs": bench["configs"] + [{**base, "name": cfg["name"],
+                                            "file": file}],
+            "workloads": bench["workloads"] + [{
+                "name": POOLS_CELL, "config": cfg["name"],
+                "traffic": "unit-b1024", "chips": 1, "why": "test"}]}
+
+
+@pytest.mark.parametrize("append", ["metric", "cell_and_metric",
+                                    "pools_cell"])
 def test_a_later_append_keeps_to_the_contract(tmp_path, append):
     """What a later PR adds, with no existing file or test edited: a
     metric with its file and the cell it is read in; or a configuration
-    that states its own row share, its cell, and a metric of that cell."""
+    that states its own row share, its cell, and a metric of that cell;
+    or a configuration whose model takes no `fanouts`, with its own
+    table kernels' counts and a reference that seeds its own weights."""
     root = _later_tree(tmp_path)
     bench = json.loads(json.dumps(BENCH))
     cells = ["sagelstm2-papers100m-s32.unit-b1024"]
     if append == "cell_and_metric":
         bench = _add_cell(root, bench, row_share=8)
         cells = [LATER_CELL]
+    elif append == "pools_cell":
+        bench = _add_pools_cell(root, bench)
+        cells = [POOLS_CELL]
     bench = _add_metric(root, bench, "later_ms", cells)
     keeps_to_the_contract(bench, root)
     # the tests that pin the list's head and the set-up four still hold
@@ -534,12 +743,18 @@ def test_a_later_append_keeps_to_the_contract(tmp_path, append):
 
 @pytest.mark.parametrize("fault", [
     "file_without_entry", "entry_without_file", "unknown_cell",
-    "empty_cells", "share_not_stated"])
+    "empty_cells", "share_not_stated", "kernels_missing",
+    "kernels_no_function"])
 def test_an_append_that_breaks_the_contract_is_refused(tmp_path, fault):
     root = _later_tree(tmp_path)
     bench = json.loads(json.dumps(BENCH))
     if fault == "share_not_stated":
         bench = _add_cell(root, bench, row_share=None)
+    elif fault == "kernels_missing":
+        bench = _add_pools_cell(root, bench, kernels=None)
+    elif fault == "kernels_no_function":
+        bench = _add_pools_cell(
+            root, bench, kernels="benchmark.work_pools_in_test.no_such")
     else:
         bench = _add_metric(
             root, bench, "later_ms",
@@ -548,5 +763,5 @@ def test_an_append_that_breaks_the_contract_is_refused(tmp_path, fault):
                 fault, ["sagelstm2-papers100m-s32.unit-b1024"]),
             entry=fault != "file_without_entry",
             file=fault != "entry_without_file")
-    with pytest.raises((AssertionError, FileNotFoundError)):
+    with pytest.raises((AssertionError, FileNotFoundError, LookupError)):
         keeps_to_the_contract(bench, root)

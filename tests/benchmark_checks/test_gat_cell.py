@@ -121,6 +121,7 @@ def test_the_control_and_the_faults_fail_the_tiny_limits():
 # -- the yardstick's arithmetic ---------------------------------------------
 _HAND = {"feature_dim": 8, "num_classes": 3, "cap": 4,
          "feature_storage": "int8", "work": "benchmark.work_gat.sage",
+         "kernels": "benchmark.kernel_work.sage",
          "model": {"kwargs": {"dim": 2, "heads": 2, "fanouts": [2, 3]}}}
 
 

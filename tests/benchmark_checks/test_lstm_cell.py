@@ -212,6 +212,7 @@ def test_each_planted_control_fails_the_tiny_limits(tiny_reference, name):
 # -- the yardstick's arithmetic ---------------------------------------------
 _HAND = {"feature_dim": 8, "num_classes": 3, "cap": 4, "lstm_hidden": 2,
          "feature_storage": "int8", "work": "benchmark.work_lstm.sage",
+         "kernels": "benchmark.kernel_work.sage",
          "model": {"kwargs": {"dim": 2, "fanouts": [2, 3]}}}
 
 

@@ -261,6 +261,7 @@ def test_the_reference_with_pair_by_pair_statistics_fails_the_tiny_limits(
 # -- the yardstick's arithmetic ---------------------------------------------
 _HAND = {"feature_dim": 8, "num_classes": 3, "cap": 4,
          "feature_storage": "int8", "work": "benchmark.work_mag.sage",
+         "kernels": "benchmark.kernel_work.sage",
          "model": {"kwargs": {"dim": 2, "heads": 2, "fanouts": [2, 3],
                               "head_dim": 6}}}
 
@@ -298,7 +299,7 @@ def test_work_counts_match_hand_sums():
     cfg = load_config(TINY, "gat2-tiny")
     assert sum(int(np.prod(s)) for s in gat2bn.param_shapes(
         {**_HAND, "model": _HAND["model"]}).values()) == n_params
-    # named `sage`: the table kernels' counts are the fanout model's,
+    # `kernels` names the fanout model's table counts,
     # and a feature row counts its 256 (tiny) or 768 stored bytes
     assert kernel_work.for_config(cfg) is kernel_work.sage
     assert kernel_work.sage(cfg, 64, False)["gather"] \

@@ -127,6 +127,34 @@ def test_scope_times_by_hand_charge_every_operation_once(monkeypatch):
     assert scope_readers.feeder_produce_ms(ctx) == pytest.approx(2.0)
 
 
+def pool_kernels(cfg, batch, weighted):
+    """Table counts of a configuration whose draw is no fanout: a pool
+    of sampled nodes a layer, each pool's rows gathered, each but the
+    last drawn from."""
+    pools = [batch, *cfg["model"]["kwargs"]["layer_sizes"]]
+    return {"gather": (sum(pools), cfg["feature_dim"]),
+            "draw": (sum(pools[:-1]), cfg["cap"] * 4 * (2 if weighted
+                                                         else 1))}
+
+
+def test_the_table_shares_read_the_configurations_own_kernels(monkeypatch):
+    ctx = _ctx(monkeypatch, _planes())
+    cfg = ctx["cfg"] = {**ctx["cfg"],
+                        "kernels": "test_scope_readers.pool_kernels",
+                        "model": {"kwargs": {"dim": 8,
+                                             "layer_sizes": [40, 20]}}}
+    assert kernel_work.for_config(cfg) is pool_kernels
+    steps = 2 * 2
+    # pools of 64, 40 and 20 rows; draws from the first two
+    assert scope_readers.gather_hbm_pct(ctx) == pytest.approx(
+        100 * 124 * cfg["feature_dim"] / (8e-3 / steps) / 1e6)
+    assert scope_readers.draw_hbm_pct(ctx) == pytest.approx(
+        100 * 104 * cfg["cap"] * 4 / (30e-3 / steps) / 1e6)
+    # the fanout model's counts could not have been read here
+    with pytest.raises(KeyError, match="fanouts"):
+        kernel_work.sage(cfg, 64, False)
+
+
 @pytest.mark.parametrize("name,scope", [
     (FWD + "draw/hop3/jit(_take)/gather:", "draw"),
     (FWD + "encoder/gather/hop0/mul:", "gather"),
@@ -194,6 +222,7 @@ def test_readers_find_nothing_where_nothing_is(monkeypatch):
 def test_kernel_work_matches_hand_sums():
     cfg = {"feature_dim": 8, "cap": 4, "feature_storage": "int8",
            "work": "benchmark.work.sage",
+           "kernels": "benchmark.kernel_work.sage",
            "model": {"kwargs": {"dim": 2, "fanouts": [2, 3]}}}
     # hops of 5, 10, 30 rows
     assert kernel_work.for_config(cfg) is kernel_work.sage
@@ -201,6 +230,7 @@ def test_kernel_work_matches_hand_sums():
         "gather": (45, 8), "draw": (15, 16)}
     assert kernel_work.sage(cfg, 5, weighted=True)["draw"] == (15, 32)
     cfg["work"] = "benchmark.work.scalablesage"
+    cfg["kernels"] = "benchmark.kernel_work.scalablesage"
     cfg["model"]["kwargs"] = {"dim": 2, "fanout": 3, "num_layers": 2}
     assert kernel_work.for_config(cfg) is kernel_work.scalablesage
     assert kernel_work.scalablesage(cfg, 5, weighted=False) == {

@@ -159,6 +159,7 @@ def test_the_label_input_switched_off_fails_the_tiny_limits(tiny_reference):
 # -- the yardstick's arithmetic ---------------------------------------------
 _HAND = {"feature_dim": 8, "num_classes": 3, "cap": 4,
          "feature_storage": "int8", "work": "benchmark.work_unimp.sage",
+         "kernels": "benchmark.kernel_work.sage",
          "model": {"kwargs": {"dim": 2, "heads": 2, "fanouts": [2, 3]}}}
 
 
