@@ -276,8 +276,15 @@ def _attend(z_t: Array, z_s: Array, e_self: Array, e_nbr: Array,
     itself (`_slot_softmax`). z_t [M, H*C], z_s [k, M, H*C] projected
     rows; e_self [H, M], e_nbr [H, k, M] the logits; mask bool [k, M].
     -> [M, H*C] (heads concatenated) or [M, C] (their mean)."""
-    heads = e_self.shape[0]
     a_self, a_nbr = _slot_softmax(e_nbr, mask, e_self)
+    return _weighted_sum(z_t, z_s, a_self, a_nbr, concat)
+
+
+def _weighted_sum(z_t: Array, z_s: Array, a_self: Array, a_nbr: Array,
+                  concat: bool) -> Array:
+    """`_attend`'s sum of the rows under the weights a_self [H, M] and
+    a_nbr [H, k, M], a head at a time."""
+    heads = a_self.shape[0]
     c = z_t.shape[-1] // heads
     outs = []
     for h in range(heads):
@@ -367,7 +374,14 @@ class GATLayer(nn.Module):
     `HopBatchNorm` before its ELU, ONE for the layer: its statistics span
     every hop the layer writes (OGB-LSC's MAG240M baseline). Scopes `proj`, `attn`, `skip` (and `norm`) under
     the layer's name; counted at trace time, one a layer whatever its
-    hops: `traced_paths_total{path="gat_attention",detail=<layer>}`."""
+    hops: `traced_paths_total{path="gat_attention",detail=<layer>}`.
+
+    On the TPU, where a head's width C is a multiple of 128 and a block of
+    targets fits VMEM (`ops/gat_pair.row_block`), a pair's attention is
+    `ops/gat_pair.attend`: the same forward, its backward one Pallas
+    kernel that reads the source rows once and writes their cotangent
+    once; counted at trace time, one a pair:
+    `traced_paths_total{path="gat_attention_kernel",detail=<M>x<k>x<H*C>}`."""
 
     width: int          # C, one head's
     heads: int
@@ -378,6 +392,8 @@ class GATLayer(nn.Module):
     def __call__(self, hidden: Sequence[Array],
                  masks: Sequence[Optional[Array]]) -> list:
         from euler_tpu import obs
+        from euler_tpu.ops import gat_pair
+        from euler_tpu.utils import aggregators
 
         h, c = self.heads, self.width
         out_dim = h * c if self.concat else c
@@ -385,9 +401,10 @@ class GATLayer(nn.Module):
         normed = self.batch_norm and self.concat
         proj = nn.Dense(h * c, use_bias=False, name="proj")
         zs = [proj(x) for x in hidden]
-        att = jnp.concatenate(
-            [_block_diagonal(_HeadVectors(c, h, name=name)())
-             for name in ("att_src", "att_dst")], axis=1)     # [H*C, 2H]
+        att_src, att_dst = (_HeadVectors(c, h, name=name)()
+                            for name in ("att_src", "att_dst"))
+        att = jnp.concatenate([_block_diagonal(att_src),
+                               _block_diagonal(att_dst)], axis=1)  # [H*C, 2H]
         bias = self.param("bias", nn.initializers.zeros, (out_dim,))
         skip = nn.Dense(out_dim, name="skip")
         obs.traced_path("gat_attention", self.name or "")
@@ -404,14 +421,24 @@ class GATLayer(nn.Module):
             k = z_s.shape[0] // m
             with jax.named_scope("attn"):
                 src, dst = scores[hop][:h], scores[hop][h:]
-                e_self = nn.leaky_relu(src + dst, 0.2)
-                e_nbr = nn.leaky_relu(
-                    scores[hop + 1][:h].reshape(h, k, m) + dst[:, None],
-                    0.2)
                 mask = masks[hop + 1]
-                y = _attend(z_t, z_s.reshape(k, m, h * c), e_self, e_nbr,
-                            None if mask is None else mask.reshape(k, m),
-                            self.concat) + bias
+                if aggregators._on_tpu() and gat_pair.row_block(
+                        k, m, h * c, h, out_dim):
+                    obs.traced_path("gat_attention_kernel",
+                                    f"{m}x{k}x{h * c}")
+                    y = gat_pair.attend(
+                        z_t, z_s.reshape(k, m, h * c), src, dst, att_src,
+                        None if mask is None else mask.reshape(k, m),
+                        self.concat)
+                else:
+                    e_self = nn.leaky_relu(src + dst, 0.2)
+                    e_nbr = nn.leaky_relu(
+                        scores[hop + 1][:h].reshape(h, k, m) + dst[:, None],
+                        0.2)
+                    y = _attend(z_t, z_s.reshape(k, m, h * c), e_self, e_nbr,
+                                None if mask is None else mask.reshape(k, m),
+                                self.concat)
+                y = y + bias
             with jax.named_scope("skip"):
                 y = y + skip(x)
                 out.append(nn.elu(y) if self.concat and not normed else y)

@@ -3,7 +3,8 @@
 The TPU compiler is installed here and compiles for a DESCRIBED v5e:2x2
 topology (guide on-chip-measurement §2, rehearsal 3): the trainer's real
 scanned step at chip_smoke.py's phase-A widths, the act-cache step, the
-GraphSAGE-LSTM step with its recurrence kernels, the row-sharded step on
+GraphSAGE-LSTM step with its recurrence kernels, the GAT cells' steps
+with their attention pairs' backward kernels, the row-sharded step on
 a 2x2 mesh (tier-1 at 4,096 roots a step over the whole-size tables,
 `slow` at the whole batch), both table exchanges and the gather-mean
 Pallas kernel. A compile that passes is not a chip run — chip_smoke.py is.
@@ -93,15 +94,12 @@ def _abstract_tables(c, rows, table_sharding, small_sharding):
     return store, sampler
 
 
-def _compile_scanned_step(est, small_sharding):
-    """Lower + compile the estimator's jitted model.init and its REAL
-    scanned train loop (BaseEstimator._build_train_loop, the program
-    _run_looped dispatches) from abstract shapes placed on described
-    devices."""
+def _abstract_state(est):
+    """Set the estimator's TrainState to its shapes; -> one step's batch
+    of roots, as shapes."""
     from euler_tpu.estimator.base_estimator import TrainState, _merged
 
-    b = est.batch_size
-    one = {"rows": [jax.ShapeDtypeStruct((b,), jnp.int32)],
+    one = {"rows": [jax.ShapeDtypeStruct((est.batch_size,), jnp.int32)],
            "sample_seed": jax.ShapeDtypeStruct((), jnp.uint32)}
 
     def make_state(batch):
@@ -113,6 +111,18 @@ def _compile_scanned_step(est, small_sharding):
             skipped_steps=jnp.zeros((), jnp.int32))
 
     est.state = jax.eval_shape(make_state, _merged(one, est.static_batch))
+    return one
+
+
+def _compile_scanned_step(est, small_sharding):
+    """Lower + compile the estimator's jitted model.init and its REAL
+    scanned train loop (BaseEstimator._build_train_loop, the program
+    _run_looped dispatches) from abstract shapes placed on described
+    devices."""
+    from euler_tpu.estimator.base_estimator import _merged
+
+    b = est.batch_size
+    one = _abstract_state(est)
     # the program _init_state dispatches before the first step
     key = jax.eval_shape(lambda: jax.random.key(0))
     jax.jit(est.model.init).lower(
@@ -334,6 +344,121 @@ def test_lstm_kernels_fit_vmem_or_leave_it_to_the_scan(
     text = jax.jit(grad).lower(kernel, bias, nbr, mask).compile().as_text()
     assert taken.value == count + (block is not None)
     assert ("tpu_custom_call" in text) == (block is not None)
+
+
+def _gat_estimator(one_chip, config):
+    """A GAT cell's configuration (its model, widths and row share) in a
+    NodeEstimator of 1,024 roots over tables placed on the described chip,
+    its state as shapes; -> (the estimator, one step's roots)."""
+    import json
+
+    from euler_tpu.estimator import NodeEstimator
+    from euler_tpu.models import DeviceSampledGraphSage
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                      / "configs" / f"{config}.json").read_text())
+    c = {"feat_dim": cfg["feature_dim"], "num_classes": cfg["num_classes"],
+         "cap": cfg["cap"], "n_nodes": cfg["num_nodes"]}
+    store, sampler = _abstract_tables(c, c["n_nodes"] + 1, one_chip,
+                                      one_chip)
+    model = DeviceSampledGraphSage(**cfg["model"]["kwargs"],
+                                   uniform_sampling=True)
+    est = NodeEstimator(
+        model,
+        dict(batch_size=1024, learning_rate=0.001, optimizer="adam",
+             label_dim=c["num_classes"], steps_per_loop=SPL),
+        None, None, label_fid="label", label_dim=c["num_classes"],
+        feature_store=store, device_sampler=sampler)
+    return est, _abstract_state(est)
+
+
+@pytest.mark.parametrize("config, reader, kernels", [
+    ("gat3-papers100m-s32", "gat_readers",
+     {"1024x10x512": 2, "10240x10x512": 2, "102400x10x512": 1}),
+    ("gat2-mag240m-s32", "mag_readers",
+     {"1024x25x1024": 2, "25600x15x1024": 1}),
+], ids=["cell4", "cell6"])
+def test_gat_pair_kernels_lower_in_the_cells_backward(one_chip, monkeypatch,
+                                                      config, reader,
+                                                      kernels):
+    """(e) A GAT cell's step as a process on the chip traces it
+    (`aggregators._on_tpu` says so): each (target hop, source hop) pair
+    whose head width is a multiple of 128 is counted once and is one
+    Pallas call in the backward, under its layer's `attn` scope, which
+    the benchmark's readers charge to `encode` and to the attention
+    (`attn_ms` in cell 4, `wideattn_ms` in cell 6); cell 4's last layer
+    (C = 172) keeps the XLA path."""
+    import importlib
+
+    from benchmark import scope_readers
+    from euler_tpu import obs
+    from euler_tpu.estimator.base_estimator import _merged
+    from euler_tpu.utils import aggregators
+
+    part_of = importlib.import_module(f"benchmark.{reader}").part_of
+    monkeypatch.setattr(aggregators, "_on_tpu", lambda: True)
+    paths = obs.counter("traced_paths_total", "", ("path", "detail"))
+
+    def taken():
+        return {d: paths.labels(path="gat_attention_kernel", detail=d).value
+                for d in kernels}
+
+    est, one = _gat_estimator(one_chip, config)
+    before = taken()
+    text = jax.jit(est._make_one_step()).lower(
+        _with_sharding(est.state, one_chip),
+        _merged(_with_sharding(one, one_chip), est.static_batch)).as_text(
+        debug_info=True)
+    assert {d: n - before[d] for d, n in taken().items()} == kernels
+    where = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    names = [where[re.findall(r"loc\((#loc\d+)\)", line)[-1]]
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(names) == sum(kernels.values()), names
+    for name in names:
+        assert re.search(r"transpose\(jvp\(.*/encoder/enc/layer[01]/attn/"
+                         r"gat_pair_bwd/", name), name
+        assert scope_readers.scope_of(name) == "encode", name
+        assert part_of(name) in ("attn", "wideattn"), name
+
+
+@pytest.mark.parametrize("slots, rows, width, concat, block", [
+    (10, 102400, 128, True, 512),    # cell 4's layer 0, hop 3 into hop 2
+    (10, 10240, 128, True, 512),     # its hop 2 into hop 1
+    (15, 25600, 256, True, 256),     # cell 6's layer 0, hop 2 into hop 1
+    (25, 1024, 256, True, 128),      # cell 6's roots
+    (10, 1024, 172, False, None),    # cell 4's last layer: C = 172
+    (60, 1024, 256, True, None),     # no block fits
+], ids=["cell4_hop3", "cell4_hop2", "cell6_hop2", "cell6_roots",
+        "cell4_last", "too_many_slots"])
+def test_gat_pair_kernel_fits_vmem_or_leaves_it_to_xla(one_chip, slots, rows,
+                                                       width, concat, block):
+    """(e') The pair's value and gradients at a cell's shapes, 4 heads:
+    where `row_block` finds a block, its `vmem_bytes` (the block's
+    float32 slots and their cotangent, each double-buffered) is within
+    the budget and Mosaic accepts the backward at it, one call; where
+    none is found the layer keeps the XLA path."""
+    from euler_tpu.ops import gat_pair
+
+    heads = 4
+    lanes, out = heads * width, heads * width if concat else width
+    assert gat_pair.row_block(slots, rows, lanes, heads, out) == block
+    if block is None:
+        return
+    assert gat_pair.vmem_bytes(block, slots, lanes, heads, out) \
+        <= gat_pair.VMEM_BUDGET < gat_pair.VMEM_LIMIT
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(z_t, z_s, t_src, t_dst, att, mask, w):
+        return (gat_pair.attend(z_t, z_s, t_src, t_dst, att, mask, concat)
+                * w).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        sds((rows, lanes)), sds((slots, rows, lanes)), sds((heads, rows)),
+        sds((heads, rows)), sds((width, heads)), sds((slots, rows), jnp.bool_),
+        sds((rows, out))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
 
 
 # The compile's time grows with the batch (my runs, PR 28, this sandbox
