@@ -558,7 +558,14 @@ class TransformerConvLayer(nn.Module):
     softmax runs with M on the lanes whatever C is. Scopes `qkv` (the
     four projections), `attn`, `gate` (gate, norm, ReLU) under the
     layer's name; counted at trace time, one a layer whatever its hops:
-    `traced_paths_total{path="unimp_attention",detail=<layer>}`."""
+    `traced_paths_total{path="unimp_attention",detail=<layer>}`.
+
+    On the TPU, where H*C is a multiple of 128 and a block of targets
+    fits VMEM (`ops/dot_pair.row_block`), a pair's attention is
+    `ops/dot_pair.attend`: the same function at the same rounding, each
+    pass one Pallas kernel that reads the keys and values once and
+    writes their cotangents once; counted at trace time, one a pair:
+    `traced_paths_total{path="unimp_attention_kernel",detail=<M>x<k>x<H*C>}`."""
 
     width: int          # C, one head's
     heads: int
@@ -568,6 +575,8 @@ class TransformerConvLayer(nn.Module):
     def __call__(self, hidden: Sequence[Array],
                  masks: Sequence[Optional[Array]]) -> list:
         from euler_tpu import obs
+        from euler_tpu.ops import dot_pair
+        from euler_tpu.utils import aggregators
 
         h, c = self.heads, self.width
         out_dim = h * c if self.concat else c
@@ -590,16 +599,16 @@ class TransformerConvLayer(nn.Module):
             k = k_s.shape[0] // m
             with jax.named_scope("attn"):
                 k_s, v_s = k_s.reshape(k, m, h * c), v_s.reshape(k, m, h * c)
-                # [H, k, M], M minor: every head's q . k of every slot
-                e = jnp.einsum("fg,kmf->gkm", head_of, q[None] * k_s) \
-                    / math.sqrt(c)
                 mask = masks[hop + 1]
-                _, alpha = _slot_softmax(
-                    e, None if mask is None else mask.reshape(k, m),
-                    jnp.full((h, m), _NO_SELF, e.dtype))
-                # a head's weight on each of its C lanes, then the sum
-                msg = (jnp.einsum("gkm,fg->kmf", alpha, head_of)
-                       * v_s).sum(axis=0)
+                if aggregators._on_tpu() and dot_pair.row_block(
+                        k, m, h * c, h):
+                    obs.traced_path("unimp_attention_kernel",
+                                    f"{m}x{k}x{h * c}")
+                    msg = dot_pair.attend(
+                        q, k_s, v_s,
+                        None if mask is None else mask.reshape(k, m), h)
+                else:
+                    msg = _dot_attend(q, k_s, v_s, mask, head_of)
                 if not self.concat:
                     msg = msg.reshape(m, h, c).mean(axis=1)
             with jax.named_scope("gate"):
@@ -608,6 +617,27 @@ class TransformerConvLayer(nn.Module):
                 o = b * r + (1.0 - b) * msg
                 out.append(nn.relu(norm(o)) if self.concat else o)
         return out
+
+
+def _dot_attend(q: Array, k_s: Array, v_s: Array, mask: Optional[Array],
+                head_of: Array) -> Array:
+    """`TransformerConvLayer`'s attention of one pair: q [M, H*C] the
+    targets' queries, k_s and v_s [k, M, H*C] their slots' keys and
+    values, mask bool [k, M] (or its k * M rows flat) or None, head_of
+    [H*C, H] the heads' 0/1 indicator (`_block_diagonal`) -> [M, H*C],
+    every head's softmax over the slots of q . k / sqrt(C) weighing its
+    lanes of the values. The per-head sum and the weights' spread over a
+    head's lanes are products with the indicator."""
+    k, m, lanes = k_s.shape
+    heads = head_of.shape[1]
+    # [H, k, M], M minor: every head's q . k of every slot
+    e = jnp.einsum("fg,kmf->gkm", head_of, q[None] * k_s) \
+        / math.sqrt(lanes // heads)
+    _, alpha = _slot_softmax(
+        e, None if mask is None else mask.reshape(k, m),
+        jnp.full((heads, m), _NO_SELF, e.dtype))
+    # a head's weight on each of its C lanes, then the sum
+    return (jnp.einsum("gkm,fg->kmf", alpha, head_of) * v_s).sum(axis=0)
 
 
 class UniMPEncoder(_AttentionEncoder):

@@ -4,7 +4,8 @@ The TPU compiler is installed here and compiles for a DESCRIBED v5e:2x2
 topology (guide on-chip-measurement §2, rehearsal 3): the trainer's real
 scanned step at chip_smoke.py's phase-A widths, the act-cache step, the
 GraphSAGE-LSTM step with its recurrence kernels, the GAT cells' steps
-with their attention pairs' backward kernels, the row-sharded step on
+with their attention pairs' backward kernels, the UniMP cell's step with
+its pairs' kernels, the row-sharded step on
 a 2x2 mesh (tier-1 at 4,096 roots a step over the whole-size tables,
 `slow` at the whole batch), both table exchanges and the gather-mean
 Pallas kernel. A compile that passes is not a chip run — chip_smoke.py is.
@@ -421,6 +422,59 @@ def test_gat_pair_kernels_lower_in_the_cells_backward(one_chip, monkeypatch,
         assert part_of(name) in ("attn", "wideattn"), name
 
 
+def test_unimp_pair_kernels_lower_in_the_cells_step(one_chip, monkeypatch):
+    """(e'') Cell 5's step as a process on the chip traces it: each of
+    its five (target hop, source hop) pairs of 128 projected lanes is
+    counted once and is one Pallas call a pass, under its layer's `attn`
+    scope, which `benchmark/unimp_readers` charges to `dotattn`; the last
+    layer's 688 lanes keep the XLA path."""
+    from benchmark import scope_readers, unimp_readers
+    from euler_tpu import obs
+    from euler_tpu.estimator.base_estimator import _merged
+    from euler_tpu.utils import aggregators
+
+    kernels = {"1024x10x128": 2, "10240x10x128": 2, "102400x10x128": 1}
+    monkeypatch.setattr(aggregators, "_on_tpu", lambda: True)
+    paths = obs.counter("traced_paths_total", "", ("path", "detail"))
+
+    def taken():
+        return {d: paths.labels(path="unimp_attention_kernel",
+                                detail=d).value for d in kernels}
+
+    est, one = _gat_estimator(one_chip, "unimp3-papers100m-s32")
+    before = taken()
+    text = jax.jit(est._make_one_step()).lower(
+        _with_sharding(est.state, one_chip),
+        _merged(_with_sharding(one, one_chip), est.static_batch)).as_text(
+        debug_info=True)
+    assert {d: n - before[d] for d, n in taken().items()} == kernels
+    where = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+
+    def loc(line):
+        return where[re.findall(r"loc\((#loc\d+)\)", line)[-1]]
+
+    # a pass's call is one function of one shape, called from each pair
+    # of that shape: the compiled kernel's op name is the call site's
+    # followed by the kernel's own
+    kernel_of, fn = {}, None
+    for line in text.splitlines():
+        fn = (re.findall(r"func\.func (?:private |public )?@(\w+)", line)
+              or [fn])[0]
+        if "tpu_custom_call" in line:
+            kernel_of[fn] = loc(line)
+    names = [f"{loc(line)}/{kernel_of[callee]}"
+             for line in text.splitlines()
+             for callee in re.findall(r"\bcall @(\w+)\(", line)
+             if callee in kernel_of]
+    assert sorted(re.search(r"dot_pair_(fwd|bwd)/", n).group(1)
+                  for n in names) == ["bwd"] * 5 + ["fwd"] * 5, names
+    for name in names:
+        assert re.search(r"/encoder/enc/layer[01]/attn/jit\(_call\)/"
+                         r"dot_pair_(fwd|bwd)/pallas_call$", name), name
+        assert scope_readers.scope_of(name) == "encode", name
+        assert unimp_readers.part_of(name) == "dotattn", name
+
+
 @pytest.mark.parametrize("slots, rows, width, concat, block", [
     (10, 102400, 128, True, 512),    # cell 4's layer 0, hop 3 into hop 2
     (10, 10240, 128, True, 512),     # its hop 2 into hop 1
@@ -459,6 +513,38 @@ def test_gat_pair_kernel_fits_vmem_or_leaves_it_to_xla(one_chip, slots, rows,
         sds((heads, rows)), sds((width, heads)), sds((slots, rows), jnp.bool_),
         sds((rows, out))).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("rows, lanes, block", [
+    (102400, 128, 512),     # cell 5's layer 0, hop 3 into hop 2
+    (1024, 688, None),      # its last layer: 4 heads of 172
+], ids=["cell5_hop3", "cell5_last"])
+def test_unimp_pair_kernels_fit_vmem_or_leave_it_to_xla(one_chip, rows,
+                                                         lanes, block):
+    """(e''') UniMP's pair, value and gradients at cell 5's shapes, 10
+    slots and 4 heads: where `row_block` finds a block, its `vmem_bytes`
+    is within the budget and Mosaic accepts both kernels at it; where
+    none is found the layer keeps the XLA path."""
+    from euler_tpu.ops import dot_pair
+
+    slots, heads = 10, 4
+    assert dot_pair.row_block(slots, rows, lanes, heads) == block
+    if block is None:
+        return
+    assert dot_pair.vmem_bytes(block, slots, lanes) \
+        <= dot_pair.VMEM_BUDGET < dot_pair.VMEM_LIMIT
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(q, k_s, v_s, mask, w):
+        return (dot_pair.attend(q, k_s, v_s, mask, heads) * w).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(3))).lower(
+        sds((rows, lanes)), sds((slots, rows, lanes)),
+        sds((slots, rows, lanes)), sds((slots, rows), jnp.bool_),
+        sds((rows, lanes))).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
 
 
 # The compile's time grows with the batch (my runs, PR 28, this sandbox
